@@ -7,7 +7,9 @@ validated: Distributed NE's lower RF translates 1:1 into lower COM, most
 visible for communication-heavy PageRank (paper §7.6).
 
 The engine needs one device per partition, so the measurement runs in a
-subprocess with 8 forced host devices (same pattern as tests/test_spmd).
+subprocess with 8 forced host devices (same pattern as tests/test_spmd),
+pinned to the CPU: this process may already hold the chip, and a child
+that asked for it would fail or hang.  Rows say ``platform=cpu``.
 """
 import os
 import subprocess
@@ -47,13 +49,15 @@ def _inner(fast: bool):
         t_wc = timeit(lambda: wcc(sg), repeats=1, warmup=1)
         print(f"CSV:table5_{name},{t_pr * 1e6:.1f},"
               f"rf={st.replication_factor:.2f};com_pr_MB={com_pr/1e6:.2f};"
-              f"t_pr={t_pr:.2f}s;t_sssp={t_ss:.2f}s;t_wcc={t_wc:.2f}s",
+              f"t_pr={t_pr:.2f}s;t_sssp={t_ss:.2f}s;t_wcc={t_wc:.2f}s;"
+              f"platform={jax.devices()[0].platform}",
               flush=True)
 
 
 def main(fast: bool = False):
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"
     root = Path(__file__).resolve().parent.parent
     env["PYTHONPATH"] = f"{root / 'src'}:{root}"
     proc = subprocess.run(

@@ -3,13 +3,14 @@ the derived column reports the roofline-relevant operation counts) and the
 partition-locality effect: Distributed NE lowers the nonzero-block count
 of the block-CSR adjacency vs random order — fewer MXU block matmuls.
 
-The ne_round rows time the fused Pallas expansion-round kernels against
-the XLA chains they replace, asserting bit-identity in-line, and account
-the SyncVertexAllocations collective payload: bit-packed replica words
-must move ≥8× fewer bytes than the (N, P) int32 psum of the bool path
-(the smoke-gate assertion).  Off-TPU the Pallas side runs in interpret
-mode, so the us ratios here measure structure, not silicon — the payload
-byte accounting is exact everywhere.
+The ne_round rows time the edge-list one-hop of the expansion round (one
+pass over M edges; XLA on every backend) against the CSR-slot chain,
+asserting bit-identity in-line, and account the
+SyncVertexAllocations collective payload: bit-packed replica words must
+move ≥8× fewer bytes than the (N, P) int32 psum of the bool path (the
+smoke-gate assertion).  Off-TPU the Pallas packing kernels run in
+interpret mode, so the us ratios here measure structure, not silicon —
+the payload byte accounting is exact everywhere.
 """
 import numpy as np
 
@@ -53,9 +54,8 @@ def _ne_rows(scale: int, repeats: int):
 
     from repro.core.graph import as_graph
     from repro.core.partitioner import (I32_INF, alpha_limit,
-                                        boundary_reseed, ne_init_state,
-                                        ne_round_step, select_chunk,
-                                        vertex_claims)
+                                        ne_init_state, ne_round_step,
+                                        one_hop, vertex_claims)
     from repro.dist import compat
     from repro.kernels.ne_round import ops as ne_ops
 
@@ -73,7 +73,7 @@ def _ne_rows(scale: int, repeats: int):
                            state.edges_per_part, sub)
     u, v = g.edges[:, 0], g.edges[:, 1]
 
-    # --- ne_claims: fused one-hop vs the 5-pass CSR segment_min chain ------
+    # --- ne_claims: edge-list one-hop vs the 5-pass CSR segment_min chain --
     @jax.jit
     def xla_chain(vc, ep):
         slot_key = vc[g.slot_src]
@@ -87,52 +87,20 @@ def _ne_rows(scale: int, repeats: int):
         return part1, counts
 
     @jax.jit
-    def pal_one_hop(vc, ep):
-        return ne_ops.one_hop(vc, u, v, ep, p_num)
+    def edge_one_hop(vc, ep):
+        return one_hop(vc, u, v, ep, p_num)
 
     px, cx = jax.block_until_ready(xla_chain(vclaim, state.edge_part))
-    pp, cp = jax.block_until_ready(pal_one_hop(vclaim, state.edge_part))
+    pp, cp = jax.block_until_ready(edge_one_hop(vclaim, state.edge_part))
     assert (np.asarray(px) == np.asarray(pp)).all()
     assert (np.asarray(cx) == np.asarray(cp)).all()
     t_x = timeit(lambda: jax.block_until_ready(
         xla_chain(vclaim, state.edge_part)), repeats=repeats)
     t_p = timeit(lambda: jax.block_until_ready(
-        pal_one_hop(vclaim, state.edge_part)), repeats=repeats)
+        edge_one_hop(vclaim, state.edge_part)), repeats=repeats)
     record("ne_claims", t_p * 1e6,
            f"scale={scale};edges={m};xla_us={t_x * 1e6:.1f};"
-           f"pallas_over_xla={t_p / t_x:.2f}x;bit_identical=True")
-
-    # --- ne_select: fused boundary top-k vs select_chunk -------------------
-    c = min(cfg.sel_chunk, p_num)
-    active_c = (state.edges_per_part <= limit)[:c]
-    remaining_c = (limit - state.edges_per_part)[:c]
-    keys_c = jax.vmap(lambda i: jax.random.fold_in(sub, i))(
-        jnp.arange(c, dtype=jnp.int32))
-    vparts_c = state.vparts.T[:c]
-
-    @jax.jit
-    def xla_sel(vp_c, dr):
-        return select_chunk(vp_c, active_c, dr, cfg.lam, cfg.k_sel, keys_c,
-                            remaining_c)
-
-    @jax.jit
-    def pal_sel(vp_c, dr):
-        rnd_v, any_ok = boundary_reseed(dr, keys_c)
-        return ne_ops.select_topk(vp_c, active_c, dr, cfg.lam, cfg.k_sel,
-                                  remaining_c, rnd_v, any_ok)
-
-    ix, vx = jax.block_until_ready(xla_sel(vparts_c, state.degree_rest))
-    ip, vp = jax.block_until_ready(pal_sel(vparts_c, state.degree_rest))
-    assert (np.asarray(vx) == np.asarray(vp)).all()
-    assert (np.where(vx, ix, -1) == np.where(vp, ip, -1)).all()
-    t_x = timeit(lambda: jax.block_until_ready(
-        xla_sel(vparts_c, state.degree_rest)), repeats=repeats)
-    t_p = timeit(lambda: jax.block_until_ready(
-        pal_sel(vparts_c, state.degree_rest)), repeats=repeats)
-    record("ne_select", t_p * 1e6,
-           f"scale={scale};chunk={c}x{n};k_sel={cfg.k_sel};"
-           f"xla_us={t_x * 1e6:.1f};pallas_over_xla={t_p / t_x:.2f}x;"
-           f"bit_identical=True")
+           f"edgelist_over_chain={t_p / t_x:.2f}x;bit_identical=True")
 
     # --- ne_or_reduce: packed OR all-reduce vs bool int32 psum -------------
     # payload accounting is exact and platform-independent: the array each
@@ -162,8 +130,8 @@ def _ne_rows(scale: int, repeats: int):
 
         sm = dict(mesh=mesh, in_specs=(P("shard", None, None),),
                   out_specs=P("shard", None, None), check_vma=False)
-        bool_fn = jax.jit(compat.shard_map(bool_body, **sm))
-        packed_fn = jax.jit(compat.shard_map(packed_body, **sm))
+        bool_fn = jax.jit(jax.shard_map(bool_body, **sm))
+        packed_fn = jax.jit(jax.shard_map(packed_body, **sm))
         rb = jax.block_until_ready(bool_fn(vnew))
         rp = jax.block_until_ready(packed_fn(vnew))
         assert (np.asarray(rb) == np.asarray(rp)).all()

@@ -40,9 +40,11 @@ HYBRID_BUDGETS = (0.5, 0.25)    # τ grid; last = tightest (asserted cell)
 RF_VS_NE_MAX = 1.3              # tightest-budget hybrid RF vs NE bound
 ANCHOR_P = 16                   # the partition count the claims assert at
 
+# the RSS child runs on the CPU, never on a chip this process may hold;
+# its rows say so (rss_platform)
 _CHILD = """
 import os
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["JAX_PLATFORMS"] = "cpu"
 from repro.io.edgefile import EdgeFile
 ef = EdgeFile({path!r})
 {body}
@@ -120,12 +122,14 @@ def main(parts=(4, 16, 64), fast: bool = False):
                     metrics = dict(rf=st.replication_factor,
                                    eb=st.edge_balance,
                                    vb=st.vertex_balance)
+                    derived = ""
                     if anchor and rss_body is not None:
                         rss[method] = child_peak_rss_kb(
                             _CHILD.format(path=ef.path, body=rss_body))
                         metrics["rss_kb"] = rss[method]
+                        derived = ";rss_platform=cpu"
                     record(f"quality_{gname}_p{p}_{method}", us,
-                           fmt_metrics(**metrics))
+                           fmt_metrics(**metrics) + derived)
 
                 cell("ne",
                      lambda: partition(

@@ -29,6 +29,7 @@ runtime layer breaks the gate loudly.
 from __future__ import annotations
 
 import json
+import os
 import subprocess
 import sys
 import tempfile
@@ -48,7 +49,10 @@ ROOT = Path(__file__).resolve().parents[1]
 
 def _multihost_run(td: Path, ef_path: str, name: str,
                    snapshot_every: int) -> dict:
-    """One 2-process × 4-device launcher invocation; returns timing.json."""
+    """One 2-process × 4-device launcher invocation; returns timing.json.
+
+    The gang runs on the CPU (``JAX_PLATFORMS=cpu``): two local workers
+    cannot share a chip, and this process may already hold it."""
     out_dir = td / f"mh_{name}"
     args = [sys.executable, str(ROOT / "scripts" / "launch_multihost.py"),
             "--edgefile", ef_path, "--partitions", "8", "--seed", "0",
@@ -57,7 +61,8 @@ def _multihost_run(td: Path, ef_path: str, name: str,
             "--snapshot-dir", str(td / f"snap_{name}"),
             "--snapshot-every", str(snapshot_every),
             "--out", str(out_dir), "--timeout", "900"]
-    proc = subprocess.run(args, capture_output=True, text=True, timeout=1200)
+    proc = subprocess.run(args, capture_output=True, text=True, timeout=1200,
+                          env=dict(os.environ, JAX_PLATFORMS="cpu"))
     if proc.returncode != 0:
         raise RuntimeError(f"multihost bench run failed "
                            f"(rc={proc.returncode}):\n{proc.stderr[-3000:]}")
@@ -85,9 +90,11 @@ def bench_multihost(single_round_us: float, fast: bool = False):
         t_snap = float(np.mean(snap["round_secs"][1:]))
         record("runtime/multihost_round", t_plain * 1e6,
                f"rounds={plain['rounds']};"
-               f"single_round_us={single_round_us:.1f}")
+               f"single_round_us={single_round_us:.1f};"
+               f"platform={plain['platform']}")
         record("runtime/multihost_snap", (t_snap - t_plain) * 1e6,
-               f"+{(t_snap - t_plain) / max(t_plain, 1e-12) * 100:.0f}%")
+               f"+{(t_snap - t_plain) / max(t_plain, 1e-12) * 100:.0f}%;"
+               f"platform={snap['platform']}")
 
 
 def main(fast: bool = False, smoke: bool = False):

@@ -165,6 +165,9 @@ def main() -> None:
     args = ap.parse_args()
     if args.smoke:
         args.fast = True
+    from repro import compile_cache
+
+    compile_cache.enable()
 
     from benchmarks import (bench_apps, bench_elapsed, bench_kernels,
                             bench_lambda_sweep, bench_memory, bench_quality,

@@ -67,4 +67,7 @@ def main(scale: int = 12, snapshot_every: int = 4):
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+
+    compile_cache.enable()
     main()

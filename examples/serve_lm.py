@@ -27,4 +27,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+
+    compile_cache.enable()
     main()

@@ -99,4 +99,7 @@ def main(scale: int = 12, num_partitions: int = 8, num_groups: int = 2,
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+
+    compile_cache.enable()
     main()

@@ -83,4 +83,7 @@ def main(steps: int = 300):
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+
+    compile_cache.enable()
     main()

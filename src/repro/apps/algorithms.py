@@ -78,7 +78,7 @@ def pagerank(sg: ShardedGraph, mesh=None, iters: int = 30,
 
         return jax.lax.fori_loop(0, iters, step, pr)[None]
 
-    fn = jax.jit(compat.shard_map(body, mesh=mesh, in_specs=_specs(6),
+    fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=_specs(6),
                                   out_specs=P(AXIS)))
     out = np.asarray(fn(*_unpack(sg)))[:, :, 0]
     return _stitch(sg, out, fill=(1.0 - damping) / n)
@@ -122,7 +122,7 @@ def _label_propagation(sg: ShardedGraph, mesh, init_fn, relax_add: float,
             cond, step, (val, jnp.bool_(True), jnp.int32(0)))
         return out[None], iters[None]
 
-    fn = jax.jit(compat.shard_map(body, mesh=mesh, in_specs=_specs(7),
+    fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=_specs(7),
                                   out_specs=(P(AXIS), P(AXIS))))
     init_vals = init_fn()
     out, iters = fn(*_unpack(sg), jnp.asarray(init_vals))

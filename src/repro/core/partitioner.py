@@ -54,11 +54,11 @@ class NEConfig:
     edge_chunk: int = 1 << 18   # edges per two-hop intersection chunk
     two_hop: bool = True        # Condition (5) allocation on/off (ablation)
     seed: int = 0
-    # Fused ne_round kernels for the round hot path (and bit-packed
-    # replica sets in the SPMD partitioner).  None resolves from the
-    # REPRO_NE_KERNELS env var at construction, so a resolved config is
-    # self-contained and its snapshot fingerprint stable.  Both values
-    # produce bit-identical results (asserted in tests).
+    # Bit-packed replica sets (the Pallas ne_round packing kernels) in the
+    # SPMD partitioner; the single-controller round ignores it.  None
+    # resolves from the REPRO_NE_KERNELS env var at construction, so a
+    # resolved config is self-contained and its snapshot fingerprint
+    # stable.  Both values produce bit-identical results (asserted in tests).
     use_pallas: bool = None
 
     def __post_init__(self):
@@ -129,21 +129,6 @@ def priority_enc(count: Array, p: Array, num_partitions: int) -> Array:
     return jnp.minimum(count, cap) * num_partitions + p
 
 
-def boundary_reseed(degree_rest, keys_c):
-    """Random re-seed draw for empty boundaries (paper Alg. 1 line 6).
-
-    Hoisted out of :func:`select_chunk` so the fused Pallas selection
-    kernel can consume the identical jax.random bits without reproducing
-    the PRNG inside the kernel.  Returns ``(rnd_v, any_ok)``: (C,) random
-    vertices with unallocated edges and the scalar any-rest flag.
-    """
-    n = degree_rest.shape[0]
-    any_rest = degree_rest > 0
-    gumb = jax.vmap(lambda k: jax.random.uniform(k, (n,)))(keys_c)
-    rnd_v = jnp.argmax(jnp.where(any_rest[None, :], gumb, -1.0), axis=1)
-    return rnd_v, any_rest.any()
-
-
 def select_chunk(vparts_c, active_c, degree_rest, lam, k_sel, keys_c,
                  remaining_c):
     """Selection for a chunk of partitions.  vparts_c: (C, N) bool."""
@@ -162,8 +147,11 @@ def select_chunk(vparts_c, active_c, degree_rest, lam, k_sel, keys_c,
     fits = jnp.cumsum(cost, axis=1) <= remaining_c[:, None]
     valid &= fits | (jnp.arange(k_sel)[None, :] == 0)
     # Random re-seed when the boundary is empty (paper Alg. 1 line 6).
-    rnd_v, any_ok = boundary_reseed(degree_rest, keys_c)
-    restart = (bsize == 0) & active_c & any_ok
+    any_rest = degree_rest > 0
+    gumb = jax.vmap(lambda k: jax.random.uniform(k, degree_rest.shape))(
+        keys_c)
+    rnd_v = jnp.argmax(jnp.where(any_rest[None, :], gumb, -1.0), axis=1)
+    restart = (bsize == 0) & active_c & any_rest.any()
     first = jnp.where(restart, rnd_v.astype(jnp.int32), idx[:, 0])
     idx = idx.at[:, 0].set(first)
     valid = valid.at[:, 0].set(jnp.where(restart, True, valid[:, 0]))
@@ -196,19 +184,9 @@ def vertex_claims(cfg: NEConfig, limit: int, vparts: Array,
 
     remaining = jnp.pad(limit - edges_per_part, (0, p_pad - p_num))
 
-    if cfg.use_pallas:
-        # fused kernel path: identical PRNG draw outside, fused masked
-        # top-k + capacity prefix inside (bit-identical — see ne_round)
-        def sel(args):
-            pc, ac, kc, rc = args
-            rnd_v, any_ok = boundary_reseed(degree_rest, kc)
-            return ne_ops.select_topk(pc, ac, degree_rest, cfg.lam,
-                                      cfg.k_sel, rc, rnd_v, any_ok)
-    else:
-        def sel(args):
-            pc, ac, kc, rc = args
-            return select_chunk(pc, ac, degree_rest, cfg.lam, cfg.k_sel,
-                                kc, rc)
+    def sel(args):
+        pc, ac, kc, rc = args
+        return select_chunk(pc, ac, degree_rest, cfg.lam, cfg.k_sel, kc, rc)
 
     sel_idx, sel_valid = jax.lax.map(
         sel,
@@ -221,9 +199,6 @@ def vertex_claims(cfg: NEConfig, limit: int, vparts: Array,
     sel_valid = sel_valid.reshape(p_pad, cfg.k_sel)[:p_num]
 
     # --- vertex-grain claims (paper Alg. 3) --------------------------------
-    if cfg.use_pallas:
-        return ne_ops.claim_scatter(sel_idx, sel_valid, edges_per_part,
-                                    n, p_num)
     part_of_row = jnp.broadcast_to(
         jnp.arange(p_num, dtype=jnp.int32)[:, None], sel_idx.shape)
     claim_keys = priority_enc(edges_per_part[part_of_row.ravel()],
@@ -231,6 +206,26 @@ def vertex_claims(cfg: NEConfig, limit: int, vparts: Array,
     flat_v = jnp.where(sel_valid.ravel(), sel_idx.ravel(), n)   # n → dropped
     vclaim_key = jnp.full((n,), I32_INF, jnp.int32)
     return vclaim_key.at[flat_v].min(claim_keys, mode="drop")
+
+
+def one_hop(vclaim: Array, u: Array, v: Array, edge_part: Array,
+            num_partitions: int, mask: Array | None = None):
+    """One-hop allocation (paper Alg. 3) over an edge list.
+
+    Per edge: ``k = min(vclaim[u], vclaim[v])``; an unallocated edge
+    (``mask`` false → never) joins partition ``k % P`` when some endpoint
+    was claimed — the min over the edge's two directed CSR slots, in one
+    pass over M edges.  Returns ``(part, counts)``: (M,) int32, ``-1``
+    for untouched edges, and the (P,) int32 histogram of new allocations.
+    """
+    k_uv = jnp.minimum(vclaim[u], vclaim[v])
+    new = (edge_part < 0) & (k_uv < I32_INF)
+    if mask is not None:
+        new &= mask
+    part = jnp.where(new, (k_uv % num_partitions).astype(jnp.int32), -1)
+    counts = jnp.zeros((num_partitions,), jnp.int32).at[
+        jnp.maximum(part, 0)].add(new.astype(jnp.int32))
+    return part, counts
 
 
 def _round(g: Graph, cfg: NEConfig, limit: int, state: NEState) -> NEState:
@@ -244,22 +239,8 @@ def _round(g: Graph, cfg: NEConfig, limit: int, state: NEState) -> NEState:
 
     # --- one-hop allocation ------------------------------------------------
     u, v = g.edges[:, 0], g.edges[:, 1]
-    if cfg.use_pallas:
-        # fused edge-block kernel: one pass over M edges replaces the
-        # five gather/scatter passes over 2M CSR slots below (min over
-        # an edge's two directed slots == min(vclaim[u], vclaim[v]))
-        part1, counts1 = ne_ops.one_hop(vclaim_key, u, v, state.edge_part,
-                                        p_num)
-        new1 = part1 >= 0
-    else:
-        slot_key = vclaim_key[g.slot_src]
-        slot_ok = (slot_key < I32_INF) & (state.edge_part[g.adj_eid] < 0)
-        slot_key = jnp.where(slot_ok, slot_key, I32_INF)
-        ekey = jax.ops.segment_min(slot_key, g.adj_eid, num_segments=m)
-        new1 = ekey < I32_INF
-        part1 = jnp.where(new1, ekey % p_num, -1)
-        counts1 = jnp.zeros((p_num,), jnp.int32).at[
-            jnp.where(new1, part1, 0)].add(new1.astype(jnp.int32))
+    part1, counts1 = one_hop(vclaim_key, u, v, state.edge_part, p_num)
+    new1 = part1 >= 0
 
     edge_part = jnp.where(new1, part1, state.edge_part)
     add_row = jnp.where(new1, part1, 0)

@@ -3,9 +3,9 @@
 This package is the substrate that turns the single-controller paper
 reproduction into the distributed system the paper describes (§4):
 
-  ``compat``          — one-module shim over the JAX SPMD API surface
-                        (``shard_map`` / ``set_mesh`` / ``make_mesh``)
-                        so the repo runs on both old and new jaxlibs.
+  ``compat``          — helpers JAX does not spell directly: the packed
+                        OR all-reduce, Auto-axis ``make_mesh``, and the
+                        process-level barrier / reductions.
   ``context``         — thread-local (mesh, batch_axes, model_axis)
                         registry used by model code that needs explicit
                         collectives (MoE expert parallelism, row-sharded

@@ -1,41 +1,17 @@
-"""Version shims for the JAX SPMD API surface.
+"""SPMD and multi-process helpers that ``jax`` does not spell directly.
 
-The repo targets the modern API (``jax.shard_map`` with ``check_vma``,
-``jax.sharding.set_mesh``, ``jax.make_mesh(..., axis_types=...)``).  Older
-jaxlibs — e.g. the 0.4.x toolchain in the reference container (note: CI
-installs an unpinned jax, so it exercises whichever branch resolves) —
-expose the same functionality as ``jax.experimental.shard_map.shard_map``
-with ``check_rep``, the ``Mesh`` context manager, and ``make_mesh``
-without axis types.  This module is the single place where that difference lives;
-everything else imports ``shard_map`` / ``make_mesh`` / ``set_mesh`` from
-here instead of touching ``jax.*`` directly.
+``or_all_reduce`` (a bitwise-OR all-reduce that keeps packed words
+packed), ``make_mesh`` (Auto axis types, which ``jax.make_mesh`` no longer
+defaults to), and the process-level wrappers — ``process_env``,
+``barrier`` and the ``all_processes_*`` reductions — that behave the same
+whether or not ``jax.distributed`` was initialized.
 """
 from __future__ import annotations
 
 import jax
 
 __all__ = ["all_processes_any", "all_processes_min", "all_processes_sum",
-           "barrier", "make_mesh", "or_all_reduce", "process_env", "pvary",
-           "set_mesh", "shard_map"]
-
-
-def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` on new jaxlibs, experimental shard_map on old.
-
-    ``check_vma=False`` maps to ``check_rep=False`` on old jaxlibs — both
-    disable the replication/varying-mesh-axes inference that cannot prove
-    invariance through e.g. FSDP ``all_gather`` patterns.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    # The legacy replication checker has no rules for while_loop /
-    # all_gather bodies this repo uses, so it stays off here; the modern
-    # check_vma path above keeps the caller's setting.
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
+           "barrier", "make_mesh", "or_all_reduce", "process_env"]
 
 
 def or_all_reduce(x, axis_name, num_devices: int):
@@ -48,8 +24,7 @@ def or_all_reduce(x, axis_name, num_devices: int):
     is a power of two, else one ``all_gather`` + fold.  Both are exact
     bitwise OR, so results are bit-identical either way.
 
-    ``num_devices`` must be the static axis size (from the mesh shape) —
-    old jaxlibs have no ``jax.lax.axis_size``.
+    ``num_devices`` is the static axis size (from the mesh shape).
     """
     d = int(num_devices)
     if d <= 1:
@@ -68,29 +43,12 @@ def or_all_reduce(x, axis_name, num_devices: int):
     return out
 
 
-def pvary(x, axis_names):
-    """Mark ``x`` as varying over ``axis_names`` inside ``shard_map``.
-
-    Old jaxlibs have no varying-mesh-axes tracking, so this is an
-    identity there (the values already behave as per-device).
-    """
-    if hasattr(jax.lax, "pvary"):
-        return jax.lax.pvary(x, axis_names)
-    return x
-
-
 def make_mesh(axis_shapes, axis_names, devices=None):
-    """``jax.make_mesh`` with Auto axis types where the concept exists."""
-    kwargs = {} if devices is None else {"devices": devices}
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(
-                axis_shapes, axis_names,
-                axis_types=(axis_type.Auto,) * len(axis_names), **kwargs)
-        except TypeError:
-            pass
-    return jax.make_mesh(axis_shapes, axis_names, **kwargs)
+    """``jax.make_mesh`` with Auto axis types (it defaults to Explicit)."""
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
+        devices=devices)
 
 
 def process_env() -> tuple[int, int]:
@@ -188,15 +146,3 @@ def all_processes_any(mask):
         gathered = multihost_utils.process_allgather(flat[i:i + chunk])
         out[i:i + chunk] = np.any(gathered, axis=0)
     return out.reshape(mask.shape)
-
-
-def set_mesh(mesh):
-    """Context manager entering ``mesh`` as the ambient sharding mesh.
-
-    New jaxlibs: ``jax.sharding.set_mesh``.  Old jaxlibs: a ``Mesh`` is
-    itself a context manager that installs the physical mesh, which is what
-    resolves bare ``PartitionSpec`` sharding constraints under ``jit``.
-    """
-    if hasattr(jax.sharding, "set_mesh"):
-        return jax.sharding.set_mesh(mesh)
-    return mesh

@@ -11,9 +11,8 @@ One while_loop step == one paper round, per device:
      because selection reads only V(E_p), D_rest and |E_p|, all of which
      are re-synchronized at the end of every round;
   2. **one-hop allocation** over local edges: edge (u, v) joins the best
-     claiming partition ``min(claim[u], claim[v])`` — identical math to the
-     single-controller ``segment_min`` over CSR slots, restricted to the
-     local shard;
+     claiming partition ``min(claim[u], claim[v])`` — the single-controller
+     ``core.partitioner.one_hop``, restricted to the local shard;
   3. **SyncVertexAllocations** — the paper's §4 merge, realized as an OR
      all-reduce of the replica-set deltas plus ``psum`` of the |E_p| and
      D_rest deltas;
@@ -37,13 +36,14 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.core.epilogue import stitch_slices
 from repro.core.graph import Graph, exclusive_rank, shard_edges
 from repro.core.partitioner import (I32_INF, NEConfig, PartitionResult,
                                     alpha_limit, finalize_result,
-                                    priority_enc, vertex_claims)
+                                    one_hop, priority_enc, vertex_claims)
 from repro.dist import compat
 from repro.io.edgefile import EdgeFile
 from repro.kernels.ne_round import ops as ne_ops
@@ -119,16 +119,9 @@ def _spmd_round(cfg: NEConfig, limit: int, n: int, num_dev: int,
                            state.edges_per_part, sub)
 
     # --- 2. one-hop allocation on the local shard --------------------------
-    counts1 = None
-    if packed:
-        part1, counts1 = ne_ops.one_hop(vclaim, u_loc, v_loc,
-                                        state.edge_part, p_num,
-                                        mask=mask_loc)
-        new1 = part1 >= 0
-    else:
-        k_uv = jnp.minimum(vclaim[u_loc], vclaim[v_loc])
-        new1 = mask_loc & (state.edge_part < 0) & (k_uv < I32_INF)
-        part1 = jnp.where(new1, (k_uv % p_num).astype(jnp.int32), -1)
+    part1, counts1 = one_hop(vclaim, u_loc, v_loc, state.edge_part, p_num,
+                             mask=mask_loc)
+    new1 = part1 >= 0
     edge_part = jnp.where(new1, part1, state.edge_part)
 
     # --- 3. SyncVertexAllocations ------------------------------------------
@@ -200,36 +193,50 @@ def _spmd_round(cfg: NEConfig, limit: int, n: int, num_dev: int,
 # round-stepping surface (repro.runtime.driver)
 # ---------------------------------------------------------------------------
 
-def _empty_vparts(n: int, cfg: NEConfig) -> Array:
+def empty_vparts(n: int, cfg: NEConfig, xp=jnp) -> Array:
     """All-empty replica sets in the representation the round uses:
-    bit-packed uint32 words under cfg.use_pallas, (N, P) bool otherwise."""
+    bit-packed uint32 words under cfg.use_pallas, (N, P) bool otherwise.
+    ``xp=np`` builds them on the host."""
     if cfg.use_pallas:
         w = ne_ops.replica_words(cfg.num_partitions)
-        return jnp.zeros((n, w), jnp.uint32)
-    return jnp.zeros((n, cfg.num_partitions), bool)
+        return xp.zeros((n, w), xp.uint32)
+    return xp.zeros((n, cfg.num_partitions), bool)
+
+
+def shard_over(mesh, x) -> Array:
+    """Place a (D, ...) host array on ``mesh``, row ``d`` on device ``d``."""
+    return jax.device_put(x, NamedSharding(mesh, P(AXIS, None)))
+
+
+def place_state(mesh, state: SpmdState) -> SpmdState:
+    """Place a round state on ``mesh``: ``edge_part`` sharded over the
+    device axis, every other field replicated — the layout
+    :func:`spmd_round_step` takes, so no round reshards from one chip."""
+    rep = NamedSharding(mesh, P())
+    return jax.device_put(state, SpmdState(
+        NamedSharding(mesh, P(AXIS, None)), *(rep,) * 6))
 
 
 def spmd_init_state(shards: np.ndarray, masks: np.ndarray, n: int,
-                    cfg: NEConfig) -> SpmdState:
+                    cfg: NEConfig, mesh) -> SpmdState:
     """Host-built initial round state, bit-identical to the in-jit init of
     :func:`_partition_spmd_jit` (global D_rest via one bincount pass instead
-    of the in-shard_map psum).  ``edge_part`` keeps its (D, C) shard layout
-    so the stepping jit can shard it over the device axis.
+    of the in-shard_map psum), placed on ``mesh``.  ``edge_part`` keeps its
+    (D, C) shard layout, sharded over the device axis.
     """
     p_num = cfg.num_partitions
     flat = shards.reshape(-1, 2)[masks.reshape(-1)]
-    degree = np.zeros(n, np.int64)
-    np.add.at(degree, flat[:, 0], 1)
-    np.add.at(degree, flat[:, 1], 1)
-    return SpmdState(
-        edge_part=jnp.full(masks.shape, -1, jnp.int32),
-        vparts=_empty_vparts(n, cfg),
-        degree_rest=jnp.asarray(degree.astype(np.int32)),
-        edges_per_part=jnp.zeros((p_num,), jnp.int32),
+    degree = (np.bincount(flat[:, 0], minlength=n)
+              + np.bincount(flat[:, 1], minlength=n))
+    return place_state(mesh, SpmdState(
+        edge_part=np.full(masks.shape, -1, np.int32),
+        vparts=empty_vparts(n, cfg, np),
+        degree_rest=degree.astype(np.int32),
+        edges_per_part=np.zeros((p_num,), np.int32),
         key=jax.random.PRNGKey(cfg.seed),
-        rounds=jnp.zeros((), jnp.int32),
-        remaining=jnp.int32(flat.shape[0]),
-    )
+        rounds=np.zeros((), np.int32),
+        remaining=np.int32(flat.shape[0]),
+    ))
 
 
 @partial(jax.jit, static_argnames=("cfg", "limit", "n", "mesh"))
@@ -254,7 +261,7 @@ def spmd_round_step(cfg: NEConfig, limit: int, n: int, mesh,
         return out._replace(edge_part=out.edge_part[None])
 
     rep = (P(),) * 6
-    return compat.shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(AXIS, None), P(AXIS, None), P(AXIS, None),
                   P(AXIS, None)) + rep,
@@ -363,7 +370,7 @@ def _partition_spmd_jit(cfg: NEConfig, limit: int, n: int, mesh,
         u_l, v_l, mask_l = u_l[0], v_l[0], mask_l[0]
         init = SpmdState(
             edge_part=jnp.full(u_l.shape, -1, jnp.int32),
-            vparts=_empty_vparts(n, cfg),
+            vparts=empty_vparts(n, cfg),
             degree_rest=(jnp.zeros((n,), jnp.int32)
                          .at[u_l].add(mask_l.astype(jnp.int32))
                          .at[v_l].add(mask_l.astype(jnp.int32))),
@@ -386,7 +393,7 @@ def _partition_spmd_jit(cfg: NEConfig, limit: int, n: int, mesh,
         return (out.edge_part[None], out.vparts, out.edges_per_part,
                 out.rounds)
 
-    return compat.shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(AXIS, None), P(AXIS, None), P(AXIS, None), P()),
         out_specs=(P(AXIS, None), P(), P(), P()),
@@ -447,9 +454,9 @@ def partition_spmd(g: Graph, cfg: NEConfig,
     limit = alpha_limit(cfg.alpha, m, p_num)
     ep_sh, vparts, counts, rounds = jax.block_until_ready(
         _partition_spmd_jit(cfg, limit, n, mesh,
-                            jnp.asarray(shards[:, :, 0]),
-                            jnp.asarray(shards[:, :, 1]),
-                            jnp.asarray(masks), jnp.int32(m)))
+                            shard_over(mesh, shards[:, :, 0]),
+                            shard_over(mesh, shards[:, :, 1]),
+                            shard_over(mesh, masks), jnp.int32(m)))
 
     edge_part = stitch_edge_part(ep_sh, dev, m)
     if cfg.use_pallas:  # result surface is always (N, P) bool

@@ -90,7 +90,7 @@ def redistribute_edges(shards: np.ndarray, masks: np.ndarray,
                                  tiled=True)
         return got.reshape(1, d * cap, 3)
 
-    fn = jax.jit(compat.shard_map(
+    fn = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=P(AXIS, None, None),
         out_specs=P(AXIS, None, None), check_vma=False))
     out = np.asarray(fn(jnp.asarray(packed)))                  # (D, D*cap, 3)

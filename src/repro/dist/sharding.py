@@ -19,22 +19,6 @@ from repro.dist.context import get_mesh_ctx
 __all__ = ["Rules", "NO_RULES", "lm_rules"]
 
 
-def _ambient_mesh():
-    """Mesh from the repro context, else jax's installed physical mesh."""
-    ctx = get_mesh_ctx()
-    if ctx is not None:
-        return ctx.mesh
-    try:  # old-jax global mesh context manager (``with mesh:``)
-        from jax.interpreters.pxla import thread_resources
-
-        physical = thread_resources.env.physical_mesh
-        if not physical.empty:
-            return physical
-    except Exception:  # noqa: BLE001 — internal layout differs across jaxlibs
-        pass
-    return None
-
-
 class Rules(Mapping):
     """Immutable logical-name → PartitionSpec table."""
 
@@ -64,10 +48,14 @@ class Rules(Mapping):
         spec = self._specs.get(name)
         if spec is None:
             return x
-        mesh = _ambient_mesh()
-        if mesh is None:
+        ctx = get_mesh_ctx()
+        if ctx is not None:
+            return jax.lax.with_sharding_constraint(
+                x, NamedSharding(ctx.mesh, spec))
+        if jax.sharding.get_abstract_mesh().empty:
             return x
-        return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+        # a bare spec resolves against the mesh jax.sharding.set_mesh set
+        return jax.lax.with_sharding_constraint(x, spec)
 
 
 NO_RULES = Rules({})

@@ -1,36 +1,18 @@
-"""Fused Pallas kernels for the Distributed NE expansion-round hot path.
+"""Pallas kernels for the bit-packed replica sets of the SPMD NE round.
 
-Three fusions, matching the chains they replace bit-for-bit (all-integer
-math, pinned against ``ref.py`` and the live partitioner by tests):
+``pack_bits`` / ``unpack_bits`` / ``or_words`` convert between the
+(N, P) bool replica map and its (N, ceil(P/32)) uint32 packing, and
+OR-merge two packings.  The SPMD partitioner uses them under
+``NEConfig.use_pallas`` to shrink the per-round replica-map all-reduce
+from (N, P)·4 bytes (int32 psum) to (N, ceil(P/32))·4 bytes.
 
-``one_hop``
-    The allocation chain of ``core.partitioner._round`` — gather claim
-    keys for both endpoints, min-combine, test allocation, histogram —
-    tiled over edge blocks so each block is read once, instead of the
-    five gather/scatter passes of the CSR-slot ``segment_min`` chain
-    (which also touches 2M directed slots where this touches M edges).
-    Per-partition counts accumulate across grid steps in the revisited
-    (P,) output block.
-
-``select``
-    ``select_chunk``'s masked top-k over (C, N) boundary scores, tiled
-    over vertex tiles with a streaming (C, K) merge, the capacity
-    prefix-sum epilogue folded into the last tile.  The streaming merge
-    is bit-identical to a full-width ``top_k`` because ``top_k`` breaks
-    ties lower-index-first and the accumulator (earlier tiles) precedes
-    the fresh tile in the concatenation.
-
-``claim_scatter``
-    ``vertex_claims``' priority-encode + scatter-min of at most P·K
-    selections into per-vertex claim keys — one grid step.
-
-Plus the bit-packed replica-set family (``pack_bits`` / ``unpack_bits``
-/ ``or_words``) used by the SPMD partitioner to shrink the per-round
-replica-map all-reduce from (N, P)·4 bytes (int32 psum) to
-(N, ceil(P/32))·4 bytes.
+The rest of the round (one-hop claims, boundary top-k, claim scatter-min)
+runs as XLA (``repro.core.partitioner``): it needs a 1-D gather over an (N,) table,
+``top_k`` and scatter-min, none of which Pallas TPU lowers.
 
 Off-TPU everything runs under ``interpret=True`` (same pattern as
-``block_spmm``), so CPU CI executes these kernel bodies for real.
+``block_spmm``), so CPU CI executes these kernel bodies for real; the
+TPU compile of each is pinned by tests/test_tpu_compile.py.
 """
 from __future__ import annotations
 
@@ -38,241 +20,28 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.ne_round.ref import replica_words
 
-I32_INF = np.iinfo(np.int32).max
+# rows x lanes of one tile: wider replica sets take fewer rows per grid
+# step, so a tile's VMEM footprint stays flat in P
+TILE_ELEMS = 4096 * 32
+# Mosaic wants a block's row count to be a multiple of the sublane tile
+# (32 rows for the 1-byte bool blocks) unless the block spans the array
+ROW_ALIGN = 32
 
-# renamed TPUCompilerParams → CompilerParams across jax releases
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
-
-# ---------------------------------------------------------------------------
-# one-hop allocation
-# ---------------------------------------------------------------------------
-
-def _one_hop_kernel(vclaim_ref, u_ref, v_ref, ep_ref, part_ref, cnt_ref,
-                    *, p_num: int, has_mask: bool, mask_ref=None):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        cnt_ref[...] = jnp.zeros_like(cnt_ref)
-
-    vclaim = vclaim_ref[...]                          # (N,) resident
-    k_uv = jnp.minimum(jnp.take(vclaim, u_ref[...]),
-                       jnp.take(vclaim, v_ref[...]))  # one read per edge
-    new = (ep_ref[...] < 0) & (k_uv < I32_INF)
-    if has_mask:
-        new &= mask_ref[...]
-    part = jnp.where(new, (k_uv % p_num).astype(jnp.int32), -1)
-    part_ref[...] = part
-    onehot = (jnp.maximum(part, 0)[:, None]
-              == jax.lax.broadcasted_iota(jnp.int32, (part.shape[0], p_num),
-                                          1))
-    cnt_ref[...] += (onehot & new[:, None]).sum(axis=0).astype(jnp.int32)
-
-
-def one_hop(vclaim, u, v, edge_part, num_partitions: int, mask=None,
-            block_edges: int = 8192, interpret: bool = True):
-    """Fused one-hop allocation over edge blocks.
-
-    Returns ``(part, counts)`` — (M,) int32 with -1 for untouched edges
-    and the (P,) histogram of new allocations.  ``mask`` (optional bool
-    (M,)) gates padded shard slots in the SPMD path.
-    """
-    m = u.shape[0]
-    if m == 0:
-        return (jnp.full((0,), -1, jnp.int32),
-                jnp.zeros((num_partitions,), jnp.int32))
-    te = min(block_edges, m)
-    m_pad = -(-m // te) * te
-    pad = m_pad - m
-    if pad:
-        u = jnp.pad(u, (0, pad))
-        v = jnp.pad(v, (0, pad))
-        edge_part = jnp.pad(edge_part, (0, pad))  # pad 0 ⇒ "allocated"
-        if mask is not None:
-            mask = jnp.pad(mask, (0, pad))
-    grid = (m_pad // te,)
-    kernel = functools.partial(_one_hop_kernel, p_num=num_partitions,
-                               has_mask=mask is not None)
-    if mask is not None:
-        kernel_in = kernel
-
-        def kernel(vc, uu, vv, ep, mk, part, cnt):
-            kernel_in(vc, uu, vv, ep, part, cnt, mask_ref=mk)
-    n = vclaim.shape[0]
-    in_specs = [
-        pl.BlockSpec((n,), lambda i: (0,)),
-        pl.BlockSpec((te,), lambda i: (i,)),
-        pl.BlockSpec((te,), lambda i: (i,)),
-        pl.BlockSpec((te,), lambda i: (i,)),
-    ]
-    args = [vclaim, u, v, edge_part]
-    if mask is not None:
-        in_specs.append(pl.BlockSpec((te,), lambda i: (i,)))
-        args.append(mask)
-    part, counts = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[pl.BlockSpec((te,), lambda i: (i,)),
-                   pl.BlockSpec((num_partitions,), lambda i: (0,))],
-        out_shape=[jax.ShapeDtypeStruct((m_pad,), jnp.int32),
-                   jax.ShapeDtypeStruct((num_partitions,), jnp.int32)],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-    )(*args)
-    return part[:m], counts
-
-
-# ---------------------------------------------------------------------------
-# boundary top-k selection
-# ---------------------------------------------------------------------------
-
-def _select_kernel(vp_ref, dr_ref, act_ref, rem_ref, rnd_ref, any_ref,
-                   idx_ref, val_ref, accv_scr, acci_scr, bsz_scr,
-                   *, k_sel: int, lam: float, ntiles: int):
-    j = pl.program_id(0)
-
-    @pl.when(j == 0)
-    def _init():
-        accv_scr[...] = jnp.full_like(accv_scr, -I32_INF)
-        acci_scr[...] = jnp.zeros_like(acci_scr)
-        bsz_scr[...] = jnp.zeros_like(bsz_scr)
-
-    vp = vp_ref[...]                                   # (C, TN)
-    dr = dr_ref[...]                                   # (TN,)
-    act = act_ref[...]                                 # (C,)
-    tn = dr.shape[0]
-    bnd = vp & (dr > 0)[None, :] & act[:, None]
-    bsz_scr[...] += bnd.sum(axis=1, keepdims=True).astype(jnp.int32)
-    scores = jnp.where(bnd, dr[None, :], I32_INF)
-    idx_t = (j * tn
-             + jax.lax.broadcasted_iota(jnp.int32, bnd.shape, 1))
-    # streaming merge: accumulator (earlier, lower indices) first, so
-    # top_k's lower-index-first tie-break matches the full-width oracle
-    cand_v = jnp.concatenate([accv_scr[...], -scores], axis=1)
-    cand_i = jnp.concatenate([acci_scr[...], idx_t], axis=1)
-    topv, pos = jax.lax.top_k(cand_v, k_sel)
-    accv_scr[...] = topv
-    acci_scr[...] = jnp.take_along_axis(cand_i, pos, axis=1)
-
-    @pl.when(j == ntiles - 1)
-    def _epilogue():
-        neg_top = accv_scr[...]
-        idx = acci_scr[...]
-        bsize = bsz_scr[...][:, 0]
-        col = jax.lax.broadcasted_iota(jnp.int32, neg_top.shape, 1)
-        k_eff = jnp.clip(jnp.ceil(lam * bsize).astype(jnp.int32), 1, k_sel)
-        valid = (neg_top > -I32_INF) & (col < k_eff[:, None])
-        cost = jnp.where(valid, -neg_top, 0)
-        fits = jnp.cumsum(cost, axis=1) <= rem_ref[...][:, None]
-        valid &= fits | (col == 0)
-        restart = (bsize == 0) & act & any_ref[0]
-        first = jnp.where(restart, rnd_ref[...].astype(jnp.int32),
-                          idx[:, 0])
-        idx = idx.at[:, 0].set(first)
-        valid = valid.at[:, 0].set(jnp.where(restart, True, valid[:, 0]))
-        valid &= act[:, None]
-        idx_ref[...] = idx
-        val_ref[...] = valid
-
-
-def select(vparts_c, active_c, degree_rest, lam: float, k_sel: int,
-           remaining_c, rnd_v, any_ok, block_n: int = 4096,
-           interpret: bool = True):
-    """Fused boundary selection for one (C, N) chunk of partitions.
-
-    ``rnd_v`` / ``any_ok`` are the pre-drawn restart vertices and the
-    global any-rest flag (PRNG stays outside the kernel — see ref.py).
-    Returns ``(idx, valid)`` of shape (C, k_sel).
-    """
-    c, n = vparts_c.shape
-    tn = min(block_n, n)
-    n_pad = -(-n // tn) * tn
-    if n_pad != n:
-        vparts_c = jnp.pad(vparts_c, ((0, 0), (0, n_pad - n)))
-        degree_rest = jnp.pad(degree_rest, (0, n_pad - n))
-    ntiles = n_pad // tn
-    idx, valid = pl.pallas_call(
-        functools.partial(_select_kernel, k_sel=k_sel, lam=lam,
-                          ntiles=ntiles),
-        grid=(ntiles,),
-        in_specs=[
-            pl.BlockSpec((c, tn), lambda j: (0, j)),
-            pl.BlockSpec((tn,), lambda j: (j,)),
-            pl.BlockSpec((c,), lambda j: (0,)),
-            pl.BlockSpec((c,), lambda j: (0,)),
-            pl.BlockSpec((c,), lambda j: (0,)),
-            pl.BlockSpec((1,), lambda j: (0,)),
-        ],
-        out_specs=[pl.BlockSpec((c, k_sel), lambda j: (0, 0)),
-                   pl.BlockSpec((c, k_sel), lambda j: (0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((c, k_sel), jnp.int32),
-                   jax.ShapeDtypeStruct((c, k_sel), jnp.bool_)],
-        scratch_shapes=[pltpu.VMEM((c, k_sel), jnp.int32),
-                        pltpu.VMEM((c, k_sel), jnp.int32),
-                        pltpu.VMEM((c, 1), jnp.int32)],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=interpret,
-    )(vparts_c, degree_rest, active_c, remaining_c,
-      rnd_v.astype(jnp.int32), jnp.reshape(any_ok, (1,)))
-    return idx, valid
-
-
-# ---------------------------------------------------------------------------
-# claim scatter-min
-# ---------------------------------------------------------------------------
-
-def _claim_kernel(idx_ref, val_ref, epp_ref, out_ref, *, p_num: int,
-                  n: int):
-    cap = (I32_INF - p_num) // p_num - 1
-    rows = jax.lax.broadcasted_iota(jnp.int32, idx_ref.shape, 0)
-    keys = jnp.minimum(epp_ref[...][:, None], cap) * p_num + rows
-    flat_v = jnp.where(val_ref[...], idx_ref[...], n).ravel()
-    vclaim = jnp.full((n,), I32_INF, jnp.int32)
-    out_ref[...] = vclaim.at[flat_v].min(keys.ravel(), mode="drop")
-
-
-def claim_scatter(sel_idx, sel_valid, edges_per_part, num_vertices: int,
-                  num_partitions: int, interpret: bool = True):
-    """Priority-encode + scatter-min (P, K) selections → (N,) claim keys.
-
-    P·K is tiny relative to N, so this is a single grid step; the fusion
-    win is skipping the materialized (P·K,) key/index intermediates.
-    """
-    p, k = sel_idx.shape
-    return pl.pallas_call(
-        functools.partial(_claim_kernel, p_num=num_partitions,
-                          n=num_vertices),
-        grid=(1,),
-        in_specs=[pl.BlockSpec((p, k), lambda i: (0, 0)),
-                  pl.BlockSpec((p, k), lambda i: (0, 0)),
-                  pl.BlockSpec((p,), lambda i: (0,))],
-        out_specs=pl.BlockSpec((num_vertices,), lambda i: (0,)),
-        out_shape=jax.ShapeDtypeStruct((num_vertices,), jnp.int32),
-        interpret=interpret,
-    )(sel_idx, sel_valid, edges_per_part)
-
-
-# ---------------------------------------------------------------------------
-# bit-packed replica sets
-# ---------------------------------------------------------------------------
 
 def _pack_kernel(b_ref, w_ref, *, w: int):
     b = b_ref[...]                                     # (TR, w*32)
-    tr = b.shape[0]
-    bits = jax.lax.broadcasted_iota(jnp.uint32, (tr, w, 32), 2)
-    w_ref[...] = (b.reshape(tr, w, 32).astype(jnp.uint32)
-                  << bits).sum(axis=-1, dtype=jnp.uint32)
+    bits = jax.lax.broadcasted_iota(jnp.int32, (b.shape[0], 32), 1)
+    # distinct powers of two never carry, so the int32 sum (Mosaic has no
+    # unsigned reductions) wraps to exactly the OR of the bits; one static
+    # 32-lane slice per word (Mosaic refuses the (TR, w, 32) reshape)
+    words = jnp.concatenate(
+        [(b[:, 32 * j:32 * (j + 1)].astype(jnp.int32) << bits)
+         .sum(axis=-1, keepdims=True) for j in range(w)], axis=1)
+    w_ref[...] = jax.lax.bitcast_convert_type(words, jnp.uint32)
 
 
 def _unpack_kernel(w_ref, b_ref, *, w: int):
@@ -287,17 +56,18 @@ def _or_kernel(a_ref, b_ref, o_ref):
     o_ref[...] = a_ref[...] | b_ref[...]
 
 
-def _row_tiles(n: int, block_rows: int):
-    tr = min(block_rows, n)
+def _row_tiles(n: int, w: int):
+    tr = min(max(ROW_ALIGN, TILE_ELEMS // (32 * w) // ROW_ALIGN * ROW_ALIGN),
+             n)
     n_pad = -(-n // tr) * tr
     return tr, n_pad
 
 
-def pack_bits(bools, block_rows: int = 4096, interpret: bool = True):
+def pack_bits(bools, interpret: bool = True):
     """(N, P) bool → (N, ceil(P/32)) uint32, LSB-first (see ref.py)."""
     n, p = bools.shape
     w = replica_words(p)
-    tr, n_pad = _row_tiles(n, block_rows)
+    tr, n_pad = _row_tiles(n, w)
     bp = jnp.pad(bools, ((0, n_pad - n), (0, w * 32 - p)))
     words = pl.pallas_call(
         functools.partial(_pack_kernel, w=w),
@@ -310,11 +80,10 @@ def pack_bits(bools, block_rows: int = 4096, interpret: bool = True):
     return words[:n]
 
 
-def unpack_bits(words, num_partitions: int, block_rows: int = 4096,
-                interpret: bool = True):
+def unpack_bits(words, num_partitions: int, interpret: bool = True):
     """(N, W) uint32 → (N, P) bool — inverse of ``pack_bits``."""
     n, w = words.shape
-    tr, n_pad = _row_tiles(n, block_rows)
+    tr, n_pad = _row_tiles(n, w)
     wp = jnp.pad(words, ((0, n_pad - n), (0, 0)))
     bools = pl.pallas_call(
         functools.partial(_unpack_kernel, w=w),
@@ -327,10 +96,10 @@ def unpack_bits(words, num_partitions: int, block_rows: int = 4096,
     return bools[:n, :num_partitions]
 
 
-def or_words(a, b, block_rows: int = 4096, interpret: bool = True):
+def or_words(a, b, interpret: bool = True):
     """Element-wise OR of two packed replica maps."""
     n, w = a.shape
-    tr, n_pad = _row_tiles(n, block_rows)
+    tr, n_pad = _row_tiles(n, w)
     ap = jnp.pad(a, ((0, n_pad - n), (0, 0)))
     bp = jnp.pad(b, ((0, n_pad - n), (0, 0)))
     out = pl.pallas_call(

@@ -1,18 +1,21 @@
-"""Front door for the ne_round kernel family.
+"""Front door for the ne_round packing kernels.
 
 Dispatch contract (mirrors ``block_spmm``/``flash_attention``, plus an
 impl override):
 
-* ``NEConfig.use_pallas`` decides whether the partitioners run the fused
-  family at all — and, in the SPMD round, whether replica sets are
-  bit-packed.  A ``None`` field resolves from ``REPRO_NE_KERNELS`` at
-  config construction (``env_enabled``), so the resolved config is
-  self-contained and its fingerprint stable.
-* ``REPRO_NE_KERNELS=ref`` keeps the family enabled but routes every op
-  to the XLA reference implementation — same packed representation, same
-  bits, no Pallas import.  The CI A/B lever and the escape hatch for
-  backends without Pallas.
-* Otherwise ops run the Pallas kernels, in interpret mode off-TPU.
+* ``NEConfig.use_pallas`` decides whether the SPMD round keeps its
+  replica sets bit-packed, through ``pack_bits``/``unpack_bits``/
+  ``or_words`` below.  A ``None`` field resolves from
+  ``REPRO_NE_KERNELS`` at config construction (``env_enabled``), so the
+  resolved config is self-contained and its fingerprint stable.
+* ``REPRO_NE_KERNELS=ref`` keeps the packed representation but routes the
+  ops to the XLA reference implementation — same bits, no Pallas import.
+  The CI A/B lever and the escape hatch for backends without Pallas.
+* Otherwise the ops run the Pallas kernels, in interpret mode off-TPU.
+
+The rest of the round (one-hop claims, boundary top-k, claim scatter-min)
+is XLA in ``repro.core.partitioner``: it needs a 1-D gather over an (N,)
+table, ``top_k`` and scatter-min, none of which Pallas TPU lowers.
 
 The Pallas module is imported lazily, only when a call actually
 dispatches to it — importing this module (and therefore
@@ -25,7 +28,6 @@ import os
 
 from repro.kernels.ne_round import ref
 from repro.kernels.ne_round.ref import (  # noqa: F401  (re-exports)
-    I32_INF,
     pack_bits_np,
     replica_words,
     unpack_bits_np,
@@ -54,34 +56,6 @@ def _pallas():
 def _interpret() -> bool:
     import jax
     return jax.default_backend() != "tpu"
-
-
-def one_hop(vclaim, u, v, edge_part, num_partitions: int, mask=None):
-    if use_ref_impl():
-        return ref.one_hop_ref(vclaim, u, v, edge_part, num_partitions,
-                               mask=mask)
-    return _pallas().one_hop(vclaim, u, v, edge_part, num_partitions,
-                             mask=mask, interpret=_interpret())
-
-
-def select_topk(vparts_c, active_c, degree_rest, lam: float, k_sel: int,
-                remaining_c, rnd_v, any_ok):
-    if use_ref_impl():
-        return ref.select_ref(vparts_c, active_c, degree_rest, lam, k_sel,
-                              remaining_c, rnd_v, any_ok)
-    return _pallas().select(vparts_c, active_c, degree_rest, lam, k_sel,
-                            remaining_c, rnd_v, any_ok,
-                            interpret=_interpret())
-
-
-def claim_scatter(sel_idx, sel_valid, edges_per_part, num_vertices: int,
-                  num_partitions: int):
-    if use_ref_impl():
-        return ref.claim_scatter_ref(sel_idx, sel_valid, edges_per_part,
-                                     num_vertices, num_partitions)
-    return _pallas().claim_scatter(sel_idx, sel_valid, edges_per_part,
-                                   num_vertices, num_partitions,
-                                   interpret=_interpret())
 
 
 def pack_bits(bools):

@@ -1,17 +1,10 @@
-"""Plain-XLA reference implementations of the ne_round kernel family.
+"""Plain-XLA and numpy twins of the ne_round packing kernels.
 
-These are the oracle *and* the fallback execution path: every function is
-the exact jnp computation the fused Pallas kernels in ``ne_round.py``
-must reproduce bit-for-bit (all-integer math — no tolerance), asserted by
-tests/test_kernels.py and the partitioner bit-identity checks.  The front
-door in ``ops.py`` dispatches here under ``REPRO_NE_KERNELS=ref``; the
-Pallas kernels themselves run in interpret mode off-TPU, so CPU CI
-exercises both sides of every pairing.
-
-The module is deliberately self-contained (jax/numpy only, no imports
-from ``repro.core``): ``core.partitioner`` imports the ops front door, so
-an import back into core would be a cycle.  ``_enc`` mirrors
-``core.partitioner.priority_enc`` and the pairing is pinned by tests.
+The XLA functions are the oracle the Pallas kernels in ``ne_round.py``
+must reproduce bit-for-bit (all-integer math — no tolerance), and the
+execution path under ``REPRO_NE_KERNELS=ref``; the Pallas kernels run in
+interpret mode off-TPU, so CPU CI exercises both sides of every pairing.
+The numpy twins unpack a device result on the host.
 
 Bit-packing convention (shared with the Pallas kernels and the host-side
 numpy helpers): partition ``p`` lives at bit ``p % 32`` (LSB-first) of
@@ -19,86 +12,8 @@ word ``p // 32`` — ``words`` has shape ``(N, ceil(P/32))`` uint32.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 import numpy as np
-
-I32_INF = np.iinfo(np.int32).max
-
-
-def _enc(count, p, num_partitions: int):
-    """Priority key — kept in lockstep with core.partitioner.priority_enc
-    (smaller edge count wins, then smaller partition id)."""
-    cap = (I32_INF - num_partitions) // num_partitions - 1
-    return jnp.minimum(count, cap) * num_partitions + p
-
-
-# ---------------------------------------------------------------------------
-# one-hop allocation
-# ---------------------------------------------------------------------------
-
-def one_hop_ref(vclaim, u, v, edge_part, num_partitions: int, mask=None):
-    """Fused one-hop allocation oracle.
-
-    Per edge: ``k = min(vclaim[u], vclaim[v])``; an unallocated edge joins
-    partition ``k % P`` when some endpoint was claimed.  Equals the
-    CSR-slot ``segment_min`` chain of ``core.partitioner._round`` because
-    every undirected edge owns exactly two directed slots (one per
-    endpoint).  Returns ``(part, counts)``: (M,) int32 with ``-1`` for
-    untouched edges, and the (P,) int32 histogram of new allocations.
-    """
-    k_uv = jnp.minimum(vclaim[u], vclaim[v])
-    new = (edge_part < 0) & (k_uv < I32_INF)
-    if mask is not None:
-        new &= mask
-    part = jnp.where(new, (k_uv % num_partitions).astype(jnp.int32), -1)
-    counts = jnp.zeros((num_partitions,), jnp.int32).at[
-        jnp.maximum(part, 0)].add(new.astype(jnp.int32))
-    return part, counts
-
-
-# ---------------------------------------------------------------------------
-# boundary top-k selection
-# ---------------------------------------------------------------------------
-
-def select_ref(vparts_c, active_c, degree_rest, lam: float, k_sel: int,
-               remaining_c, rnd_v, any_ok):
-    """Selection for one chunk of partitions — the math of
-    ``core.partitioner.select_chunk`` with the PRNG re-seed draw hoisted
-    out (``rnd_v`` (C,) pre-drawn random restart vertices, ``any_ok``
-    scalar ``(degree_rest > 0).any()``), so the kernel never has to
-    reproduce ``jax.random`` bit patterns.
-    """
-    bnd = vparts_c & (degree_rest > 0)[None, :] & active_c[:, None]
-    bsize = bnd.sum(axis=1)
-    k_eff = jnp.clip(jnp.ceil(lam * bsize).astype(jnp.int32), 1, k_sel)
-    scores = jnp.where(bnd, degree_rest[None, :], I32_INF)
-    neg_top, idx = jax.lax.top_k(-scores, k_sel)
-    valid = (neg_top > -I32_INF) & (jnp.arange(k_sel)[None, :]
-                                    < k_eff[:, None])
-    cost = jnp.where(valid, -neg_top, 0)
-    fits = jnp.cumsum(cost, axis=1) <= remaining_c[:, None]
-    valid &= fits | (jnp.arange(k_sel)[None, :] == 0)
-    restart = (bsize == 0) & active_c & any_ok
-    first = jnp.where(restart, rnd_v.astype(jnp.int32), idx[:, 0])
-    idx = idx.at[:, 0].set(first)
-    valid = valid.at[:, 0].set(jnp.where(restart, True, valid[:, 0]))
-    valid &= active_c[:, None]
-    return idx, valid
-
-
-def claim_scatter_ref(sel_idx, sel_valid, edges_per_part,
-                      num_vertices: int, num_partitions: int):
-    """Priority-encode + scatter-min the selections into per-vertex claim
-    keys: ``vclaim[v] = min over claiming partitions of enc(|E_p|, p)``,
-    ``I32_INF`` where nobody claimed ``v``."""
-    rows = jnp.broadcast_to(
-        jnp.arange(num_partitions, dtype=jnp.int32)[:, None],
-        sel_idx.shape)
-    keys = _enc(edges_per_part[:, None], rows, num_partitions)
-    flat_v = jnp.where(sel_valid, sel_idx, num_vertices).ravel()
-    vclaim = jnp.full((num_vertices,), I32_INF, jnp.int32)
-    return vclaim.at[flat_v].min(keys.ravel(), mode="drop")
 
 
 # ---------------------------------------------------------------------------

@@ -23,7 +23,6 @@ from pathlib import Path  # noqa: E402
 import jax           # noqa: E402
 
 from repro.configs.registry import ARCH_IDS, all_cells, get_arch  # noqa: E402
-from repro.dist import compat  # noqa: E402
 from repro.dist.context import mesh_context  # noqa: E402
 from repro.launch.hlo import collective_bytes, roofline  # noqa: E402
 from repro.launch.mesh import make_production_mesh  # noqa: E402
@@ -40,7 +39,7 @@ def run_cell(arch_id: str, shape_id: str, multi_pod: bool,
     batch_axes = ("pod", "data") if multi_pod else ("data",)
     t0 = time.time()
     with mesh_context(mesh, batch_axes=batch_axes, model_axis="model"), \
-            compat.set_mesh(mesh):
+            jax.sharding.set_mesh(mesh):
         if spec.family == "lm" and rules_override is not None:
             from repro.launch.steps import make_lm_step
             bundle = make_lm_step(spec.config,

@@ -28,8 +28,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.dist import compat
-
 from repro.apps import engine as eng
 from repro.models.common import mlp_apply
 
@@ -290,7 +288,8 @@ def eqv2_forward(params, a, caps, cfg, axis, edge_chunk: int = 16384):
             return smax, None
 
         idxs = jnp.arange(nch * edge_chunk).reshape(nch, edge_chunk)
-        init_smax = compat.pvary(jnp.full((r, hh), -jnp.inf), axis)
+        init_smax = jax.lax.pcast(jnp.full((r, hh), -jnp.inf), axis,
+                                  to="varying")
         smax_m, _ = jax.lax.scan(score_chunk, init_smax, idxs)
         smax_o = _reduce(smax_m, a, caps, axis, "max", -jnp.inf)
         smax_o = jnp.where(jnp.isfinite(smax_o), smax_o, 0.0)
@@ -313,7 +312,7 @@ def eqv2_forward(params, a, caps, cfg, axis, edge_chunk: int = 16384):
             return (acc, wsum), None
 
         init_acc = jax.tree.map(
-            lambda x: compat.pvary(x, axis),
+            lambda x: jax.lax.pcast(x, axis, to="varying"),
             (jnp.zeros((r, k * c)), jnp.zeros((r, hh))))
         (acc_m, wsum_m), _ = jax.lax.scan(msg_chunk, init_acc, idxs)
         agg = _reduce(acc_m, a, caps, axis).reshape(o, k, hh, c // hh)
@@ -376,7 +375,7 @@ def make_engine_loss(model_module: str, cfg, caps: EngineCaps, mesh,
     aspec = P(dev_axes)
 
     def loss_fn(params, arrays):
-        return compat.shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(), jax.tree.map(lambda _: aspec, arrays)),
             out_specs=P(),

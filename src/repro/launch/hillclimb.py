@@ -16,7 +16,6 @@ import jax           # noqa: E402
 
 from repro.configs.registry import get_arch  # noqa: E402
 from repro.configs.shapes import FAMILY_SHAPES  # noqa: E402
-from repro.dist import compat  # noqa: E402
 from repro.dist.context import mesh_context  # noqa: E402
 from repro.launch.hlo import (ICI_BW, collective_bytes_scoped,  # noqa: E402
                               roofline)
@@ -60,7 +59,7 @@ def lm_cell(arch, shape_id, multi_pod=False, **overrides):
     mesh = make_production_mesh(multi_pod=multi_pod)
     ba = ("pod", "data") if multi_pod else ("data",)
     shape = dict(FAMILY_SHAPES["lm"][shape_id])
-    with mesh_context(mesh, ba, "model"), compat.set_mesh(mesh):
+    with mesh_context(mesh, ba, "model"), jax.sharding.set_mesh(mesh):
         b = make_lm_step(spec.config, shape, mesh, multi_pod, **overrides)
         return compile_and_measure(b, mesh, mesh.size)
 
@@ -70,7 +69,7 @@ def gnn_cell(arch, shape_id, multi_pod=False, **overrides):
     mesh = make_production_mesh(multi_pod=multi_pod)
     ba = ("pod", "data") if multi_pod else ("data",)
     shape = dict(FAMILY_SHAPES["gnn"][shape_id])
-    with mesh_context(mesh, ba, "model"), compat.set_mesh(mesh):
+    with mesh_context(mesh, ba, "model"), jax.sharding.set_mesh(mesh):
         b = make_gnn_step(spec, spec.config, shape, mesh, multi_pod,
                           **overrides)
         return compile_and_measure(b, mesh, mesh.size)

@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.dist import compat
 from repro.dist.context import get_mesh_ctx
 from repro.dist.sharding import Rules
 from repro.models.common import dense_init
@@ -169,7 +168,7 @@ def moe_block(p, x, cfg: MoEConfig, rules: Rules):
     wspec = P(ctx.model_axis, ctx.batch_axes, None)
     # check_vma=False: the FSDP all_gather output *is* invariant over the
     # batch axes but vma inference can't statically prove it.
-    y, aux = compat.shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(bspec, P(), wspec, wspec, wspec),
         out_specs=(bspec, P(batch_axes)), check_vma=False,

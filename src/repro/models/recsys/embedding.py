@@ -17,7 +17,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.dist import compat
 from repro.dist.context import get_mesh_ctx
 
 Array = jax.Array
@@ -84,7 +83,7 @@ def sharded_lookup(table: Array, ids: Array) -> Array:
         emb = jnp.where(hit[..., None], emb, 0.0)
         return jax.lax.psum(emb, ctx.model_axis)
 
-    return compat.shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(ctx.model_axis, None), bspec),
         out_specs=P(ba, *([None] * ids.ndim)),

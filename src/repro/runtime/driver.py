@@ -54,10 +54,11 @@ from repro.core.partitioner import (NEConfig, NEState, PartitionResult,
                                     alpha_limit, finalize_result, ne_done,
                                     ne_init_state, ne_round_step)
 from repro.dist import compat
-from repro.dist.partitioner_sm import (AXIS, SpmdState, round_quality,
-                                       round_sync_payload_bytes, spmd_done,
-                                       spmd_init_state, spmd_round_step,
-                                       stitch_edge_part)
+from repro.dist.partitioner_sm import (AXIS, SpmdState, place_state,
+                                       round_quality,
+                                       round_sync_payload_bytes, shard_over,
+                                       spmd_done, spmd_init_state,
+                                       spmd_round_step, stitch_edge_part)
 from repro.io.edgefile import EdgeFile
 from repro.kernels.ne_round import ops as ne_ops
 from repro.io.stream import require_canonical
@@ -156,10 +157,11 @@ class PartitionDriver:
                 self.limit = alpha_limit(self.cfg.alpha, self.m,
                                          self.cfg.num_partitions)
                 self.mesh = make_edge_mesh(self.num_devices, axis=AXIS)
-                self._u_sh = jnp.asarray(shards[:, :, 0])
-                self._v_sh = jnp.asarray(shards[:, :, 1])
-                self._mask_sh = jnp.asarray(masks)
-                self.state = spmd_init_state(shards, masks, self.n, self.cfg)
+                self._u_sh = shard_over(self.mesh, shards[:, :, 0])
+                self._v_sh = shard_over(self.mesh, shards[:, :, 1])
+                self._mask_sh = shard_over(self.mesh, masks)
+                self.state = spmd_init_state(shards, masks, self.n, self.cfg,
+                                             self.mesh)
 
         # per-round SyncVertexAllocations traffic (per device) — a pure
         # function of the config, recorded as a cumulative trace counter
@@ -529,7 +531,9 @@ class PartitionDriver:
                 # in-memory stitch + re-split is the honest path)
                 fields["edge_part"] = self._reshard_in_memory(
                     np.asarray(fields["edge_part"]))
-        self.state = cls(**{k: jnp.asarray(fields[k]) for k in want})
+        state = cls(**{k: np.asarray(fields[k]) for k in want})
+        self.state = (place_state(self.mesh, state) if self.mode == "spmd"
+                      else jax.tree.map(jnp.asarray, state))
         self._result = None
         self._final_slices = None
         self._done = None

@@ -56,7 +56,7 @@ from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.dist import compat
-from repro.dist.partitioner_sm import SpmdState
+from repro.dist.partitioner_sm import SpmdState, empty_vparts
 
 EXIT_FAULT = 17  # what an injected crash (test hook) exits with
 
@@ -69,14 +69,10 @@ def initialize_distributed(
     """Bring up ``jax.distributed`` for this worker.
 
     Must run before anything queries devices.  On the CPU backend,
-    cross-process collectives need the gloo implementation; the config
-    knob is set failure-tolerantly because accelerator backends (and
-    future jaxlibs) pick their own.
+    cross-process collectives need the gloo implementation; accelerator
+    backends ignore the knob and use their own.
     """
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:
-        pass
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(
         coordinator_address=coordinator,
         num_processes=num_processes,
@@ -128,8 +124,7 @@ def replicate(mesh, x):
 
     The value must be identical on every process (all replicated round
     state is — it is derived deterministically from the shared plan).
-    Built from explicit per-device copies instead of a bare
-    ``device_put`` so it works on every jaxlib the repo supports.
+    Built from one copy per local device of the mesh.
     """
     x = np.asarray(x)
     pid = compat.process_env()[0]
@@ -178,14 +173,9 @@ def spmd_init_state_global(
         (cap,),
         np.int32,
     )
-    if getattr(cfg, "use_pallas", False):
-        from repro.kernels.ne_round import ops as ne_ops
-        vp0 = np.zeros((n, ne_ops.replica_words(p_num)), np.uint32)
-    else:
-        vp0 = np.zeros((n, p_num), bool)
     return SpmdState(
         edge_part=edge_part,
-        vparts=replicate(mesh, vp0),
+        vparts=replicate(mesh, empty_vparts(n, cfg, np)),
         degree_rest=replicate(mesh, degree.astype(np.int32)),
         edges_per_part=replicate(mesh, np.zeros((p_num,), np.int32)),
         key=replicate(mesh, np.asarray(jax.random.PRNGKey(cfg.seed))),
@@ -208,6 +198,9 @@ def worker_main(ns) -> int:
     round stepping with per-host snapshot writes → finalize → process 0
     publishes ``result.npz`` + ``timing.json`` under ``--out``.
     """
+    from repro import compile_cache
+
+    compile_cache.enable()
     initialize_distributed(ns.coordinator, ns.num_processes, ns.process_id)
     from repro.core.partitioner import NEConfig
     from repro.io.edgefile import EdgeFile
@@ -327,6 +320,8 @@ def worker_main(ns) -> int:
                 os._exit(EXIT_FAULT)
         res = drv.finalize()
         extra["rounds"] = int(res.rounds)
+        extra["platform"] = jax.devices()[0].platform
+        extra["device_kind"] = jax.devices()[0].device_kind
         if res.stats is not None:
             # quality metrics from the sharded epilogue's (P,)-sized
             # partials — computed without the global assignment
@@ -380,16 +375,16 @@ def free_port() -> int:
 
 
 def child_env(devices_per_process: int, extra: dict | None = None) -> dict:
-    """Worker environment: force the per-process device count (replacing
-    any inherited forcing, e.g. CI's 8-device tier-1 env), default to the
-    CPU backend, and make ``repro`` importable."""
+    """Worker environment: force the per-process host device count
+    (replacing any inherited forcing, e.g. CI's 8-device tier-1 env) and
+    make ``repro`` importable.  The platform is inherited, never
+    defaulted: a worker on a chip host partitions on the chip."""
     env = dict(os.environ)
     flags = _FORCE_DEVICES.sub("", env.get("XLA_FLAGS", "")).strip()
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={devices_per_process} "
         + flags
     ).strip()
-    env.setdefault("JAX_PLATFORMS", "cpu")
     src = str(Path(__file__).resolve().parents[2])
     env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
     if extra:
@@ -416,7 +411,19 @@ def launch_local(
     — SIGTERM, then SIGKILL after ``grace`` — because a surviving peer is
     blocked in a collective whose counterpart died.  Returns the overall
     exit code (first nonzero, 0 if all clean) and each worker's log.
+
+    One process per chip: a worker that reaches an accelerator takes
+    every local chip, so several local workers run only pinned to the
+    CPU (``JAX_PLATFORMS=cpu``, the local stand-in for N machines); on a
+    chip host, ``num_processes=1`` runs one worker that owns every chip.
     """
+    env = child_env(devices_per_process)
+    if num_processes > 1 and env.get("JAX_PLATFORMS", "") != "cpu":
+        raise ValueError(
+            f"{num_processes} local workers would share this host's "
+            "chips: run one process (it owns every local chip), or pin "
+            "the gang to the CPU with JAX_PLATFORMS=cpu"
+        )
     coordinator = coordinator or f"127.0.0.1:{free_port()}"
     # worker output always goes to files, never PIPE: the monitor loop
     # below doesn't drain pipes, and a worker that filled the OS pipe
@@ -425,7 +432,6 @@ def launch_local(
         log_dir = tempfile.mkdtemp(prefix="multihost_logs_")
     log_dir = Path(log_dir)
     log_dir.mkdir(parents=True, exist_ok=True)
-    env = child_env(devices_per_process)
     procs, logs = [], []
     for i in range(num_processes):
         cmd = worker_argv + [

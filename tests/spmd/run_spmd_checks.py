@@ -66,7 +66,7 @@ def _or_body(b):
     return ne_ops.unpack_bits(red, 37)[None]
 
 
-or_out = compat.shard_map(
+or_out = jax.shard_map(
     _or_body, mesh=mesh_or, in_specs=(PSpec("shard", None, None),),
     out_specs=PSpec("shard", None, None), check_vma=False,
 )(jax.numpy.asarray(bool_sh))
@@ -176,7 +176,7 @@ ref_logits, _, _ = tfm.decode(lp, tok, (kc, vc), clen, lcfg)
 rules = lm_rules(batch_axes=(), tp="model", q_ok=True, kv_ok=False,
                  seq_kv_axes=("data", "model"))
 cache_sh = NamedSharding(mesh2, rules["kv_cache"])
-with compat.set_mesh(mesh2):
+with jax.sharding.set_mesh(mesh2):
     kc_s = jax.device_put(kc, cache_sh)
     vc_s = jax.device_put(vc, cache_sh)
     sh_logits, _, _ = jax.jit(
@@ -195,7 +195,7 @@ mp = init_moe(jax.random.PRNGKey(6), 24, mcfg, jnp.float32)
 xm = jax.random.normal(jax.random.PRNGKey(7), (4, 6, 24))
 y_dense, aux_dense = moe_block(mp, xm, mcfg, None)
 with mesh_context(mesh2, batch_axes=("data",), model_axis="model"), \
-        compat.set_mesh(mesh2):
+        jax.sharding.set_mesh(mesh2):
     y_ep, aux_ep = jax.jit(lambda p, x: moe_block(p, x, mcfg, None))(mp, xm)
 out["moe_ep_err"] = float(jnp.abs(y_dense - y_ep).max())
 out["moe_aux_err"] = float(jnp.abs(aux_dense - aux_ep))

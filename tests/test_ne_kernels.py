@@ -1,9 +1,12 @@
-"""ne_round kernel family: Pallas (interpret) vs XLA ref vs the live
-partitioner chains — all-integer math, so every comparison is exact.
+"""ne_round kernel family: the XLA edge-list round (``one_hop``,
+``select_chunk``, ``vertex_claims``) vs independent oracles, and the
+Pallas (interpret) packing kernels vs their XLA and numpy twins —
+all-integer math, so every comparison is exact.
 
 Separate from test_kernels.py so none of this skips when hypothesis is
 absent; the fuzz test guards its own import.
 """
+import dataclasses
 import subprocess
 import sys
 from pathlib import Path
@@ -13,12 +16,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.partitioner import (I32_INF, NEConfig, boundary_reseed,
-                                    partition, priority_enc, select_chunk,
+from repro.core.graph import as_graph
+from repro.core.partitioner import (I32_INF, NEConfig, one_hop, partition,
+                                    priority_enc, select_chunk,
                                     vertex_claims)
+from repro.dist.partitioner_sm import partition_spmd
 from repro.graphs.generators import barabasi_albert
 from repro.graphs.rmat import rmat
-from repro.kernels.ne_round import ne_round as ne_pl
 from repro.kernels.ne_round import ops as ne_ops
 from repro.kernels.ne_round import ref as ne_ref
 
@@ -37,103 +41,134 @@ def _rand_graph(n, m, seed):
 # one-hop allocation
 # --------------------------------------------------------------------------
 
-@pytest.mark.parametrize("n,m,p,seed", [(50, 200, 4, 0), (300, 1000, 8, 1),
-                                        (128, 500, 16, 2)])
-def test_one_hop_pallas_matches_ref(n, m, p, seed):
-    rng = np.random.default_rng(seed)
-    e = _rand_graph(n, m, seed)
-    u, v = jnp.asarray(e[:, 0]), jnp.asarray(e[:, 1])
-    # claim keys: mostly unclaimed, a few priority_enc-style small keys
-    vclaim = np.full(n, I32_INF, np.int32)
-    claimed = rng.integers(0, n, n // 3)
-    vclaim[claimed] = rng.integers(0, 1000, claimed.size)
-    ep = jnp.asarray(np.where(rng.random(e.shape[0]) < 0.3, 0, -1)
-                     .astype(np.int32))
-    mask = jnp.asarray(rng.random(e.shape[0]) < 0.9)
-    for mk in (None, mask):
-        got = ne_pl.one_hop(jnp.asarray(vclaim), u, v, ep, p, mask=mk,
-                            block_edges=128, interpret=True)
-        want = ne_ref.one_hop_ref(jnp.asarray(vclaim), u, v, ep, p, mask=mk)
-        np.testing.assert_array_equal(np.asarray(got[0]),
-                                      np.asarray(want[0]))
-        np.testing.assert_array_equal(np.asarray(got[1]),
-                                      np.asarray(want[1]))
+def _csr_chain_one_hop(g, vclaim, edge_part, p, mask=None):
+    """Oracle: the min claim key over each edge's two directed CSR slots
+    (``segment_min`` over 2M slots back to M edges)."""
+    m = g.num_edges
+    slot_key = vclaim[g.slot_src]
+    slot_ok = (slot_key < I32_INF) & (edge_part[g.adj_eid] < 0)
+    if mask is not None:
+        slot_ok &= mask[g.adj_eid]
+    ekey = jax.ops.segment_min(jnp.where(slot_ok, slot_key, I32_INF),
+                               g.adj_eid, num_segments=m)
+    new = ekey < I32_INF
+    part = jnp.where(new, ekey % p, -1)
+    counts = jnp.zeros((p,), jnp.int32).at[jnp.where(new, part, 0)].add(
+        new.astype(jnp.int32))
+    return part, counts
 
 
-def test_one_hop_matches_segment_min_chain():
-    """The fused edge-list kernel == the CSR-slot segment_min chain of
-    core.partitioner._round (each undirected edge owns two slots)."""
-    from repro.core.graph import as_graph
-
-    g = as_graph(barabasi_albert(200, 3, seed=3))
+@pytest.mark.parametrize("masked", [False, True], ids=["all", "masked"])
+@pytest.mark.parametrize("graph,p,seed", [
+    ("rand-50-200", 4, 0), ("rand-300-1000", 8, 1), ("rand-128-500", 16, 2),
+    ("ba-200-3", 8, 4)])
+def test_one_hop_matches_segment_min_chain(graph, p, seed, masked):
+    """The edge-list one-hop == the CSR-slot segment_min chain (each
+    undirected edge owns two slots), with and without the SPMD padding
+    mask."""
+    kind, n, m = graph.split("-")
+    e = (barabasi_albert(int(n), int(m), seed=3) if kind == "ba"
+         else _rand_graph(int(n), int(m), seed))
+    g = as_graph(e)
     n, m = g.num_vertices, g.num_edges
-    rng = np.random.default_rng(4)
+    rng = np.random.default_rng(seed)
+    # claim keys: unclaimed or priority_enc keys of a few partitions
     vclaim = np.full(n, I32_INF, np.int32)
     cl = rng.integers(0, n, n // 2)
     vclaim[cl] = priority_enc(jnp.asarray(rng.integers(0, 50, cl.size)),
-                              jnp.asarray(rng.integers(0, 8, cl.size)), 8)
+                              jnp.asarray(rng.integers(0, p, cl.size)), p)
     vclaim = jnp.asarray(vclaim)
-    ep = jnp.asarray(np.where(rng.random(m) < 0.4, 2, -1).astype(np.int32))
-    slot_key = vclaim[g.slot_src]
-    slot_ok = (slot_key < I32_INF) & (ep[g.adj_eid] < 0)
-    ekey = jax.ops.segment_min(jnp.where(slot_ok, slot_key, I32_INF),
-                               g.adj_eid, num_segments=m)
-    want_part = jnp.where(ekey < I32_INF, ekey % 8, -1)
-    got_part, got_counts = ne_ops.one_hop(
-        vclaim, g.edges[:, 0], g.edges[:, 1], ep, 8)
+    ep = jnp.asarray(np.where(rng.random(m) < 0.3, 0, -1).astype(np.int32))
+    mask = jnp.asarray(rng.random(m) < 0.9) if masked else None
+    got_part, got_counts = one_hop(vclaim, g.edges[:, 0], g.edges[:, 1], ep,
+                                   p, mask=mask)
+    want_part, want_counts = _csr_chain_one_hop(g, vclaim, ep, p, mask=mask)
     np.testing.assert_array_equal(np.asarray(got_part),
                                   np.asarray(want_part))
-    assert int(got_counts.sum()) == int((np.asarray(want_part) >= 0).sum())
+    np.testing.assert_array_equal(np.asarray(got_counts),
+                                  np.asarray(want_counts))
 
 
 # --------------------------------------------------------------------------
-# boundary selection + claim scatter
+# boundary selection + claims
 # --------------------------------------------------------------------------
+
+def _np_select(vparts_c, active_c, degree_rest, lam, k_sel, remaining_c):
+    """Oracle for the deterministic part of ``select_chunk``: per
+    partition, its k_eff smallest-D_rest boundary vertices (ties by vertex
+    id) whose D_rest prefix sum fits the remaining capacity (the first
+    always).  Returns a list of selected-vertex lists, None for a row
+    whose boundary is empty (a random re-seed)."""
+    out = []
+    for bnd_row, act, rem in zip(vparts_c, active_c, remaining_c):
+        if not act:
+            out.append([])
+            continue
+        cand = np.flatnonzero(bnd_row & (degree_rest > 0))
+        if cand.size == 0:
+            out.append(None)
+            continue
+        order = cand[np.lexsort((cand, degree_rest[cand]))]
+        k_eff = int(np.clip(np.ceil(np.float32(lam) * np.float32(cand.size)),
+                            1, k_sel))
+        order = order[:k_eff]
+        fits = np.cumsum(degree_rest[order]) <= rem
+        fits[0] = True
+        out.append(order[fits].tolist())
+    return out
+
 
 @pytest.mark.parametrize("n,c,k_sel,seed", [(100, 4, 16, 0), (600, 8, 64, 1),
                                             (257, 3, 32, 2)])
-def test_select_pallas_matches_select_chunk(n, c, k_sel, seed):
+def test_select_chunk_matches_numpy_oracle(n, c, k_sel, seed):
+    """``select_chunk`` == a numpy sort-and-prefix oracle; an empty
+    boundary re-seeds from a vertex that still has unallocated edges."""
     rng = np.random.default_rng(seed)
-    vparts_c = jnp.asarray(rng.random((c, n)) < 0.15)
-    active_c = jnp.asarray(rng.random(c) < 0.8)
-    degree_rest = jnp.asarray(rng.integers(0, 20, n).astype(np.int32))
-    remaining_c = jnp.asarray(rng.integers(0, 200, c).astype(np.int32))
+    vparts_c = rng.random((c, n)) < 0.15
+    vparts_c[0] = False                       # one re-seed row
+    active_c = rng.random(c) < 0.8
+    active_c[0] = True
+    degree_rest = rng.integers(0, 20, n).astype(np.int32)
+    remaining_c = rng.integers(0, 200, c).astype(np.int32)
     keys_c = jax.vmap(jax.random.PRNGKey)(jnp.arange(c) + seed)
-    want_idx, want_val = select_chunk(vparts_c, active_c, degree_rest, 0.5,
-                                      k_sel, keys_c, remaining_c)
-    rnd_v, any_ok = boundary_reseed(degree_rest, keys_c)
-    got_idx, got_val = ne_pl.select(vparts_c, active_c, degree_rest, 0.5,
-                                    k_sel, remaining_c, rnd_v, any_ok,
-                                    block_n=64, interpret=True)
-    np.testing.assert_array_equal(np.asarray(got_val), np.asarray(want_val))
-    # invalid slots never feed downstream; valid ones must agree exactly
-    np.testing.assert_array_equal(
-        np.asarray(jnp.where(got_val, got_idx, -1)),
-        np.asarray(jnp.where(want_val, want_idx, -1)))
-    # claims built from each must agree (full downstream equivalence)
-    epp = jnp.asarray(rng.integers(0, 100, c).astype(np.int32))
-    got_claim = ne_pl.claim_scatter(got_idx, got_val, epp, n, c,
-                                    interpret=True)
-    want_claim = ne_ref.claim_scatter_ref(want_idx, want_val, epp, n, c)
-    np.testing.assert_array_equal(np.asarray(got_claim),
-                                  np.asarray(want_claim))
+    idx, valid = select_chunk(jnp.asarray(vparts_c), jnp.asarray(active_c),
+                              jnp.asarray(degree_rest), 0.5, k_sel, keys_c,
+                              jnp.asarray(remaining_c))
+    idx, valid = np.asarray(idx), np.asarray(valid)
+    want = _np_select(vparts_c, active_c, degree_rest, 0.5, k_sel,
+                      remaining_c)
+    for row, w in enumerate(want):
+        got = idx[row][valid[row]].tolist()
+        if w is None:
+            assert len(got) == 1 and degree_rest[got[0]] > 0
+        else:
+            assert got == w, row
 
 
-def test_vertex_claims_bit_identical():
-    """End-to-end vertex_claims: pallas-config == xla-config, same state."""
+@pytest.mark.parametrize("sel_chunk", [1, 3, 8])
+def test_vertex_claims_matches_numpy_oracle(sel_chunk):
+    """``vertex_claims`` == the scatter-min of priority keys over each
+    partition's ``select_chunk`` selection, for any selection chunking
+    (every partition draws from its own fold_in key)."""
     rng = np.random.default_rng(7)
     n, p = 400, 8
     vparts = jnp.asarray(rng.random((n, p)) < 0.1)
     degree_rest = jnp.asarray(rng.integers(0, 15, n).astype(np.int32))
-    epp = jnp.asarray(rng.integers(0, 300, p).astype(np.int32))
+    epp = rng.integers(0, 300, p).astype(np.int32)
     sub = jax.random.PRNGKey(9)
-    kw = dict(num_partitions=p, seed=0, k_sel=32)
-    ref_claims = vertex_claims(NEConfig(use_pallas=False, **kw), 500,
-                               vparts, degree_rest, epp, sub)
-    pal_claims = vertex_claims(NEConfig(use_pallas=True, **kw), 500,
-                               vparts, degree_rest, epp, sub)
-    np.testing.assert_array_equal(np.asarray(ref_claims),
-                                  np.asarray(pal_claims))
+    cfg = NEConfig(num_partitions=p, seed=0, k_sel=32, sel_chunk=sel_chunk)
+    got = np.asarray(vertex_claims(cfg, 500, vparts, degree_rest,
+                                   jnp.asarray(epp), sub))
+    keys = jax.vmap(lambda i: jax.random.fold_in(sub, i))(
+        jnp.arange(p, dtype=jnp.int32))
+    idx, valid = select_chunk(vparts.T, jnp.asarray(epp <= 500), degree_rest,
+                              cfg.lam, cfg.k_sel, keys,
+                              jnp.asarray(500 - epp))
+    want = np.full(n, I32_INF, np.int64)
+    for part in range(p):
+        sel = np.asarray(idx[part])[np.asarray(valid[part])]
+        np.minimum.at(want, sel, int(priority_enc(epp[part], part, p)))
+    np.testing.assert_array_equal(got, want)
 
 
 # --------------------------------------------------------------------------
@@ -195,28 +230,38 @@ def test_pack_fuzz_odd_widths():
 # --------------------------------------------------------------------------
 
 def test_partition_pallas_bit_identical_rmat():
+    """Packed replica sets (use_pallas) == bool ones, through the SPMD
+    round on one device."""
     g = rmat(10, 8, seed=13)
-    kw = dict(num_partitions=8, seed=0, k_sel=64, edge_chunk=1 << 12)
-    r0 = partition(g, NEConfig(use_pallas=False, **kw))
-    r1 = partition(g, NEConfig(use_pallas=True, **kw))
+    cfg = NEConfig(num_partitions=8, seed=0, k_sel=64, edge_chunk=1 << 12,
+                   use_pallas=False)
+    r0 = partition_spmd(g, cfg, num_devices=1)
+    r1 = partition_spmd(g, dataclasses.replace(cfg, use_pallas=True),
+                        num_devices=1)
     np.testing.assert_array_equal(r0.edge_part, r1.edge_part)
     np.testing.assert_array_equal(r0.vparts, r1.vparts)
     np.testing.assert_array_equal(r0.edges_per_part, r1.edges_per_part)
     assert r0.rounds == r1.rounds
+    # on one device the SPMD round is the single-controller round, which
+    # ignores use_pallas
+    r2 = partition(g, dataclasses.replace(cfg, use_pallas=True))
+    np.testing.assert_array_equal(r2.edge_part, r0.edge_part)
+    assert r2.rounds == r0.rounds
 
 
 def test_ref_impl_env_switch(monkeypatch):
-    """REPRO_NE_KERNELS=ref enables the family but routes to pure XLA."""
+    """REPRO_NE_KERNELS=ref enables packing but routes it to pure XLA."""
     monkeypatch.setenv("REPRO_NE_KERNELS", "ref")
     assert ne_ops.env_enabled() and ne_ops.use_ref_impl()
     cfg = NEConfig(num_partitions=4)
     assert cfg.use_pallas is True
     g = barabasi_albert(120, 3, seed=1)
-    r_env = partition(g, NEConfig(num_partitions=4, seed=0, k_sel=16))
+    r_env = partition_spmd(g, NEConfig(num_partitions=4, seed=0, k_sel=16),
+                           num_devices=1)
     monkeypatch.delenv("REPRO_NE_KERNELS")
     assert not ne_ops.env_enabled()
-    r_ref = partition(g, NEConfig(num_partitions=4, seed=0, k_sel=16,
-                                  use_pallas=False))
+    r_ref = partition_spmd(g, NEConfig(num_partitions=4, seed=0, k_sel=16,
+                                       use_pallas=False), num_devices=1)
     np.testing.assert_array_equal(r_env.edge_part, r_ref.edge_part)
     np.testing.assert_array_equal(r_env.vparts, r_ref.vparts)
 

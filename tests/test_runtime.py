@@ -829,3 +829,54 @@ def test_lazy_partition_result_materializes_once():
     assert res.edge_part_materialized
     np.testing.assert_array_equal(res.edge_part, np.arange(5))
     assert len(calls) == 1
+
+
+# ---------------------------------------------------------------------------
+# one process per chip + compile cache placement
+# ---------------------------------------------------------------------------
+
+def test_child_env_inherits_platform(monkeypatch):
+    """Workers inherit the platform: the launcher never defaults it."""
+    from repro.runtime.multihost import child_env
+
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    assert "JAX_PLATFORMS" not in child_env(4)
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    env = child_env(4)
+    assert env["JAX_PLATFORMS"] == "cpu"
+    assert "--xla_force_host_platform_device_count=4" in env["XLA_FLAGS"]
+
+
+@pytest.mark.parametrize("platforms", [None, "tpu", "tpu,cpu"])
+def test_launch_local_refuses_gang_sharing_chips(monkeypatch, platforms):
+    """Several local workers run only pinned to the CPU; nothing spawns."""
+    import sys
+
+    from repro.runtime.multihost import launch_local
+
+    if platforms is None:
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    else:
+        monkeypatch.setenv("JAX_PLATFORMS", platforms)
+    with pytest.raises(ValueError, match="share this host's chips"):
+        launch_local([sys.executable, "-c", "raise SystemExit(3)"],
+                     num_processes=2, devices_per_process=1)
+
+
+def test_compile_cache_placement(monkeypatch):
+    """Unset: the fixed checkout-root ``.jax_cache``; set: JAX's own
+    reading of ``JAX_COMPILATION_CACHE_DIR`` is left alone."""
+    import jax
+
+    from repro import compile_cache
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv(compile_cache.ENV_VAR, "/elsewhere")
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert compile_cache.enable() is None
+        monkeypatch.delenv(compile_cache.ENV_VAR)
+        assert compile_cache.enable() == os.path.join(root, ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)

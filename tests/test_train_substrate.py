@@ -221,7 +221,7 @@ def test_compression_error_feedback():
     def body(g, r):
         return psum_compressed(g, r, "d")
 
-    out, new_r = jax.jit(compat.shard_map(
+    out, new_r = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P())))(g, r)
     # quantization error bounded by scale/2 and captured in the residual
     scale = float(jnp.abs(g["w"]).max()) / 127.0
