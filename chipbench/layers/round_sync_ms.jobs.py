@@ -1,0 +1,5 @@
+"""Round program layer, sync phase: device ms per traced round in ops under the
+``ne_sync`` scope (both ``_apply_alloc`` calls: the local (P,) and D_rest
+deltas, the replica-map delta and the collectives), averaged over the
+devices.  Jobs cells, where it moves ``partition_s``."""
+from program_trace import sync_ms as read  # noqa: F401

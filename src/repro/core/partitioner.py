@@ -20,6 +20,11 @@ iteration.  One ``jax.lax.while_loop`` step == one paper round:
 Boundary sets are *derived*, not stored: ``v ∈ B_p  ⇔  p ∈ parts(v) ∧
 D_rest(v) > 0`` — this is exactly the paper's definition of B(X) and avoids
 an (N, P) frontier structure.
+
+Each phase of a round runs under a ``jax.named_scope`` — ``ne_select``,
+``ne_one_hop``, ``ne_sync``, ``ne_two_hop`` — shared by the single, hybrid
+and SPMD rounds, so a profiler trace attributes every op to its phase
+(the scope is the op's HLO ``op_name``; results are unchanged).
 """
 from __future__ import annotations
 
@@ -159,6 +164,7 @@ def select_chunk(vparts_c, active_c, degree_rest, lam, k_sel, keys_c,
     return idx, valid
 
 
+@jax.named_scope("ne_select")
 def vertex_claims(cfg: NEConfig, limit: int, vparts: Array,
                   degree_rest: Array, edges_per_part: Array,
                   sub: Array) -> Array:
@@ -208,6 +214,7 @@ def vertex_claims(cfg: NEConfig, limit: int, vparts: Array,
     return vclaim_key.at[flat_v].min(claim_keys, mode="drop")
 
 
+@jax.named_scope("ne_one_hop")
 def one_hop(vclaim: Array, u: Array, v: Array, edge_part: Array,
             num_partitions: int, mask: Array | None = None):
     """One-hop allocation (paper Alg. 3) over an edge list.
@@ -232,7 +239,8 @@ def _round(g: Graph, cfg: NEConfig, limit: int, state: NEState) -> NEState:
     n = g.num_vertices
     m = g.num_edges
     p_num = cfg.num_partitions
-    key, sub = jax.random.split(state.key)
+    with jax.named_scope("ne_select"):
+        key, sub = jax.random.split(state.key)
 
     vclaim_key = vertex_claims(cfg, limit, state.vparts, state.degree_rest,
                                state.edges_per_part, sub)
@@ -240,71 +248,77 @@ def _round(g: Graph, cfg: NEConfig, limit: int, state: NEState) -> NEState:
     # --- one-hop allocation ------------------------------------------------
     u, v = g.edges[:, 0], g.edges[:, 1]
     part1, counts1 = one_hop(vclaim_key, u, v, state.edge_part, p_num)
-    new1 = part1 >= 0
+    with jax.named_scope("ne_one_hop"):
+        new1 = part1 >= 0
+        edge_part = jnp.where(new1, part1, state.edge_part)
 
-    edge_part = jnp.where(new1, part1, state.edge_part)
-    add_row = jnp.where(new1, part1, 0)
-    vparts = state.vparts
-    drop_u = jnp.where(new1, u, n)
-    drop_v = jnp.where(new1, v, n)
-    vparts = vparts.at[drop_u, add_row].set(True, mode="drop")
-    vparts = vparts.at[drop_v, add_row].set(True, mode="drop")
-    dec = (jnp.zeros((n,), jnp.int32)
-           .at[drop_u].add(new1.astype(jnp.int32), mode="drop")
-           .at[drop_v].add(new1.astype(jnp.int32), mode="drop"))
-    degree_rest = state.degree_rest - dec
-    edges_per_part = state.edges_per_part + counts1
+    # --- replica-set update (the SPMD round's SyncVertexAllocations) -------
+    with jax.named_scope("ne_sync"):
+        add_row = jnp.where(new1, part1, 0)
+        vparts = state.vparts
+        drop_u = jnp.where(new1, u, n)
+        drop_v = jnp.where(new1, v, n)
+        vparts = vparts.at[drop_u, add_row].set(True, mode="drop")
+        vparts = vparts.at[drop_v, add_row].set(True, mode="drop")
+        dec = (jnp.zeros((n,), jnp.int32)
+               .at[drop_u].add(new1.astype(jnp.int32), mode="drop")
+               .at[drop_v].add(new1.astype(jnp.int32), mode="drop"))
+        degree_rest = state.degree_rest - dec
+        edges_per_part = state.edges_per_part + counts1
 
     # --- 3. two-hop "free edge" allocation, Condition (5) ------------------
     if cfg.two_hop:
-        ce = min(cfg.edge_chunk, m)
-        n_ec = (m + ce - 1) // ce
-        m_pad = n_ec * ce
-        pad = m_pad - m
-        u_p = jnp.pad(u, (0, pad))
-        v_p = jnp.pad(v, (0, pad))
-        un_p = jnp.pad(edge_part < 0, (0, pad))  # pads → False
-        enc_vec = priority_enc(edges_per_part,
-                               jnp.arange(p_num, dtype=jnp.int32),
-                               p_num)  # tie-break by |E_p| (Alg. 3 line 16)
-        # free edges only go to partitions still under the α-capacity, and a
-        # partition may absorb at most its remaining capacity this round —
-        # otherwise one round's free-edge batch around a hub blows up |E_p|
-        # (the paper's per-vertex expansion granularity implies the same cap).
-        enc_vec = jnp.where(edges_per_part <= limit, enc_vec, I32_INF)
-        quota0 = jnp.maximum(limit + 1 - edges_per_part, 0)
+        with jax.named_scope("ne_two_hop"):
+            ce = min(cfg.edge_chunk, m)
+            n_ec = (m + ce - 1) // ce
+            m_pad = n_ec * ce
+            pad = m_pad - m
+            u_p = jnp.pad(u, (0, pad))
+            v_p = jnp.pad(v, (0, pad))
+            un_p = jnp.pad(edge_part < 0, (0, pad))  # pads → False
+            # tie-break by |E_p| (Alg. 3 line 16)
+            enc_vec = priority_enc(edges_per_part,
+                                   jnp.arange(p_num, dtype=jnp.int32), p_num)
+            # free edges only go to partitions still under the α-capacity,
+            # and a partition may absorb at most its remaining capacity this
+            # round — otherwise one round's free-edge batch around a hub
+            # blows up |E_p| (the paper's per-vertex expansion granularity
+            # implies the same cap).
+            enc_vec = jnp.where(edges_per_part <= limit, enc_vec, I32_INF)
+            quota0 = jnp.maximum(limit + 1 - edges_per_part, 0)
 
-        def two_hop(quota, args):
-            uu, vv, unal = args
-            inter = vparts[uu] & vparts[vv]                      # (ce, P)
-            k2 = jnp.where(inter & unal[:, None], enc_vec[None, :], I32_INF)
-            best = k2.min(axis=1)
-            cand = jnp.where(best < I32_INF, best % p_num, -1)
-            rank = exclusive_rank(cand, p_num)
-            keep = (cand >= 0) & (rank < quota[jnp.maximum(cand, 0)])
-            out = jnp.where(keep, cand, -1)
-            quota = quota - jnp.zeros((p_num,), jnp.int32).at[
-                jnp.maximum(out, 0)].add(keep.astype(jnp.int32))
-            return quota, out
+            def two_hop(quota, args):
+                uu, vv, unal = args
+                inter = vparts[uu] & vparts[vv]                  # (ce, P)
+                k2 = jnp.where(inter & unal[:, None], enc_vec[None, :],
+                               I32_INF)
+                best = k2.min(axis=1)
+                cand = jnp.where(best < I32_INF, best % p_num, -1)
+                rank = exclusive_rank(cand, p_num)
+                keep = (cand >= 0) & (rank < quota[jnp.maximum(cand, 0)])
+                out = jnp.where(keep, cand, -1)
+                quota = quota - jnp.zeros((p_num,), jnp.int32).at[
+                    jnp.maximum(out, 0)].add(keep.astype(jnp.int32))
+                return quota, out
 
-        _, part2 = jax.lax.scan(
-            two_hop, quota0,
-            (u_p.reshape(n_ec, ce), v_p.reshape(n_ec, ce),
-             un_p.reshape(n_ec, ce)),
-        )
-        part2 = part2.reshape(m_pad)[:m]
-        new2 = part2 >= 0
-        edge_part = jnp.where(new2, part2, edge_part)
-        add2 = jnp.where(new2, part2, 0)
-        edges_per_part = edges_per_part + jnp.zeros(
-            (p_num,), jnp.int32).at[add2].add(new2.astype(jnp.int32))
-        dec2 = (jnp.zeros((n,), jnp.int32)
-                .at[jnp.where(new2, u, n)].add(new2.astype(jnp.int32),
-                                               mode="drop")
-                .at[jnp.where(new2, v, n)].add(new2.astype(jnp.int32),
-                                               mode="drop"))
-        degree_rest = degree_rest - dec2
-        new_total = new1.sum() + new2.sum()
+            _, part2 = jax.lax.scan(
+                two_hop, quota0,
+                (u_p.reshape(n_ec, ce), v_p.reshape(n_ec, ce),
+                 un_p.reshape(n_ec, ce)),
+            )
+            part2 = part2.reshape(m_pad)[:m]
+            new2 = part2 >= 0
+            edge_part = jnp.where(new2, part2, edge_part)
+            add2 = jnp.where(new2, part2, 0)
+            edges_per_part = edges_per_part + jnp.zeros(
+                (p_num,), jnp.int32).at[add2].add(new2.astype(jnp.int32))
+            dec2 = (jnp.zeros((n,), jnp.int32)
+                    .at[jnp.where(new2, u, n)].add(new2.astype(jnp.int32),
+                                                   mode="drop")
+                    .at[jnp.where(new2, v, n)].add(new2.astype(jnp.int32),
+                                                   mode="drop"))
+            degree_rest = degree_rest - dec2
+            new_total = new1.sum() + new2.sum()
     else:
         new_total = new1.sum()
 

@@ -22,7 +22,10 @@ One while_loop step == one paper round, per device:
      of per-device candidate histograms).
 
 Steps 2–4 touch only the local shard, so per-round work scales 1/D; the
-sync in step 3 is the round barrier the paper describes.  Because steps 1–3
+sync in step 3 is the round barrier the paper describes.  Each step runs
+under a ``jax.named_scope`` — ``ne_select``, ``ne_one_hop``, ``ne_sync``
+(both ``_apply_alloc`` calls) and ``ne_two_hop`` — which names every op's
+phase in a profiler trace.  Because steps 1–3
 are bit-identical to the single-controller fixed point and only the quota
 *ordering* in step 4 differs, the resulting quality (replication factor)
 matches ``core.partitioner.partition`` closely — asserted by
@@ -65,6 +68,7 @@ class SpmdState(NamedTuple):
     remaining: Array        # ()     int32 unallocated edges, global
 
 
+@jax.named_scope("ne_sync")
 def _apply_alloc(new, part, u_loc, v_loc, n, p_num, vparts, degree_rest,
                  edges_per_part, num_dev, local_counts=None):
     """Fold one local allocation batch into the replicated state.
@@ -108,21 +112,23 @@ def _spmd_round(cfg: NEConfig, limit: int, n: int, num_dev: int,
                 state: SpmdState) -> SpmdState:
     p_num = cfg.num_partitions
     packed = cfg.use_pallas
-    key, sub = jax.random.split(state.key)
 
     # --- 1. replicated selection + claims ----------------------------------
     # the packed replica map unpacks once per round for selection; every
     # other consumer below reads the packed words directly
-    vparts_rep = (ne_ops.unpack_bits(state.vparts, p_num) if packed
-                  else state.vparts)
+    with jax.named_scope("ne_select"):
+        key, sub = jax.random.split(state.key)
+        vparts_rep = (ne_ops.unpack_bits(state.vparts, p_num) if packed
+                      else state.vparts)
     vclaim = vertex_claims(cfg, limit, vparts_rep, state.degree_rest,
                            state.edges_per_part, sub)
 
     # --- 2. one-hop allocation on the local shard --------------------------
     part1, counts1 = one_hop(vclaim, u_loc, v_loc, state.edge_part, p_num,
                              mask=mask_loc)
-    new1 = part1 >= 0
-    edge_part = jnp.where(new1, part1, state.edge_part)
+    with jax.named_scope("ne_one_hop"):
+        new1 = part1 >= 0
+        edge_part = jnp.where(new1, part1, state.edge_part)
 
     # --- 3. SyncVertexAllocations ------------------------------------------
     vparts, degree_rest, edges_per_part, new_total = _apply_alloc(
@@ -132,58 +138,59 @@ def _spmd_round(cfg: NEConfig, limit: int, n: int, num_dev: int,
 
     # --- 4. two-hop free edges, Condition (5) ------------------------------
     if cfg.two_hop:
-        enc_vec = priority_enc(edges_per_part,
-                               jnp.arange(p_num, dtype=jnp.int32), p_num)
-        enc_vec = jnp.where(edges_per_part <= limit, enc_vec, I32_INF)
-        quota = jnp.maximum(limit + 1 - edges_per_part, 0)
-        unal = mask_loc & (edge_part < 0)
-        # candidates + local ranks, scanned in edge_chunk-sized chunks so
-        # peak memory is edge_chunk × P, like the single-controller path
-        c_len = u_loc.shape[0]
-        ce = min(cfg.edge_chunk, c_len)
-        n_ec = (c_len + ce - 1) // ce
-        pad = n_ec * ce - c_len
-        u_p = jnp.pad(u_loc, (0, pad))
-        v_p = jnp.pad(v_loc, (0, pad))
-        un_p = jnp.pad(unal, (0, pad))                  # pads → False
+        with jax.named_scope("ne_two_hop"):
+            enc_vec = priority_enc(edges_per_part,
+                                   jnp.arange(p_num, dtype=jnp.int32), p_num)
+            enc_vec = jnp.where(edges_per_part <= limit, enc_vec, I32_INF)
+            quota = jnp.maximum(limit + 1 - edges_per_part, 0)
+            unal = mask_loc & (edge_part < 0)
+            # candidates + local ranks, scanned in edge_chunk-sized chunks so
+            # peak memory is edge_chunk × P, like the single-controller path
+            c_len = u_loc.shape[0]
+            ce = min(cfg.edge_chunk, c_len)
+            n_ec = (c_len + ce - 1) // ce
+            pad = n_ec * ce - c_len
+            u_p = jnp.pad(u_loc, (0, pad))
+            v_p = jnp.pad(v_loc, (0, pad))
+            un_p = jnp.pad(unal, (0, pad))                  # pads → False
 
-        def cand_chunk(counts, args):
-            uu, vv, un = args
-            if packed:
-                # gather packed words (32× less traffic), unpack per chunk
-                inter = ne_ops.unpack_bits(vparts[uu] & vparts[vv], p_num)
-            else:
-                inter = vparts[uu] & vparts[vv]                   # (ce, P)
-            k2 = jnp.where(inter & un[:, None], enc_vec[None, :], I32_INF)
-            best = k2.min(axis=1)
-            cand_c = jnp.where(best < I32_INF,
-                               (best % p_num).astype(jnp.int32), -1)
-            rank_c = exclusive_rank(cand_c, p_num) \
-                + counts[jnp.maximum(cand_c, 0)]
-            counts = counts.at[jnp.maximum(cand_c, 0)].add(
-                (cand_c >= 0).astype(jnp.int32))
-            return counts, (cand_c, rank_c)
+            def cand_chunk(counts, args):
+                uu, vv, un = args
+                if packed:
+                    # gather packed words (32× less traffic), unpack per chunk
+                    inter = ne_ops.unpack_bits(vparts[uu] & vparts[vv], p_num)
+                else:
+                    inter = vparts[uu] & vparts[vv]                   # (ce, P)
+                k2 = jnp.where(inter & un[:, None], enc_vec[None, :], I32_INF)
+                best = k2.min(axis=1)
+                cand_c = jnp.where(best < I32_INF,
+                                   (best % p_num).astype(jnp.int32), -1)
+                rank_c = exclusive_rank(cand_c, p_num) \
+                    + counts[jnp.maximum(cand_c, 0)]
+                counts = counts.at[jnp.maximum(cand_c, 0)].add(
+                    (cand_c >= 0).astype(jnp.int32))
+                return counts, (cand_c, rank_c)
 
-        hist, (cand, myrank) = jax.lax.scan(
-            cand_chunk, jnp.zeros((p_num,), jnp.int32),
-            (u_p.reshape(n_ec, ce), v_p.reshape(n_ec, ce),
-             un_p.reshape(n_ec, ce)))
-        cand = cand.reshape(-1)[:c_len]
-        myrank = myrank.reshape(-1)[:c_len]
-        cand0 = jnp.maximum(cand, 0)
-        # deterministic cross-device quota split: device d's candidates for
-        # partition p rank after all candidates on devices < d.
-        hists = jax.lax.all_gather(hist, AXIS)                    # (D, P)
-        r = jax.lax.axis_index(AXIS)
-        before = jnp.where(jnp.arange(hists.shape[0])[:, None] < r,
-                           hists, 0).sum(axis=0)                  # (P,)
-        keep = (cand >= 0) & (before[cand0] + myrank < quota[cand0])
-        part2 = jnp.where(keep, cand, -1)
-        edge_part = jnp.where(keep, part2, edge_part)
-        vparts, degree_rest, edges_per_part, new2 = _apply_alloc(
-            keep, part2, u_loc, v_loc, n, p_num, vparts, degree_rest,
-            edges_per_part, num_dev)
-        new_total = new_total + new2
+            hist, (cand, myrank) = jax.lax.scan(
+                cand_chunk, jnp.zeros((p_num,), jnp.int32),
+                (u_p.reshape(n_ec, ce), v_p.reshape(n_ec, ce),
+                 un_p.reshape(n_ec, ce)))
+            cand = cand.reshape(-1)[:c_len]
+            myrank = myrank.reshape(-1)[:c_len]
+            cand0 = jnp.maximum(cand, 0)
+            # deterministic cross-device quota split: device d's candidates for
+            # partition p rank after all candidates on devices < d.
+            hists = jax.lax.all_gather(hist, AXIS)                    # (D, P)
+            r = jax.lax.axis_index(AXIS)
+            before = jnp.where(jnp.arange(hists.shape[0])[:, None] < r,
+                               hists, 0).sum(axis=0)                  # (P,)
+            keep = (cand >= 0) & (before[cand0] + myrank < quota[cand0])
+            part2 = jnp.where(keep, cand, -1)
+            edge_part = jnp.where(keep, part2, edge_part)
+            vparts, degree_rest, edges_per_part, new2 = _apply_alloc(
+                keep, part2, u_loc, v_loc, n, p_num, vparts, degree_rest,
+                edges_per_part, num_dev)
+            new_total = new_total + new2
 
     return SpmdState(edge_part, vparts, degree_rest, edges_per_part, key,
                      state.rounds + 1, state.remaining - new_total)

@@ -1,19 +1,20 @@
 """repro.obs — jax-optional telemetry for the partitioning runtime.
 
-Round-level tracing (nested spans + counters → per-host JSONL logs,
-``trace``), one shared peak-RSS implementation (``rss``), Chrome
-``trace_event`` / Perfetto export plus the optional ``jax.profiler``
-window (``export``), run-directory aggregation into per-phase /
+Round-level tracing (nested spans + counters → per-host JSONL logs, and
+``jax.profiler`` annotations once jax is loaded, ``trace``), one shared
+peak-RSS implementation (``rss``), Chrome ``trace_event`` / Perfetto
+export (``export``), run-directory aggregation into per-phase /
 per-round summaries (``report``), and the live side: the store-backed
 per-host metrics bus (``live``) plus the stall/straggler monitor and
 Prometheus exposition behind ``scripts/monitor_run.py`` (``monitor``).
 See docs/DESIGN-observability.md for the event schema, span taxonomy
 and live-bus snapshot schema.
 
-Tracing is off by default and near-zero cost when off: the module-level
-``trace.span`` / ``trace.counter`` front door checks one global.  Turn
-it on with ``REPRO_TRACE=1`` (or ``REPRO_TRACE=<dir>``) or by calling
-``trace.configure`` explicitly — the multihost launcher's ``--trace-dir``
+JSONL tracing is off by default and near-zero cost when off: the
+module-level ``trace.span`` / ``trace.counter`` front door checks one
+global (plus, once jax is loaded, a profiler annotation of about a
+microsecond per span).  Turn it on with ``REPRO_TRACE=1`` (or
+``REPRO_TRACE=<dir>``) or by calling ``trace.configure`` explicitly — the multihost launcher's ``--trace-dir``
 does the latter per worker.
 
 Re-exports resolve lazily (PEP 562) and every submodule imports without
@@ -36,12 +37,12 @@ _EXPORTS = {
     "log_name": "repro.obs.trace",
     "span": "repro.obs.trace",
     "traced": "repro.obs.trace",
+    "watch_compiles": "repro.obs.trace",
     "peak_rss_kb": "repro.obs.rss",
     "vm_hwm_kb": "repro.obs.rss",
     "vm_rss_kb": "repro.obs.rss",
     "chrome_trace": "repro.obs.export",
     "host_logs": "repro.obs.export",
-    "jax_profile": "repro.obs.export",
     "load_events": "repro.obs.export",
     "merge_events": "repro.obs.export",
     "write_chrome_trace": "repro.obs.export",

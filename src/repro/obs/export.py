@@ -8,15 +8,9 @@ merge rebases every log onto one axis using the ``start_unix`` wall-clock
 anchor each meta line carries — exact across processes on one machine,
 NTP-accurate across machines (good enough for eyeballing round skew; the
 per-host durations themselves are always pure ``perf_counter`` deltas).
-
-Also hosts the optional :func:`jax_profile` window — a context manager
-that wraps a flagged round range in a ``jax.profiler`` trace when jax is
-importable and no-ops otherwise, keeping this module (and the whole
-``repro.obs`` package) importable without jax.
 """
 from __future__ import annotations
 
-import contextlib
 import json
 import os
 import warnings
@@ -139,31 +133,5 @@ def write_chrome_trace(out_path: str | os.PathLike, paths) -> dict:
     return trace
 
 
-@contextlib.contextmanager
-def jax_profile(logdir: str | os.PathLike | None, enabled: bool = True):
-    """Optionally wrap a block in a ``jax.profiler`` trace.
-
-    Yields True when a profiler trace is actually running.  No-ops (and
-    never raises) when disabled, when ``logdir`` is None, or when jax is
-    not importable — so call sites can use it unconditionally.  Use for
-    a flagged round window: XLA-level timelines are far heavier than the
-    JSONL spans, so profile a few rounds, not the run.
-    """
-    if not enabled or logdir is None:
-        yield False
-        return
-    try:
-        from jax import profiler
-    except Exception:
-        yield False
-        return
-    os.makedirs(os.fspath(logdir), exist_ok=True)
-    profiler.start_trace(os.fspath(logdir))
-    try:
-        yield True
-    finally:
-        profiler.stop_trace()
-
-
-__all__ = ["chrome_trace", "host_logs", "jax_profile", "load_events",
-           "merge_events", "write_chrome_trace"]
+__all__ = ["chrome_trace", "host_logs", "load_events", "merge_events",
+           "write_chrome_trace"]

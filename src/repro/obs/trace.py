@@ -25,10 +25,18 @@ summaries.  Three event kinds:
     a point-in-time sample: a gauge (``counter``) or the running total
     of an accumulating counter (``add``).
 
-Everything here is jax-free and near-zero cost when disabled: the
-module-level :func:`span` / :func:`counter` / :func:`add` check one
-global and return a shared no-op when no tracer is configured, so the
-instrumented round loop pays one attribute load per call site.  All
+The module-level front door (:func:`span`, :func:`traced`) has a second
+sink: once jax is imported (``"jax" in sys.modules``) each span also opens
+a ``jax.profiler.TraceAnnotation("repro.<name>")``, so the program's spans
+land on the profiler's host plane, on the device trace's clock, whenever
+anyone traces the process.  With no profiler session open an annotation
+costs about a microsecond.  :func:`watch_compiles` adds zero-length
+``repro.compile`` marks for JAX's compile events the same way.
+
+Everything here imports without jax and is near-zero cost when disabled:
+with no tracer configured and jax not loaded, :func:`span` /
+:func:`counter` / :func:`add` check one global and return a shared no-op,
+so the instrumented round loop pays one attribute load per call site.  All
 recording is thread-safe (one re-entrant lock around the event buffer).
 """
 from __future__ import annotations
@@ -36,12 +44,20 @@ from __future__ import annotations
 import functools
 import json
 import os
+import sys
 import threading
 import time
 
 from repro.obs import rss
 
 SCHEMA_VERSION = 1
+
+# profiler-sink names: every front-door span ``x`` is the host event
+# ``repro.x``; compile events are ``repro.compile`` marks
+ANNOTATION_PREFIX = "repro."
+COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+                  "/jax/core/compile/backend_compile_duration",
+                  "/jax/compilation_cache/cache_retrieval_time_sec")
 
 
 def log_name(process: int) -> str:
@@ -65,6 +81,43 @@ class _NullSpan:
 
 
 NULL_SPAN = _NullSpan()
+
+
+class _AnnotatedSpan:
+    """A profiler ``TraceAnnotation`` around an optional JSONL span."""
+
+    __slots__ = ("_ann", "_span")
+
+    def __init__(self, ann, span):
+        self._ann = ann
+        self._span = span
+
+    def __enter__(self):
+        self._ann.__enter__()
+        if self._span is not None:
+            self._span.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        try:
+            if self._span is not None:
+                self._span.__exit__(*exc)
+        finally:
+            self._ann.__exit__(*exc)
+        return False
+
+    def set(self, **args):
+        if self._span is not None:
+            self._span.set(**args)
+
+
+def _annotation(name: str):
+    """``TraceAnnotation("repro.<name>")`` once jax is loaded, else None;
+    never imports jax itself."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    return jax.profiler.TraceAnnotation(ANNOTATION_PREFIX + name)
 
 
 class _Span:
@@ -257,10 +310,15 @@ def from_env(default_dir: str | os.PathLike | None = None,
 
 
 def span(name: str, cat: str = "run", **args):
+    """Context manager for one span: a JSONL span when a tracer is
+    configured, and a profiler annotation ``repro.<name>`` when jax is
+    loaded; ``NULL_SPAN`` when neither."""
     t = _TRACER
-    if t is None:
-        return NULL_SPAN
-    return t.span(name, cat, **args)
+    sp = t.span(name, cat, **args) if t is not None else None
+    ann = _annotation(name)
+    if ann is None:
+        return NULL_SPAN if sp is None else sp
+    return _AnnotatedSpan(ann, sp)
 
 
 def counter(name: str, value):
@@ -282,19 +340,18 @@ def flush():
 
 
 def traced(name: str | None = None, cat: str = "run"):
-    """Decorator: run the wrapped function inside a span (no-op when
-    tracing is disabled — the undecorated call path is one ``is None``
-    check)."""
+    """Decorator: run the wrapped function inside :func:`span` (with no
+    tracer and jax not loaded the call path is one ``is None`` check and
+    one dict lookup)."""
 
     def deco(fn):
         label = name or fn.__qualname__
 
         @functools.wraps(fn)
         def wrapper(*args, **kwargs):
-            t = _TRACER
-            if t is None:
+            if _TRACER is None and "jax" not in sys.modules:
                 return fn(*args, **kwargs)
-            with t.span(label, cat):
+            with span(label, cat):
                 return fn(*args, **kwargs)
 
         return wrapper
@@ -302,6 +359,36 @@ def traced(name: str | None = None, cat: str = "run"):
     return deco
 
 
-__all__ = ["NULL_SPAN", "SCHEMA_VERSION", "Tracer", "add", "configure",
-           "counter", "disable", "enabled", "flush", "from_env",
-           "get_tracer", "log_name", "span", "traced"]
+_COMPILE_LISTENER = None
+
+
+def watch_compiles():
+    """Register (once per process) a ``jax.monitoring`` listener that
+    marks each of JAX's :data:`COMPILE_EVENTS` with a zero-length
+    ``repro.compile`` profiler annotation tagged ``event``, ``seconds``
+    and JAX's own tags (the function's name), and counts it in the
+    ``compiles`` counter when a tracer is configured.  Imports jax: call
+    it from code that runs jax."""
+    global _COMPILE_LISTENER
+    if _COMPILE_LISTENER is not None:
+        return
+    from jax import monitoring
+    from jax.profiler import TraceAnnotation
+
+    def on_duration(event: str, seconds: float, **tags):
+        if event in COMPILE_EVENTS:
+            # zero length: the listener runs once the event has ended;
+            # JAX's tags (``fun_name``) ride along
+            with TraceAnnotation(ANNOTATION_PREFIX + "compile", event=event,
+                                 seconds=seconds, **tags):
+                pass
+            add("compiles", 1)
+
+    monitoring.register_event_duration_secs_listener(on_duration)
+    _COMPILE_LISTENER = on_duration
+
+
+__all__ = ["ANNOTATION_PREFIX", "COMPILE_EVENTS", "NULL_SPAN",
+           "SCHEMA_VERSION", "Tracer", "add", "configure", "counter",
+           "disable", "enabled", "flush", "from_env", "get_tracer",
+           "log_name", "span", "traced", "watch_compiles"]

@@ -111,6 +111,7 @@ class PartitionDriver:
             raise ValueError(f"mode={mode!r} is single-controller by "
                              "definition — multi-process runs drive the "
                              "SPMD partitioner (mode='spmd')")
+        obs.watch_compiles()
         with obs.span("ingest", cat="runtime", mode=mode):
             if mode == "hybrid":
                 from repro.core.hybrid import (HybridConfig,
@@ -150,18 +151,20 @@ class PartitionDriver:
                 self._graph_fp = graph_fingerprint(source)
                 d = num_devices or len(jax.devices())
                 self.num_devices = max(1, min(d, len(jax.devices())))
-                self.n, self.m, self._edges, shards, masks, self._dev = \
-                    self._ingest(source, self.num_devices, num_hosts,
-                                 ingest_processes)
+                with obs.span("ingest_shards", cat="runtime"):
+                    self.n, self.m, self._edges, shards, masks, self._dev = \
+                        self._ingest(source, self.num_devices, num_hosts,
+                                     ingest_processes)
                 self.cfg = cfg.clamped(self.n)
                 self.limit = alpha_limit(self.cfg.alpha, self.m,
                                          self.cfg.num_partitions)
-                self.mesh = make_edge_mesh(self.num_devices, axis=AXIS)
-                self._u_sh = shard_over(self.mesh, shards[:, :, 0])
-                self._v_sh = shard_over(self.mesh, shards[:, :, 1])
-                self._mask_sh = shard_over(self.mesh, masks)
-                self.state = spmd_init_state(shards, masks, self.n, self.cfg,
-                                             self.mesh)
+                with obs.span("ingest_place", cat="runtime"):
+                    self.mesh = make_edge_mesh(self.num_devices, axis=AXIS)
+                    self._u_sh = shard_over(self.mesh, shards[:, :, 0])
+                    self._v_sh = shard_over(self.mesh, shards[:, :, 1])
+                    self._mask_sh = shard_over(self.mesh, masks)
+                    self.state = spmd_init_state(shards, masks, self.n,
+                                                 self.cfg, self.mesh)
 
         # per-round SyncVertexAllocations traffic (per device) — a pure
         # function of the config, recorded as a cumulative trace counter
@@ -270,9 +273,11 @@ class PartitionDriver:
                 self._done = True
             elif self.mode in ("single", "hybrid"):
                 # HybridConfig carries max_rounds, so ne_done reads either
-                self._done = ne_done(self.state, self.cfg)
+                with obs.span("done_read", cat="runtime"):
+                    self._done = ne_done(self.state, self.cfg)
             else:
-                self._done = spmd_done(self.state, self.cfg)
+                with obs.span("done_read", cat="runtime"):
+                    self._done = spmd_done(self.state, self.cfg)
         return self._done
 
     def step(self) -> int:
@@ -283,23 +288,28 @@ class PartitionDriver:
         """
         if self.done:
             return self.rounds
-        tr = obs.get_tracer()
-        sp = (tr.span("round", cat="runtime") if tr is not None
-              else obs.NULL_SPAN)
         # the round span covers the snapshot save too (nested "snapshot"
         # span): per-round cost as a long run pays it, matching the old
         # hand-timed round_secs the multihost_snap bench row diffs
-        with sp:
-            if self.mode in ("single", "hybrid"):
-                cfg = self.cfg if self.mode == "single" else self._necfg
-                self.state = jax.block_until_ready(ne_round_step(
-                    self._graph, cfg, self.limit, self.state))
-            else:
-                self.state = jax.block_until_ready(spmd_round_step(
-                    self.cfg, self.limit, self.n, self.mesh, self._u_sh,
-                    self._v_sh, self._mask_sh, self.state))
+        with obs.span("round", cat="runtime") as sp:
+            # the call returns once the round is enqueued; the wait is the
+            # device's time, the read the scalar the step returns
+            with obs.span("round_dispatch", cat="runtime"):
+                if self.mode in ("single", "hybrid"):
+                    cfg = self.cfg if self.mode == "single" else self._necfg
+                    state = ne_round_step(self._graph, cfg, self.limit,
+                                          self.state)
+                else:
+                    state = spmd_round_step(
+                        self.cfg, self.limit, self.n, self.mesh, self._u_sh,
+                        self._v_sh, self._mask_sh, self.state)
+            with obs.span("round_wait", cat="runtime"):
+                self.state = jax.block_until_ready(state)
+            with obs.span("round_read", cat="runtime"):
+                rounds = self.rounds
+            tr = obs.get_tracer()
             if tr is not None:
-                sp.set(round=int(self.state.rounds))
+                sp.set(round=rounds)
                 rem = getattr(self.state, "remaining", None)
                 if rem is not None:
                     tr.counter("edges_remaining", int(rem))
@@ -313,7 +323,7 @@ class PartitionDriver:
                 rem = getattr(self.state, "remaining", None)
                 rem = (int(rem) if rem is not None
                        else q["degree_sum"] // 2)
-                live.publish(phase="round", round=int(self.state.rounds),
+                live.publish(phase="round", round=rounds,
                              edges_remaining=rem,
                              sync_payload_bytes=self._sync_total,
                              rf=q["rf"], eb=q["eb"], vb=q["vb"],
@@ -322,9 +332,9 @@ class PartitionDriver:
             self._final_slices = None
             self._done = None
             if (self.snapshot is not None and self.snapshot_every
-                    and self.rounds % self.snapshot_every == 0):
+                    and rounds % self.snapshot_every == 0):
                 self.save_snapshot()
-        return self.rounds
+        return rounds
 
     def run(self) -> PartitionResult:
         """Step to the fixed point (snapshotting as configured), finalize."""
@@ -357,24 +367,25 @@ class PartitionDriver:
                                                self.cfg)
                 self._publish_live_done()
                 return self._result
-            if self.mode == "single":
-                edge_part = self.state.edge_part
-            elif self.multihost:
+            if self.multihost:
                 self._result = self._finalize_multihost()
                 self._publish_live_done()
                 return self._result
-            else:
-                ep_sh = np.asarray(self.state.edge_part)
-                edge_part = stitch_edge_part(ep_sh, self._dev, self.m)
-            vparts = self.state.vparts
-            if self.mode == "spmd" and self.cfg.use_pallas:
-                # SPMD round state keeps replica sets bit-packed; the
-                # result surface is always (N, P) bool
-                vparts = ne_ops.unpack_bits_np(np.asarray(vparts), p_num)
-            self._result = finalize_result(edge_part, vparts,
-                                           self.state.edges_per_part,
-                                           self._edges, self.cfg,
-                                           self.rounds)
+            st = self.state
+            with obs.span("device_get", cat="runtime"):
+                edge_part, vparts, counts, rounds = jax.device_get(
+                    (st.edge_part, st.vparts, st.edges_per_part, st.rounds))
+            if self.mode == "spmd":
+                with obs.span("stitch_edge_part", cat="runtime"):
+                    edge_part = stitch_edge_part(edge_part, self._dev, self.m)
+                if self.cfg.use_pallas:
+                    # SPMD round state keeps replica sets bit-packed; the
+                    # result surface is always (N, P) bool
+                    vparts = ne_ops.unpack_bits_np(vparts, p_num)
+            with obs.span("finalize_result", cat="runtime"):
+                self._result = finalize_result(edge_part, vparts, counts,
+                                               self._edges, self.cfg,
+                                               int(rounds))
             self._publish_live_done()
             return self._result
 
@@ -648,9 +659,11 @@ class PartitionDriver:
             self._edges = (self.source.read_all()
                            if isinstance(self.source, EdgeFile)
                            else np.asarray(as_graph(self.source).edges))
-        return save_artifact(dirpath, res, self._edges, self.n,
-                             config_fingerprint=config_fingerprint(self.cfg),
-                             graph_fingerprint=self._graph_fp)
+        with obs.span("save_artifact", cat="runtime"):
+            return save_artifact(
+                dirpath, res, self._edges, self.n,
+                config_fingerprint=config_fingerprint(self.cfg),
+                graph_fingerprint=self._graph_fp)
 
     def _save_artifact_multihost(self, dirpath, res) -> PartitionArtifact:
         from repro.runtime import artifact as art

@@ -66,7 +66,10 @@ def test_span_set_args():
     assert span["args"] == {"k": 1, "remaining": 42}
 
 
-def test_disabled_module_api_is_noop():
+def test_disabled_module_api_is_noop(monkeypatch):
+    # NULL_SPAN is the path while jax is not loaded; once it is, spans
+    # also go to the profiler (tests/test_obs_profiler.py)
+    monkeypatch.delitem(sys.modules, "jax", raising=False)
     assert obs.get_tracer() is None
     assert not obs.enabled()
     # the disabled fast path returns the shared singleton — no allocation
@@ -207,13 +210,6 @@ def test_write_chrome_trace_accepts_run_dir(tmp_path):
     trace = export.write_chrome_trace(out, tmp_path)
     assert out.exists()
     assert json.loads(out.read_text()) == json.loads(json.dumps(trace))
-
-
-def test_jax_profile_noop():
-    with export.jax_profile(None) as on:
-        assert on is False
-    with export.jax_profile("/tmp/x", enabled=False) as on:
-        assert on is False
 
 
 # ---------------------------------------------------------------------------
