@@ -115,6 +115,20 @@ def to_networkx(g: Graph):
     return gx
 
 
+def target_histogram(ids: Array, num_targets: int) -> Array:
+    """(T,) int32 count of the items per target id; negative ids count nowhere.
+
+    A dense one-hot compare-and-sum over the items: K·T compare-adds that
+    stream through the vector units, where an XLA scatter-add into T
+    entries serializes its colliding updates on the TPU (about 9 ns per
+    item, whatever T).  Written for the small T of the partition axis.
+    Pass ids that are already -1 where an item counts nowhere: a separate
+    weight array leaves a K-long temporary behind.
+    """
+    targets = jnp.arange(num_targets, dtype=ids.dtype)
+    return jnp.sum(ids[:, None] == targets[None, :], axis=0, dtype=jnp.int32)
+
+
 def exclusive_rank(cand: Array, num_targets: int) -> Array:
     """Per-item exclusive rank among earlier items with the same target.
 
@@ -124,11 +138,16 @@ def exclusive_rank(cand: Array, num_targets: int) -> Array:
     ``rank[i] < quota[cand[i]]``) and of stable send-buffer slotting.
     Value at negative-target items is that of target 0; guard with the
     candidate mask as the callers do.
+
+    The running count is the cumulative sum of the (T, K) one-hot along
+    the items, and the lookup selects item i's row and sums along T: K·T
+    work and no per-item gather, which suits the small T of the partition
+    and device axes.
     """
-    onehot = cand[:, None] == jnp.arange(num_targets)[None, :]
-    rank = jnp.cumsum(onehot.astype(jnp.int32), axis=0) - 1
-    return jnp.take_along_axis(rank, jnp.maximum(cand, 0)[:, None],
-                               axis=1)[:, 0]
+    targets = jnp.arange(num_targets, dtype=cand.dtype)[:, None]
+    running = jnp.cumsum((cand[None, :] == targets).astype(jnp.int32), axis=1)
+    own = jnp.maximum(cand, 0)[None, :] == targets
+    return jnp.sum(jnp.where(own, running, 0), axis=0) - 1
 
 
 # ---------------------------------------------------------------------------

@@ -38,7 +38,8 @@ import numpy as np
 
 from repro.core.epilogue import (alpha_limit, cleanup_leftovers,  # noqa: F401 — re-exported epilogue surface
                                  leftover_plan, leftover_targets)
-from repro.core.graph import Graph, as_graph, exclusive_rank
+from repro.core.graph import (Graph, as_graph, exclusive_rank,
+                              target_histogram)
 from repro.core.metrics import stats_from_counts
 from repro.kernels.ne_round import ops as ne_ops
 
@@ -223,16 +224,15 @@ def one_hop(vclaim: Array, u: Array, v: Array, edge_part: Array,
     (``mask`` false → never) joins partition ``k % P`` when some endpoint
     was claimed — the min over the edge's two directed CSR slots, in one
     pass over M edges.  Returns ``(part, counts)``: (M,) int32, ``-1``
-    for untouched edges, and the (P,) int32 histogram of new allocations.
+    for untouched edges, and the (P,) int32 histogram of new allocations
+    (``target_histogram``: M·P compare-adds, no scatter).
     """
     k_uv = jnp.minimum(vclaim[u], vclaim[v])
     new = (edge_part < 0) & (k_uv < I32_INF)
     if mask is not None:
         new &= mask
     part = jnp.where(new, (k_uv % num_partitions).astype(jnp.int32), -1)
-    counts = jnp.zeros((num_partitions,), jnp.int32).at[
-        jnp.maximum(part, 0)].add(new.astype(jnp.int32))
-    return part, counts
+    return part, target_histogram(part, num_partitions)
 
 
 def _round(g: Graph, cfg: NEConfig, limit: int, state: NEState) -> NEState:
@@ -297,9 +297,7 @@ def _round(g: Graph, cfg: NEConfig, limit: int, state: NEState) -> NEState:
                 rank = exclusive_rank(cand, p_num)
                 keep = (cand >= 0) & (rank < quota[jnp.maximum(cand, 0)])
                 out = jnp.where(keep, cand, -1)
-                quota = quota - jnp.zeros((p_num,), jnp.int32).at[
-                    jnp.maximum(out, 0)].add(keep.astype(jnp.int32))
-                return quota, out
+                return quota - target_histogram(out, p_num), out
 
             _, part2 = jax.lax.scan(
                 two_hop, quota0,
@@ -309,9 +307,7 @@ def _round(g: Graph, cfg: NEConfig, limit: int, state: NEState) -> NEState:
             part2 = part2.reshape(m_pad)[:m]
             new2 = part2 >= 0
             edge_part = jnp.where(new2, part2, edge_part)
-            add2 = jnp.where(new2, part2, 0)
-            edges_per_part = edges_per_part + jnp.zeros(
-                (p_num,), jnp.int32).at[add2].add(new2.astype(jnp.int32))
+            edges_per_part = edges_per_part + target_histogram(part2, p_num)
             dec2 = (jnp.zeros((n,), jnp.int32)
                     .at[jnp.where(new2, u, n)].add(new2.astype(jnp.int32),
                                                    mode="drop")

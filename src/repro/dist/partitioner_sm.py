@@ -43,7 +43,8 @@ from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.core.epilogue import stitch_slices
-from repro.core.graph import Graph, exclusive_rank, shard_edges
+from repro.core.graph import (Graph, exclusive_rank, shard_edges,
+                              target_histogram)
 from repro.core.partitioner import (I32_INF, NEConfig, PartitionResult,
                                     alpha_limit, finalize_result,
                                     one_hop, priority_enc, vertex_claims)
@@ -78,14 +79,16 @@ def _apply_alloc(new, part, u_loc, v_loc, n, p_num, vparts, degree_rest,
     (uint32 words — cfg.use_pallas), the replica-set delta is packed
     *before* the collective, so the all-reduce moves (N, ceil(P/32))·4
     bytes instead of the bool path's (N, P)·4-byte int32 psum — exact OR
-    either way, hence bit-identical replica sets after unpacking.
+    either way, hence bit-identical replica sets after unpacking.  The
+    (P,) count delta, unless the caller has it as ``local_counts``, is a
+    ``target_histogram`` of the batch (C·P compare-adds, no scatter).
     """
     packed = vparts.dtype == jnp.uint32
     newi = new.astype(jnp.int32)
     add = jnp.where(new, part, 0)
     counts = local_counts
     if counts is None:
-        counts = jnp.zeros((p_num,), jnp.int32).at[add].add(newi)
+        counts = target_histogram(jnp.where(new, part, -1), p_num)
     counts = jax.lax.psum(counts, AXIS)
     drop_u = jnp.where(new, u_loc, n)
     drop_v = jnp.where(new, v_loc, n)
@@ -167,8 +170,7 @@ def _spmd_round(cfg: NEConfig, limit: int, n: int, num_dev: int,
                                    (best % p_num).astype(jnp.int32), -1)
                 rank_c = exclusive_rank(cand_c, p_num) \
                     + counts[jnp.maximum(cand_c, 0)]
-                counts = counts.at[jnp.maximum(cand_c, 0)].add(
-                    (cand_c >= 0).astype(jnp.int32))
+                counts = counts + target_histogram(cand_c, p_num)
                 return counts, (cand_c, rank_c)
 
             hist, (cand, myrank) = jax.lax.scan(

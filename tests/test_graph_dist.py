@@ -1,10 +1,12 @@
 """Tests for the distribution helpers: 2D-hash edge sharding
 (core/graph.py), the leftover cleanup pass, and edge redistribution."""
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import NEConfig, evaluate, theorem1_upper_bound
-from repro.core.graph import grid_assign, shard_edges
+from repro.core.graph import (exclusive_rank, grid_assign, shard_edges,
+                              target_histogram)
 from repro.core.partitioner import cleanup_leftovers
 from repro.graphs.generators import erdos_renyi
 from repro.graphs.rmat import rmat
@@ -66,6 +68,38 @@ def test_shard_edges_roundtrip(graph, d):
     got = np.sort(np.concatenate([key(shards[i][masks[i]])
                                   for i in range(d)]))
     np.testing.assert_array_equal(got, np.sort(key(e)))
+
+
+# ---------------------------------------------------------------------------
+# target_histogram / exclusive_rank: the one-hot reductions against numpy
+# ---------------------------------------------------------------------------
+
+def _rank_loop(ids, t):
+    """Exclusive rank by a Python loop; a negative id reads target 0's."""
+    seen = np.zeros(t, np.int64)
+    out = np.empty(ids.shape, np.int64)
+    for i, c in enumerate(ids):
+        out[i] = seen[c] if c >= 0 else seen[0] - 1
+        if c >= 0:
+            seen[c] += 1
+    return out
+
+
+@pytest.mark.parametrize("length", [1, 300, 1000])
+@pytest.mark.parametrize("t", [1, 16, 64, 96])
+@pytest.mark.parametrize("kind", ["mixed", "all_negative"])
+def test_target_histogram_and_rank_match_numpy(kind, t, length):
+    rng = np.random.default_rng(t * 1000 + length)
+    if kind == "mixed":   # about a quarter of the items target nothing
+        ids = rng.integers(-t // 3 - 1, t, size=length).astype(np.int32)
+    else:
+        ids = rng.integers(-5, 0, size=length).astype(np.int32)
+    hist = np.asarray(target_histogram(jnp.asarray(ids), t))
+    assert hist.dtype == np.int32 and hist.shape == (t,)
+    np.testing.assert_array_equal(
+        hist, np.bincount(ids[ids >= 0], minlength=t))
+    np.testing.assert_array_equal(
+        np.asarray(exclusive_rank(jnp.asarray(ids), t)), _rank_loop(ids, t))
 
 
 # ---------------------------------------------------------------------------

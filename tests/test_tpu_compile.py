@@ -12,6 +12,8 @@ module is imported would make the test workers collect different tests.
 Kernels are called directly with ``interpret=False`` — the ops front
 door picks interpret mode from the (CPU) default backend.
 """
+import re
+
 import pytest
 
 import jax
@@ -59,6 +61,17 @@ def no_cache():
     cc.reset_cache()
     yield
     jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _partition_axis_scatters_gathers(text, p_num, chunk):
+    """Scatters into a (P,) int32 target, and gathers that read the
+    (chunk, P) int32 running count, in compiled HLO text: the round
+    computes both as one-hot reductions, so neither may appear."""
+    shapes = dict(re.findall(r"%(\S+) = (\w+\[[\d,]*\])", text))
+    scatters = re.findall(rf"= s32\[{p_num}\]\S* scatter\(", text)
+    gathers = [op for op in re.findall(r"= \S+ gather\(%([^,\s]+)", text)
+               if shapes.get(op) == f"s32[{chunk},{p_num}]"]
+    return scatters, gathers
 
 
 def _kernel_compiles(fn, *args):
@@ -125,6 +138,8 @@ def test_spmd_round_step_compiles(topo, no_cache, monkeypatch, num_dev,
         assert "all-reduce" in text or "collective-permute" in text
     if use_pallas:
         assert "tpu_custom_call" in text
+    assert _partition_axis_scatters_gathers(
+        text, P_NUM, min(cfg.edge_chunk, cap)) == ([], [])
     mem = compiled.memory_analysis()
     if mem is not None:
         used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
