@@ -25,7 +25,9 @@ Steps 2–4 touch only the local shard, so per-round work scales 1/D; the
 sync in step 3 is the round barrier the paper describes.  Each step runs
 under a ``jax.named_scope`` — ``ne_select``, ``ne_one_hop``, ``ne_sync``
 (both ``_apply_alloc`` calls) and ``ne_two_hop`` — which names every op's
-phase in a profiler trace.  Because steps 1–3
+phase in a profiler trace; each collective call alone sits in a nested
+``ne_exchange`` scope, so the cross-device exchange reads apart from the
+local work of its phase.  Because steps 1–3
 are bit-identical to the single-controller fixed point and only the quota
 *ordering* in step 4 differs, the resulting quality (replication factor)
 matches ``core.partitioner.partition`` closely — asserted by
@@ -54,6 +56,8 @@ from repro.kernels.ne_round import ops as ne_ops
 from repro.io.stream import require_canonical, shard_edges_stream
 
 AXIS = "shard"
+# the scope of the round's collective calls, and of nothing else
+EXCHANGE = "ne_exchange"
 Array = jax.Array
 
 
@@ -67,6 +71,11 @@ class SpmdState(NamedTuple):
     key: Array              # PRNG key — replicated
     rounds: Array           # ()     int32
     remaining: Array        # ()     int32 unallocated edges, global
+
+
+# the round state's layout: ``edge_part`` sharded over the device axis,
+# every other field replicated
+STATE_SPECS = SpmdState(P(AXIS, None), *(P(),) * 6)
 
 
 @jax.named_scope("ne_sync")
@@ -89,24 +98,32 @@ def _apply_alloc(new, part, u_loc, v_loc, n, p_num, vparts, degree_rest,
     counts = local_counts
     if counts is None:
         counts = target_histogram(jnp.where(new, part, -1), p_num)
-    counts = jax.lax.psum(counts, AXIS)
+    with jax.named_scope(EXCHANGE):
+        counts = jax.lax.psum(counts, AXIS)
     drop_u = jnp.where(new, u_loc, n)
     drop_v = jnp.where(new, v_loc, n)
     if packed:
         vnew = jnp.zeros((n, p_num), bool)
         vnew = vnew.at[drop_u, add].set(True, mode="drop")
         vnew = vnew.at[drop_v, add].set(True, mode="drop")
-        delta = compat.or_all_reduce(ne_ops.pack_bits(vnew), AXIS, num_dev)
+        words = ne_ops.pack_bits(vnew)
+        with jax.named_scope(EXCHANGE):
+            delta = compat.or_all_reduce(words, AXIS, num_dev)
         vparts = ne_ops.or_words(vparts, delta)
     else:
         vnew = jnp.zeros_like(vparts)
         vnew = vnew.at[drop_u, add].set(True, mode="drop")
         vnew = vnew.at[drop_v, add].set(True, mode="drop")
-        vparts = vparts | (jax.lax.psum(vnew.astype(jnp.int32), AXIS) > 0)
+        vnewi = vnew.astype(jnp.int32)
+        with jax.named_scope(EXCHANGE):
+            hits = jax.lax.psum(vnewi, AXIS)
+        vparts = vparts | (hits > 0)
     dec = (jnp.zeros((n,), jnp.int32)
            .at[drop_u].add(newi, mode="drop")
            .at[drop_v].add(newi, mode="drop"))
-    degree_rest = degree_rest - jax.lax.psum(dec, AXIS)
+    with jax.named_scope(EXCHANGE):
+        dec = jax.lax.psum(dec, AXIS)
+    degree_rest = degree_rest - dec
     return vparts, degree_rest, edges_per_part + counts, counts.sum()
 
 
@@ -181,11 +198,16 @@ def _spmd_round(cfg: NEConfig, limit: int, n: int, num_dev: int,
             myrank = myrank.reshape(-1)[:c_len]
             cand0 = jnp.maximum(cand, 0)
             # deterministic cross-device quota split: device d's candidates for
-            # partition p rank after all candidates on devices < d.
-            hists = jax.lax.all_gather(hist, AXIS)                    # (D, P)
+            # partition p rank after all candidates on devices < d.  The
+            # (D, P) gather of the histograms is the psum of each device's
+            # own row: the compiler makes an all-reduce of an all_gather
+            # here anyway, and drops the op_name on the way
             r = jax.lax.axis_index(AXIS)
-            before = jnp.where(jnp.arange(hists.shape[0])[:, None] < r,
-                               hists, 0).sum(axis=0)                  # (P,)
+            rows = jnp.arange(num_dev)[:, None]
+            own = jnp.where(rows == r, hist[None, :], 0)
+            with jax.named_scope(EXCHANGE):
+                hists = jax.lax.psum(own, AXIS)                       # (D, P)
+            before = jnp.where(rows < r, hists, 0).sum(axis=0)        # (P,)
             keep = (cand >= 0) & (before[cand0] + myrank < quota[cand0])
             part2 = jnp.where(keep, cand, -1)
             edge_part = jnp.where(keep, part2, edge_part)
@@ -218,12 +240,11 @@ def shard_over(mesh, x) -> Array:
 
 
 def place_state(mesh, state: SpmdState) -> SpmdState:
-    """Place a round state on ``mesh``: ``edge_part`` sharded over the
-    device axis, every other field replicated — the layout
-    :func:`spmd_round_step` takes, so no round reshards from one chip."""
-    rep = NamedSharding(mesh, P())
+    """Place a round state on ``mesh`` in the layout of :data:`STATE_SPECS`:
+    the one :func:`spmd_round_step` takes and returns, so no round
+    reshards."""
     return jax.device_put(state, SpmdState(
-        NamedSharding(mesh, P(AXIS, None)), *(rep,) * 6))
+        *(NamedSharding(mesh, spec) for spec in STATE_SPECS)))
 
 
 def spmd_init_state(shards: np.ndarray, masks: np.ndarray, n: int,
@@ -248,7 +269,8 @@ def spmd_init_state(shards: np.ndarray, masks: np.ndarray, n: int,
     ))
 
 
-@partial(jax.jit, static_argnames=("cfg", "limit", "n", "mesh"))
+@partial(jax.jit, static_argnames=("cfg", "limit", "n", "mesh"),
+         out_shardings=STATE_SPECS)
 def spmd_round_step(cfg: NEConfig, limit: int, n: int, mesh,
                     u_sh: Array, v_sh: Array, mask_sh: Array,
                     state: SpmdState) -> SpmdState:
@@ -259,7 +281,11 @@ def spmd_round_step(cfg: NEConfig, limit: int, n: int, mesh,
     pausing/snapshotting/resuming between them — is bit-identical to the
     fire-and-forget :func:`partition_spmd` (asserted by
     tests/test_runtime.py).  ``state.edge_part`` is (D, C) and sharded over
-    the device axis; everything else is replicated.
+    the device axis; everything else is replicated (:data:`STATE_SPECS`).
+    The state comes back in that layout too, so round k's output is round
+    k+1's input with no reshard and no second trace (left to itself, jit
+    returns one device's ``vparts`` sharded like ``edge_part``).  So call it
+    under ``jax.set_mesh(mesh)``, which the output specs are read against.
     """
     num_dev = mesh.shape[AXIS]
 
@@ -269,12 +295,10 @@ def spmd_round_step(cfg: NEConfig, limit: int, n: int, mesh,
                           mask_l[0], st)
         return out._replace(edge_part=out.edge_part[None])
 
-    rep = (P(),) * 6
     return jax.shard_map(
         body, mesh=mesh,
-        in_specs=(P(AXIS, None), P(AXIS, None), P(AXIS, None),
-                  P(AXIS, None)) + rep,
-        out_specs=SpmdState(P(AXIS, None), *rep),
+        in_specs=(P(AXIS, None),) * 3 + tuple(STATE_SPECS),
+        out_specs=STATE_SPECS,
         check_vma=False,
     )(u_sh, v_sh, mask_sh, *state)
 
@@ -332,17 +356,25 @@ def round_quality(cfg: NEConfig, state, n: int) -> dict:
 def round_sync_payload_bytes(cfg: NEConfig, n: int, num_dev: int) -> int:
     """Per-device bytes one round's SyncVertexAllocations moves.
 
-    The round-loop telemetry counter (``repro.obs``): each
-    ``_apply_alloc`` all-reduces the replica-set delta — (N, ⌈P/32⌉)
-    uint32 words under ``cfg.use_pallas``, an (N, P) int32 psum
-    otherwise — plus the (P,) count and (N,) D_rest deltas; the two-hop
-    pass adds a second sync and the (D, P) quota-histogram all_gather.
-    A pure function of the config so the driver can record it per round
-    without touching device state.
+    The round-loop telemetry counter (``repro.obs``) and the ``round``
+    span's argument: each ``_apply_alloc`` all-reduces the replica-set
+    delta — an (N, P) int32 psum, or under ``cfg.use_pallas`` the
+    (N, ⌈P/32⌉) uint32 words through ``compat.or_all_reduce``, which
+    sends them once per recursive-doubling step (log2 D of them) or
+    all-gathers D rows where D is no power of two — plus the (P,) count
+    and (N,) D_rest deltas; the two-hop pass adds a second sync and the
+    (D, P) quota histograms.  Each collective counts its operand (an
+    all-gather its result), as in the compiled round
+    (tests/test_tpu_compile.py); on one device, where the collectives
+    compile away, it counts their operands all the same (the packed words
+    once).  A pure function of the config so the driver can record it per
+    round without touching device state.
     """
     p = cfg.num_partitions
     if cfg.use_pallas:
-        vbytes = n * ne_ops.replica_words(p) * 4
+        d = max(num_dev, 2)
+        sends = (d - 1).bit_length() if d & (d - 1) == 0 else d
+        vbytes = n * ne_ops.replica_words(p) * 4 * sends
     else:
         vbytes = n * p * 4
     per_sync = vbytes + p * 4 + n * 4

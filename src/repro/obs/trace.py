@@ -111,13 +111,14 @@ class _AnnotatedSpan:
             self._span.set(**args)
 
 
-def _annotation(name: str):
-    """``TraceAnnotation("repro.<name>")`` once jax is loaded, else None;
-    never imports jax itself."""
+def _annotation(name: str, args: dict):
+    """``TraceAnnotation("repro.<name>", **args)`` once jax is loaded, else
+    None; never imports jax itself.  The profiler keeps ``args`` as the
+    event's stats (and encodes them only while a session is open)."""
     jax = sys.modules.get("jax")
     if jax is None:
         return None
-    return jax.profiler.TraceAnnotation(ANNOTATION_PREFIX + name)
+    return jax.profiler.TraceAnnotation(ANNOTATION_PREFIX + name, **args)
 
 
 class _Span:
@@ -311,11 +312,12 @@ def from_env(default_dir: str | os.PathLike | None = None,
 
 def span(name: str, cat: str = "run", **args):
     """Context manager for one span: a JSONL span when a tracer is
-    configured, and a profiler annotation ``repro.<name>`` when jax is
-    loaded; ``NULL_SPAN`` when neither."""
+    configured, and a profiler annotation ``repro.<name>`` carrying
+    ``args`` as its stats when jax is loaded; ``NULL_SPAN`` when neither.
+    Args added later with ``set`` reach the JSONL span alone."""
     t = _TRACER
     sp = t.span(name, cat, **args) if t is not None else None
-    ann = _annotation(name)
+    ann = _annotation(name, args)
     if ann is None:
         return NULL_SPAN if sp is None else sp
     return _AnnotatedSpan(ann, sp)

@@ -290,8 +290,12 @@ class PartitionDriver:
             return self.rounds
         # the round span covers the snapshot save too (nested "snapshot"
         # span): per-round cost as a long run pays it, matching the old
-        # hand-timed round_secs the multihost_snap bench row diffs
-        with obs.span("round", cat="runtime") as sp:
+        # hand-timed round_secs the multihost_snap bench row diffs.  An
+        # SPMD round's span carries what its sync sends, so a profiler
+        # trace holds the count beside the exchange's device time
+        args = ({"sync_payload_bytes": self._sync_bytes}
+                if self._sync_bytes else {})
+        with obs.span("round", cat="runtime", **args) as sp:
             # the call returns once the round is enqueued; the wait is the
             # device's time, the read the scalar the step returns
             with obs.span("round_dispatch", cat="runtime"):
@@ -300,9 +304,11 @@ class PartitionDriver:
                     state = ne_round_step(self._graph, cfg, self.limit,
                                           self.state)
                 else:
-                    state = spmd_round_step(
-                        self.cfg, self.limit, self.n, self.mesh, self._u_sh,
-                        self._v_sh, self._mask_sh, self.state)
+                    with jax.set_mesh(self.mesh):
+                        state = spmd_round_step(
+                            self.cfg, self.limit, self.n, self.mesh,
+                            self._u_sh, self._v_sh, self._mask_sh,
+                            self.state)
             with obs.span("round_wait", cat="runtime"):
                 self.state = jax.block_until_ready(state)
             with obs.span("round_read", cat="runtime"):

@@ -21,7 +21,8 @@ from jax.profiler import ProfileData
 from repro.core.graph import shard_edges
 from repro.core.partitioner import (NEConfig, alpha_limit, ne_init_state,
                                     ne_round_step)
-from repro.dist.partitioner_sm import (AXIS, shard_over, spmd_init_state,
+from repro.dist.partitioner_sm import (AXIS, round_sync_payload_bytes,
+                                       shard_over, spmd_init_state,
                                        spmd_round_step)
 from repro.graphs.rmat import rmat
 from repro.launch.mesh import make_edge_mesh
@@ -67,8 +68,11 @@ def test_span_reaches_the_profiler(tmp_path, jsonl):
             sp.set(done=True)
             with obs.span("inner"):
                 pass
-    names = [e[0] for e in _repro_events(tmp_path)]
-    assert names == ["outer", "inner"]
+    events = _repro_events(tmp_path)
+    assert [e[0] for e in events] == ["outer", "inner"]
+    # the span's own args ride on the profiler event; ``set`` reaches the
+    # JSONL span alone
+    assert events[0][3].get("k") == 1 and "done" not in events[0][3]
     if tr is None:
         assert obs.get_tracer() is None
     else:
@@ -134,6 +138,10 @@ def test_driver_spans_on_the_profiler(tmp_path):
     assert rounds > 1
     for name in ("round", "round_dispatch", "round_wait", "round_read"):
         assert count[name] == rounds, name
+    # each SPMD round says on the trace what its sync sends
+    want = round_sync_payload_bytes(drv.cfg, drv.n, 1)
+    assert [e[3].get("sync_payload_bytes") for e in events
+            if e[0] == "round"] == [want] * rounds
     assert count["done_read"] == rounds + 1
     for name in ("ingest", "ingest_shards", "ingest_place", "finalize",
                  "device_get", "stitch_edge_part", "finalize_result",
@@ -184,10 +192,12 @@ def _round_hlo(path: str) -> str:
     shards, masks, _, _ = shard_edges(np.asarray(g.edges), 1)
     mesh = make_edge_mesh(1, axis=AXIS)
     state = spmd_init_state(shards, masks, g.num_vertices, cfg, mesh)
-    return spmd_round_step.lower(
-        cfg, limit, g.num_vertices, mesh, shard_over(mesh, shards[:, :, 0]),
-        shard_over(mesh, shards[:, :, 1]), shard_over(mesh, masks),
-        state).compile().as_text()
+    with jax.set_mesh(mesh):
+        return spmd_round_step.lower(
+            cfg, limit, g.num_vertices, mesh,
+            shard_over(mesh, shards[:, :, 0]),
+            shard_over(mesh, shards[:, :, 1]), shard_over(mesh, masks),
+            state).compile().as_text()
 
 
 @pytest.mark.parametrize("path", ["spmd-bool", "spmd-packed", "single"])

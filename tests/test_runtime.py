@@ -56,6 +56,46 @@ def test_driver_bit_identical_to_partition_spmd(graph12, snapped_run):
     assert res.leftover == ref.leftover
 
 
+# four host devices need a process of their own: the device count is
+# fixed before jax starts
+_LAYOUT_CHECK = """
+import jax
+from repro.core import NEConfig
+from repro.dist.partitioner_sm import place_state, spmd_round_step
+from repro.graphs.rmat import rmat
+from repro.runtime import PartitionDriver
+
+assert len(jax.devices()) == 4
+cfg = NEConfig(num_partitions=8, seed=0, k_sel=32, edge_chunk=1 << 10)
+for d in (1, 4):
+    drv = PartitionDriver(rmat(9, 8, seed=1), cfg, num_devices=d)
+    placed = [x.sharding for x in place_state(drv.mesh, drv.state)]
+    before = spmd_round_step._cache_size()
+    for _ in range(2):
+        drv.step()
+        assert [x.sharding for x in drv.state] == placed, (d, drv.rounds)
+    assert spmd_round_step._cache_size() == before + 1, d
+print("placed")
+"""
+
+
+def test_driver_keeps_the_placed_layout():
+    """On 1 and 4 of four CPU devices, two ``step()`` calls leave every
+    state field in ``place_state``'s layout, and the round is traced once:
+    round k's output is round k+1's input as it stands."""
+    import subprocess
+    import sys
+
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=src,
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    proc = subprocess.run([sys.executable, "-c", _LAYOUT_CHECK], env=env,
+                          capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert proc.stdout.split()[-1] == "placed"
+
+
 def test_driver_single_mode_matches_partition(graph12):
     drv = PartitionDriver(graph12, CFG, mode="single")
     res = drv.run()
