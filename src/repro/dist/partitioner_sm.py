@@ -15,7 +15,8 @@ One while_loop step == one paper round, per device:
      ``core.partitioner.one_hop``, restricted to the local shard;
   3. **SyncVertexAllocations** — the paper's §4 merge, realized as an OR
      all-reduce of the replica-set deltas plus ``psum`` of the |E_p| and
-     D_rest deltas;
+     D_rest deltas, each device's deltas scattered from its batch's new
+     edges alone (a sort brings them to the front, then fixed chunks);
   4. **two-hop "free edge" allocation** (Condition (5)) over local edges,
      with the per-round α-capacity quota split deterministically across
      devices by an exclusive prefix over the device axis (an ``all_gather``
@@ -35,6 +36,7 @@ tests/test_spmd.py.
 """
 from __future__ import annotations
 
+import math
 from functools import partial
 from typing import NamedTuple
 
@@ -78,49 +80,79 @@ class SpmdState(NamedTuple):
 STATE_SPECS = SpmdState(P(AXIS, None), *(P(),) * 6)
 
 
+def sync_chunk(c: int) -> int:
+    """Slots one trip of the sync's fold scatters, from the shard length
+    C alone: the power of two nearest C/64, within [2**12, 2**16] and no
+    more than C.  A batch of every slot then takes at most ~64 trips, and
+    the last trip's unused tail is about 1/128 of C on average."""
+    k = 1 << max(0, round(math.log2(max(c, 1) / 64)))
+    return max(1, min(c, max(1 << 12, min(k, 1 << 16))))
+
+
+def _fold_new(new, n_new, part, u_loc, v_loc, n, p_num):
+    """This device's replica-set delta (N, P) and D_rest decrement (N,)
+    for one batch, scattered from its ``n_new`` new slots alone.
+
+    One sort brings the new slots' indices to the front (every other slot
+    keys as C, and sorts behind them); then ceil(n_new / K) trips of
+    :func:`sync_chunk`'s K each gather K indices' endpoints and partition
+    and scatter them, the tail of the last trip dropped.  Sets are ORs and
+    decrements integer adds, so the order the slots fold in cannot change
+    the result."""
+    c = new.shape[0]
+    k = sync_chunk(c)
+    pad = -c % k
+    keys = jnp.where(jnp.pad(new, (0, pad)),
+                     jnp.arange(c + pad, dtype=jnp.int32), c)
+    order = jax.lax.sort(keys, is_stable=False)     # the keys are distinct
+
+    def trip(t, acc):
+        vnew, dec = acc
+        idx = jax.lax.dynamic_slice(order, (t * k,), (k,))
+        u = u_loc.at[idx].get(mode="fill", fill_value=n)
+        v = v_loc.at[idx].get(mode="fill", fill_value=n)
+        p = part.at[idx].get(mode="fill", fill_value=0)
+        vnew = vnew.at[u, p].set(True, mode="drop")
+        vnew = vnew.at[v, p].set(True, mode="drop")
+        dec = dec.at[u].add(1, mode="drop").at[v].add(1, mode="drop")
+        return vnew, dec
+
+    return jax.lax.fori_loop(
+        0, (n_new + k - 1) // k, trip,
+        (jnp.zeros((n, p_num), bool), jnp.zeros((n,), jnp.int32)))
+
+
 @jax.named_scope("ne_sync")
 def _apply_alloc(new, part, u_loc, v_loc, n, p_num, vparts, degree_rest,
                  edges_per_part, num_dev, local_counts=None):
     """Fold one local allocation batch into the replicated state.
 
     ``psum`` of the per-device deltas + OR of the replica-set delta ==
-    the paper's SyncVertexAllocations.  When ``vparts`` arrives bit-packed
-    (uint32 words — cfg.use_pallas), the replica-set delta is packed
-    *before* the collective, so the all-reduce moves (N, ceil(P/32))·4
-    bytes instead of the bool path's (N, P)·4-byte int32 psum — exact OR
-    either way, hence bit-identical replica sets after unpacking.  The
-    (P,) count delta, unless the caller has it as ``local_counts``, is a
-    ``target_histogram`` of the batch (C·P compare-adds, no scatter).
+    the paper's SyncVertexAllocations.  The deltas are scattered from the
+    batch's new slots alone (:func:`_fold_new`).  When ``vparts`` arrives
+    bit-packed (uint32 words — cfg.use_pallas), the replica-set delta is
+    packed *before* the collective, so the all-reduce moves
+    (N, ceil(P/32))·4 bytes instead of the bool path's (N, P)·4-byte int32
+    psum — exact OR either way, hence bit-identical replica sets after
+    unpacking.  The (P,) count delta, unless the caller has it as
+    ``local_counts``, is a ``target_histogram`` of the batch (C·P
+    compare-adds, no scatter).
     """
-    packed = vparts.dtype == jnp.uint32
-    newi = new.astype(jnp.int32)
-    add = jnp.where(new, part, 0)
     counts = local_counts
     if counts is None:
         counts = target_histogram(jnp.where(new, part, -1), p_num)
+    vnew, dec = _fold_new(new, counts.sum(), part, u_loc, v_loc, n, p_num)
     with jax.named_scope(EXCHANGE):
         counts = jax.lax.psum(counts, AXIS)
-    drop_u = jnp.where(new, u_loc, n)
-    drop_v = jnp.where(new, v_loc, n)
-    if packed:
-        vnew = jnp.zeros((n, p_num), bool)
-        vnew = vnew.at[drop_u, add].set(True, mode="drop")
-        vnew = vnew.at[drop_v, add].set(True, mode="drop")
+    if vparts.dtype == jnp.uint32:
         words = ne_ops.pack_bits(vnew)
         with jax.named_scope(EXCHANGE):
             delta = compat.or_all_reduce(words, AXIS, num_dev)
         vparts = ne_ops.or_words(vparts, delta)
     else:
-        vnew = jnp.zeros_like(vparts)
-        vnew = vnew.at[drop_u, add].set(True, mode="drop")
-        vnew = vnew.at[drop_v, add].set(True, mode="drop")
-        vnewi = vnew.astype(jnp.int32)
         with jax.named_scope(EXCHANGE):
-            hits = jax.lax.psum(vnewi, AXIS)
+            hits = jax.lax.psum(vnew.astype(jnp.int32), AXIS)
         vparts = vparts | (hits > 0)
-    dec = (jnp.zeros((n,), jnp.int32)
-           .at[drop_u].add(newi, mode="drop")
-           .at[drop_v].add(newi, mode="drop"))
     with jax.named_scope(EXCHANGE):
         dec = jax.lax.psum(dec, AXIS)
     degree_rest = degree_rest - dec
@@ -301,12 +333,6 @@ def spmd_round_step(cfg: NEConfig, limit: int, n: int, mesh,
         out_specs=STATE_SPECS,
         check_vma=False,
     )(u_sh, v_sh, mask_sh, *state)
-
-
-def spmd_done(state: SpmdState, cfg: NEConfig) -> bool:
-    """Host-side mirror of the whole-run while_loop condition."""
-    return bool(int(state.remaining) <= 0
-                or int(state.rounds) >= cfg.max_rounds)
 
 
 @partial(jax.jit, static_argnames=("p_num",))

@@ -57,8 +57,8 @@ from repro.dist import compat
 from repro.dist.partitioner_sm import (AXIS, SpmdState, place_state,
                                        round_quality,
                                        round_sync_payload_bytes, shard_over,
-                                       spmd_done, spmd_init_state,
-                                       spmd_round_step, stitch_edge_part)
+                                       spmd_init_state, spmd_round_step,
+                                       stitch_edge_part, sync_chunk)
 from repro.io.edgefile import EdgeFile
 from repro.kernels.ne_round import ops as ne_ops
 from repro.io.stream import require_canonical
@@ -99,6 +99,11 @@ class PartitionDriver:
         self.snapshot_every = int(snapshot_every)
         self._result: PartitionResult | None = None
         self._done: bool | None = None
+        # SPMD: ``remaining`` as last read from the device (None until the
+        # current state's read), the read before it, and their difference
+        self._rem: int | None = None
+        self._rem_last: int | None = None
+        self._new_edges: int | None = None
         self._host, self._nprocs = compat.process_env()
         self.multihost = self.mode == "spmd" and self._nprocs > 1
         self._final_slices = None   # set by the sharded finalize epilogue
@@ -172,6 +177,9 @@ class PartitionDriver:
                             round_sync_payload_bytes(self.cfg, self.n,
                                                      self.num_devices))
         self._sync_total = 0
+        # and the slots one trip of its fold scatters (sync_chunk)
+        self._sync_chunk = (sync_chunk(int(self._mask_sh.shape[1]))
+                            if self._sync_bytes else 0)
         if live.live_enabled():
             live.publish(phase="ingest", round=0, edges_remaining=self.m)
         self.snapshot = (RunSnapshot(snapshot_dir, self.cfg, self._graph_fp,
@@ -276,9 +284,32 @@ class PartitionDriver:
                 with obs.span("done_read", cat="runtime"):
                     self._done = ne_done(self.state, self.cfg)
             else:
+                # the host mirror of the whole-run while_loop condition
                 with obs.span("done_read", cat="runtime"):
-                    self._done = spmd_done(self.state, self.cfg)
+                    self._done = (self._remaining() <= 0
+                                  or self.rounds >= self.cfg.max_rounds)
         return self._done
+
+    def _remaining(self) -> int:
+        """The SPMD state's unallocated edges, read from the device once
+        per state.  The drop since the previous read is what the rounds
+        between them folded, both syncs of each (``sync_new_edges``)."""
+        if self._rem is None:
+            self._rem = int(self.state.remaining)
+            if self._rem_last is not None:
+                self._new_edges = self._rem_last - self._rem
+            self._rem_last = self._rem
+        return self._rem
+
+    def _state_changed(self, restored: bool = False):
+        """Drop what was read from or built on the previous state; after
+        a restore the next read of ``remaining`` starts the count anew."""
+        self._result = None
+        self._final_slices = None
+        self._done = None
+        self._rem = None
+        if restored:
+            self._rem_last = self._new_edges = None
 
     def step(self) -> int:
         """Advance one paper round; returns the completed round count.
@@ -292,9 +323,14 @@ class PartitionDriver:
         # span): per-round cost as a long run pays it, matching the old
         # hand-timed round_secs the multihost_snap bench row diffs.  An
         # SPMD round's span carries what its sync sends, so a profiler
-        # trace holds the count beside the exchange's device time
-        args = ({"sync_payload_bytes": self._sync_bytes}
-                if self._sync_bytes else {})
+        # trace holds the count beside the exchange's device time, and
+        # the sync's chunk with the new edges the round before folded
+        args = {}
+        if self._sync_bytes:
+            args = {"sync_payload_bytes": self._sync_bytes,
+                    "sync_chunk": self._sync_chunk}
+            if self._new_edges is not None:
+                args["sync_new_edges"] = self._new_edges
         with obs.span("round", cat="runtime", **args) as sp:
             # the call returns once the round is enqueued; the wait is the
             # device's time, the read the scalar the step returns
@@ -313,30 +349,26 @@ class PartitionDriver:
                 self.state = jax.block_until_ready(state)
             with obs.span("round_read", cat="runtime"):
                 rounds = self.rounds
+            self._state_changed()
             tr = obs.get_tracer()
             if tr is not None:
                 sp.set(round=rounds)
-                rem = getattr(self.state, "remaining", None)
-                if rem is not None:
-                    tr.counter("edges_remaining", int(rem))
-                if self._sync_bytes:
+                if self.mode == "spmd":
+                    tr.counter("edges_remaining", self._remaining())
+                    tr.counter("sync_new_edges", self._new_edges)
                     tr.add("sync_payload_bytes", self._sync_bytes)
             self._sync_total += self._sync_bytes
             if live.live_enabled():
                 # pure read of the replicated state (no RNG, no mutation),
                 # so monitored runs stay bit-identical to unmonitored
                 q = round_quality(self.cfg, self.state, self.n)
-                rem = getattr(self.state, "remaining", None)
-                rem = (int(rem) if rem is not None
+                rem = (self._remaining() if self.mode == "spmd"
                        else q["degree_sum"] // 2)
                 live.publish(phase="round", round=rounds,
                              edges_remaining=rem,
                              sync_payload_bytes=self._sync_total,
                              rf=q["rf"], eb=q["eb"], vb=q["vb"],
                              boundary=q["boundary"])
-            self._result = None
-            self._final_slices = None
-            self._done = None
             if (self.snapshot is not None and self.snapshot_every
                     and rounds % self.snapshot_every == 0):
                 self.save_snapshot()
@@ -551,9 +583,7 @@ class PartitionDriver:
         state = cls(**{k: np.asarray(fields[k]) for k in want})
         self.state = (place_state(self.mesh, state) if self.mode == "spmd"
                       else jax.tree.map(jnp.asarray, state))
-        self._result = None
-        self._final_slices = None
-        self._done = None
+        self._state_changed(restored=True)
         return rnd
 
     def _reshard_in_memory(self, old: np.ndarray) -> np.ndarray:
@@ -612,9 +642,7 @@ class PartitionDriver:
         rep = {k: mh.replicate(self.mesh, fields[k])
                for k in SpmdState._fields if k != "edge_part"}
         self.state = SpmdState(edge_part=edge_part, **rep)
-        self._result = None
-        self._final_slices = None
-        self._done = None
+        self._state_changed(restored=True)
         compat.barrier(f"resume-{rnd}")
         return rnd
 

@@ -23,7 +23,7 @@ from repro.core.partitioner import (NEConfig, alpha_limit, ne_init_state,
                                     ne_round_step)
 from repro.dist.partitioner_sm import (AXIS, round_sync_payload_bytes,
                                        shard_over, spmd_init_state,
-                                       spmd_round_step)
+                                       spmd_round_step, sync_chunk)
 from repro.graphs.rmat import rmat
 from repro.launch.mesh import make_edge_mesh
 from repro.obs import trace as obs
@@ -142,6 +142,13 @@ def test_driver_spans_on_the_profiler(tmp_path):
     want = round_sync_payload_bytes(drv.cfg, drv.n, 1)
     assert [e[3].get("sync_payload_bytes") for e in events
             if e[0] == "round"] == [want] * rounds
+    # and the chunk its fold scatters in, with the new edges the round
+    # before it folded (the last round's the driver holds after the loop)
+    assert [e[3].get("sync_chunk") for e in events if e[0] == "round"] \
+        == [sync_chunk(drv._mask_sh.shape[1])] * rounds
+    new = [e[3].get("sync_new_edges") for e in events if e[0] == "round"]
+    assert new[0] is None and min(new[1:]) >= 0
+    assert sum(new[1:]) + drv._new_edges == drv.m
     assert count["done_read"] == rounds + 1
     for name in ("ingest", "ingest_shards", "ingest_place", "finalize",
                  "device_get", "stitch_edge_part", "finalize_result",

@@ -96,6 +96,67 @@ def test_driver_keeps_the_placed_layout():
     assert proc.stdout.split()[-1] == "placed"
 
 
+class _CountedRead:
+    """A device scalar of the round state whose host reads are counted."""
+
+    def __init__(self, x, reads):
+        self.x, self.reads = x, reads
+
+    def __int__(self):
+        self.reads.append(1)
+        return int(self.x)
+
+    def block_until_ready(self):
+        self.x.block_until_ready()
+        return self
+
+
+def test_sync_new_edges_sum_to_m_without_a_new_read(monkeypatch):
+    """Over one whole job the ``sync_new_edges`` counter, one sample a
+    round, sums to m: it is the drop in the ``remaining`` the driver reads
+    for ``done`` anyway, so a traced job reads the round's scalars
+    (``remaining``, ``rounds``) to the host no more often than an untraced
+    one."""
+    import repro.runtime.driver as driver
+    from repro.obs import trace as obs
+
+    reads = []
+    step = driver.spmd_round_step
+
+    def counted(*args):
+        *head, state = args
+        state = state._replace(
+            remaining=getattr(state.remaining, "x", state.remaining),
+            rounds=getattr(state.rounds, "x", state.rounds))
+        out = step(*head, state)
+        return out._replace(remaining=_CountedRead(out.remaining, reads),
+                            rounds=_CountedRead(out.rounds, reads))
+
+    monkeypatch.setattr(driver, "spmd_round_step", counted)
+    g = rmat(10, 8, seed=2)
+    cfg = NEConfig(num_partitions=4, k_sel=32, edge_chunk=1 << 10)
+
+    def job():
+        reads.clear()
+        drv = PartitionDriver(g, cfg, num_devices=1)
+        while not drv.done:
+            drv.step()
+        return drv, len(reads)
+
+    obs.disable()
+    _, untraced = job()
+    tr = obs.configure()
+    try:
+        drv, traced = job()
+    finally:
+        obs.disable()
+    assert drv.rounds > 1 and traced == untraced
+    new = [e["value"] for e in tr.events
+           if e["ev"] == "counter" and e["name"] == "sync_new_edges"]
+    assert len(new) == drv.rounds and min(new) >= 0
+    assert sum(new) == drv.m
+
+
 def test_driver_single_mode_matches_partition(graph12):
     drv = PartitionDriver(graph12, CFG, mode="single")
     res = drv.run()

@@ -27,7 +27,7 @@ from repro.core.partitioner import NEConfig, alpha_limit
 from repro.dist import compat
 from repro.dist.partitioner_sm import (AXIS, EXCHANGE, STATE_SPECS, SpmdState,
                                        round_sync_payload_bytes,
-                                       spmd_round_step)
+                                       spmd_round_step, sync_chunk)
 from repro.kernels.ne_round import ne_round as ne_pl
 from repro.kernels.ne_round import ops as ne_ops
 
@@ -91,6 +91,28 @@ def _partition_axis_scatters_gathers(text, p_num, chunk):
     gathers = [op for op in re.findall(r"= \S+ gather\(%([^,\s]+)", text)
                if shapes.get(op) == f"s32[{chunk},{p_num}]"]
     return scatters, gathers
+
+
+def _state_scatters(text, n, p_num):
+    """``(target shape, updates, op_name)`` of every scatter into the
+    (N·P) replica map or an (N,) int32 vector such as D_rest, in compiled
+    HLO text; op_name is '' where the instruction has none."""
+    shapes = dict(re.findall(r"%(\S+) = (\w+\[[\d,]*\])", text))
+    for line in text.splitlines():
+        m = re.search(r"= (\w+)\[([\d,]*)\]\S* scatter\(%[^,\s]+, "
+                      r"%[^,\s]+, %([^,\s)]+)\)", line)
+        if not m:
+            continue
+        dtype, dims = m.group(1), [int(x) for x in m.group(2).split(",")]
+        size = 1
+        for x in dims:
+            size *= x
+        if (dtype, size) not in (("pred", n * p_num), ("s32", n)):
+            continue
+        updates = re.fullmatch(r"\w+\[(\d+)[\d,]*\]", shapes[m.group(3)])
+        name = re.search(r'op_name="([^"]*)"', line)
+        yield (f"{dtype}{dims}", int(updates.group(1)),
+               name.group(1) if name else "")
 
 
 def _collectives(text):
@@ -207,6 +229,21 @@ def test_spmd_round_step_compiles(compiled_round, num_dev, use_pallas):
         used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                 + mem.temp_size_in_bytes)
         assert used < 16 * 2**30
+
+
+@pytest.mark.parametrize("num_dev,use_pallas", ROUNDS, ids=ROUND_IDS)
+def test_sync_scatters_take_one_chunk(compiled_round, num_dev, use_pallas):
+    """No scatter into the replica map or an (N,) vector spans the shard
+    length C; the sync's (two replica-map sets and two D_rest adds in each
+    of the round's two syncs) take ``sync_chunk(C)`` updates each."""
+    compiled, _, _ = compiled_round(num_dev, use_pallas)
+    c = CAP_ROUND // num_dev
+    found = list(_state_scatters(compiled.as_text(), N_ROUND, P_NUM))
+    assert all(updates < c for _, updates, _ in found), found
+    sync = [(target, updates) for target, updates, name in found
+            if "ne_select" not in name.split("/")]
+    assert len(sync) == 8, found
+    assert {updates for _, updates in sync} == {sync_chunk(c)}, found
 
 
 @pytest.mark.parametrize("num_dev,use_pallas", ROUNDS, ids=ROUND_IDS)
