@@ -53,21 +53,23 @@ def _ne_rows(scale: int, repeats: int):
     import jax.numpy as jnp
 
     from repro.core.graph import as_graph
-    from repro.core.partitioner import (I32_INF, alpha_limit,
-                                        ne_init_state, ne_round_step,
-                                        one_hop, vertex_claims)
+    from repro.core.partitioner import (I32_INF, alpha_limit, one_hop,
+                                        vertex_claims)
     from repro.dist import compat
     from repro.kernels.ne_round import ops as ne_ops
+    from repro.runtime import PartitionDriver
 
     g = as_graph(rmat(scale, 8, seed=13))
     n, m, p_num = g.num_vertices, g.num_edges, NE_PARTS
     cfg = NEConfig(num_partitions=p_num, seed=0, use_pallas=False).clamped(n)
     limit = alpha_limit(cfg.alpha, m, p_num)
     # a mid-run state (3 XLA rounds in) so claim keys / boundaries are
-    # realistically dense, not the degenerate round-0 shapes
-    state = ne_init_state(g, cfg)
+    # realistically dense, not the degenerate round-0 shapes; one device's
+    # shard holds every edge in edge order
+    drv = PartitionDriver(g, cfg, num_devices=1)
     for _ in range(3):
-        state = ne_round_step(g, cfg, limit, state)
+        drv.step()
+    state = drv.state._replace(edge_part=drv.state.edge_part[0])
     _, sub = jax.random.split(state.key)
     vclaim = vertex_claims(cfg, limit, state.vparts, state.degree_rest,
                            state.edges_per_part, sub)

@@ -52,7 +52,7 @@ assert (res.edge_part >= 0).all()
 """
 
 _NE_BODY = """
-from repro.core.partitioner import NEConfig, partition
+from repro.core import NEConfig, partition
 res = partition(ef, NEConfig(num_partitions={p}, seed=0))
 """
 

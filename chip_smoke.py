@@ -182,7 +182,7 @@ def run_one_chip(args, out: Path, meter, child) -> None:
     import jax
     import numpy as np
 
-    from repro.core.partitioner import NEConfig, partition
+    from repro.core import NEConfig, partition
     from repro.graphs.rmat import rmat
     from repro.runtime import load_artifact
 
@@ -246,7 +246,7 @@ def cpu_reference(args) -> None:
     import numpy as np
 
     from repro import compile_cache
-    from repro.core.partitioner import NEConfig, partition
+    from repro.core import NEConfig, partition
     from repro.graphs.rmat import rmat
     from repro.io.spill import spill_canonical_rmat
     from repro.runtime import PartitionDriver

@@ -24,7 +24,7 @@ _EXPORTS = {
     "leftover_plan": "repro.core.epilogue",
     "leftover_targets": "repro.core.epilogue",
     "stitch_slices": "repro.core.epilogue",
-    "partition": "repro.core.partitioner",
+    "partition": "repro.dist.partitioner_sm",
     "PartitionStats": "repro.core.metrics",
     "evaluate": "repro.core.metrics",
     "stats_from_counts": "repro.core.metrics",

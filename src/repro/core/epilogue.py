@@ -35,10 +35,9 @@ import numpy as np
 def alpha_limit(alpha: float, m: int, num_partitions: int) -> int:
     """α-capacity limit ``⌊α·|E|/|P|⌋`` (paper Alg. 1).
 
-    The single shared definition for every enforcement site — the cleanup
-    pass and SPMD/single-controller parity depend on the expression staying
-    bit-identical between ``_partition_jit``, ``partition`` and
-    ``dist.partitioner_sm``.
+    The single shared definition for every enforcement site — the round
+    (``dist.partitioner_sm``), the cleanup pass and the hybrid's global
+    capacity all depend on the expression staying bit-identical.
     """
     return int(alpha * m / num_partitions)
 

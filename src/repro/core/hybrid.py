@@ -20,12 +20,12 @@ explicit memory budget:
    baseline (one streamed pass over the store — the full CSR is never
    built).
 3. **expansion** — the NE fixed point runs over the low subgraph only,
-   through the *exact* round function of the primary partitioner
-   (``core.partitioner._round`` / ``ne_round_step``), with the round
-   state pre-seeded with the tail phase's ``|E_p|`` counts and replica
-   marks: expansion balances around the load the hash phase already
-   placed and can grow regions from (and two-hop into) the partitions
-   where a vertex's tail edges already live.
+   through the one NE round (``dist.partitioner_sm._spmd_round``) on a
+   one-device mesh, with the round state pre-seeded with the tail
+   phase's ``|E_p|`` counts and replica marks: expansion balances around
+   the load the hash phase already placed and can grow regions from (and
+   two-hop into) the partitions where a vertex's tail edges already
+   live.
 4. **stitch** — both halves meet in the shared finalize epilogue
    (``core.epilogue.cleanup_leftovers`` water-fills the ``max_rounds``
    leftovers under the *global* α-capacity), so
@@ -40,18 +40,17 @@ generalization, not a fork, of the primary partitioner.
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
 from typing import NamedTuple
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core.epilogue import alpha_limit, cleanup_leftovers
 from repro.core.graph import Graph, from_edges
 from repro.core.metrics import stats_from_counts
-from repro.core.partitioner import (NEConfig, NEState, PartitionResult,
-                                    _round, ne_init_state)
+from repro.core.partitioner import NEConfig, PartitionResult
+from repro.dist.partitioner_sm import (SpmdState, make_edge_mesh,
+                                       place_state, run_rounds,
+                                       spmd_init_state)
 from repro.io.csr import grid_assign_host
 from repro.io.edgefile import EdgeFile
 from repro.io.stream import degree_indptr, require_canonical
@@ -217,44 +216,50 @@ def hybrid_split(source, cfg: HybridConfig) -> HybridSplit:
                        int(theta), int(n), int(edge_part0.shape[0]))
 
 
-def hybrid_init_state(split: HybridSplit, necfg: NEConfig) -> NEState:
-    """NE round state over the low subgraph, pre-seeded with the tail
-    phase's per-partition edge counts and replica marks — expansion
-    balances around (and grows from) what the hash already placed.  With
-    an empty tail this is exactly ``ne_init_state``."""
-    st = ne_init_state(split.low, necfg)
-    return st._replace(
-        vparts=jnp.asarray(split.tail_vparts),
-        edges_per_part=jnp.asarray(split.tail_counts.astype(np.int32)))
+def low_shard(split: HybridSplit) -> tuple[np.ndarray, np.ndarray]:
+    """The low subgraph as one device's edge shard: (1, M_low, 2) edges in
+    low-edge order and their (1, M_low) mask — what ``shard_edges`` makes
+    of them for one device."""
+    edges = np.asarray(split.low.edges, np.int32)
+    return edges[None], np.ones((1, edges.shape[0]), bool)
 
 
-@partial(jax.jit, static_argnames=("cfg", "limit"))
-def _hybrid_jit(g: Graph, cfg: NEConfig, limit: int, init: NEState):
-    """Fire-and-forget expansion fixed point — the same traced round
-    function driven one-jit-call-per-round by ``PartitionDriver``
-    (mode="hybrid"), which is what makes pause/resume bit-identical."""
+def hybrid_init_state(split: HybridSplit, necfg: NEConfig,
+                      mesh) -> SpmdState:
+    """NE round state over the low subgraph on a one-device ``mesh``,
+    pre-seeded with the tail phase's per-partition edge counts and replica
+    marks — expansion balances around (and grows from) what the hash
+    already placed.  With an empty tail this is exactly
+    ``spmd_init_state`` of :func:`low_shard`."""
+    shards, masks = low_shard(split)
+    st = spmd_init_state(shards, masks, split.num_vertices, necfg, mesh)
+    vparts = split.tail_vparts
+    if necfg.use_pallas:    # the round's packed representation
+        vparts = ne_ops.pack_bits_np(vparts)
+    return place_state(mesh, st._replace(
+        vparts=vparts,
+        edges_per_part=split.tail_counts.astype(np.int32)))
 
-    def cond(s: NEState):
-        return (s.edge_part < 0).any() & (s.rounds < cfg.max_rounds)
 
-    return jax.lax.while_loop(cond, partial(_round, g, cfg, limit), init)
-
-
-def hybrid_finalize(state: NEState, split: HybridSplit,
+def hybrid_finalize(state: SpmdState, split: HybridSplit,
                     cfg: HybridConfig) -> PartitionResult:
     """Stitch the two phases through the shared epilogue.
 
-    Low-slot assignments scatter to their global edge ids over the tail
-    grid assignments; the ``max_rounds`` leftovers (always low edges —
-    the tail is fully assigned by construction) water-fill under the
-    *global* α-capacity via the exact ``cleanup_leftovers`` every other
-    partitioning path uses.  Counts/replicas already carry both phases
-    (the seeded state), so the stats combine is the standard one.
+    One device's shard holds the low edges in their order, so its slots
+    are the low-edge assignments; they scatter to their global edge ids
+    over the tail grid assignments.  The ``max_rounds`` leftovers (always
+    low edges — the tail is fully assigned by construction) water-fill
+    under the *global* α-capacity via the exact ``cleanup_leftovers``
+    every other partitioning path uses.  Counts/replicas already carry
+    both phases (the seeded state), so the stats combine is the standard
+    one.
     """
     p = cfg.num_partitions
     limit = alpha_limit(cfg.alpha, split.num_edges, p)
-    ep_low = np.array(state.edge_part)
+    ep_low = np.array(state.edge_part).reshape(-1)
     vparts = np.array(state.vparts)
+    if cfg.use_pallas:  # result surface is always (N, P) bool
+        vparts = ne_ops.unpack_bits_np(vparts, p)
     counts = np.array(state.edges_per_part)
     leftover = cleanup_leftovers(ep_low, vparts, counts,
                                  np.asarray(split.low.edges), p, limit)
@@ -276,16 +281,15 @@ def partition_hybrid(source, cfg: HybridConfig) -> PartitionResult:
     split = hybrid_split(source, cfg)
     cfg = cfg.clamped(split.num_vertices)
     necfg = cfg.ne_config()
-    limit = alpha_limit(cfg.alpha, split.num_edges, cfg.num_partitions)
-    init = hybrid_init_state(split, necfg)
+    mesh = make_edge_mesh(1)
+    state = hybrid_init_state(split, necfg, mesh)
     if split.low.num_edges:
-        state = jax.block_until_ready(
-            _hybrid_jit(split.low, necfg, limit, init))
-    else:
-        state = init
+        limit = alpha_limit(cfg.alpha, split.num_edges, cfg.num_partitions)
+        state = run_rounds(necfg, limit, split.num_vertices, mesh,
+                           *low_shard(split), state)
     return hybrid_finalize(state, split, cfg)
 
 
 __all__ = ["HybridConfig", "HybridSplit", "degree_threshold",
            "hybrid_finalize", "hybrid_init_state", "hybrid_split",
-           "partition_hybrid"]
+           "low_shard", "partition_hybrid"]

@@ -1,36 +1,31 @@
-"""Distributed Neighbor Expansion (Distributed NE) — vectorized JAX core.
+"""Distributed Neighbor Expansion (Distributed NE) — what the round shares.
 
-Implements the paper's parallel expansion (§3), distributed edge allocation
-(§4) and multi-expansion (§5) as a bounded-shape, jit-compiled fixed-point
-iteration.  One ``jax.lax.while_loop`` step == one paper round:
+The paper's parallel expansion (§3), distributed edge allocation (§4) and
+multi-expansion (§5) run as one round program,
+``repro.dist.partitioner_sm._spmd_round``, over 2D-hash edge shards; one
+device is a one-device mesh.  ``partition``, ``partition_spmd``, the
+hybrid's expansion and :class:`repro.runtime.PartitionDriver` all step it.
+This module holds the pieces that round and the host epilogue share:
 
-  1. every active partition selects its ``k = clamp(λ·|B_p|, 1, K)``
-     minimum-``D_rest`` boundary vertices (priority queue → masked top_k);
-     empty boundaries re-seed from a random vertex with unallocated edges,
-  2. one-hop allocation with deterministic vertex-grain conflict resolution
-     (min ``(edges_per_part, partition_id)`` key — the paper's CAS made
-     reproducible; see docs/DESIGN-dist.md, ``partitioner_sm`` step 1),
-  3. replica-set updates (the paper's ``SyncVertexAllocations`` — a no-op
-     here because the single-controller state is already global; the
-     shard_map version in ``repro.dist.partitioner_sm`` does the OR
-     all-reduce),
-  4. two-hop "free edge" allocation under Condition (5) with
-     ``argmin NumEdges`` tie-breaking (paper Alg. 3).
+  * :class:`NEConfig` and :class:`PartitionResult`;
+  * the round's phase 1, :func:`vertex_claims`: every active partition
+    selects its ``k = clamp(λ·|B_p|, 1, K)`` minimum-``D_rest`` boundary
+    vertices (priority queue → masked top_k, :func:`select_chunk`); empty
+    boundaries re-seed from a random vertex with unallocated edges; claims
+    resolve at vertex grain by the min ``(edges_per_part, partition_id)``
+    key (:func:`priority_enc` — the paper's CAS made reproducible; see
+    docs/DESIGN-dist.md);
+  * its phase 2, :func:`one_hop` allocation over an edge list;
+  * :func:`finalize_result`, the whole-array epilogue.
 
 Boundary sets are *derived*, not stored: ``v ∈ B_p  ⇔  p ∈ parts(v) ∧
 D_rest(v) > 0`` — this is exactly the paper's definition of B(X) and avoids
-an (N, P) frontier structure.
-
-Each phase of a round runs under a ``jax.named_scope`` — ``ne_select``,
-``ne_one_hop``, ``ne_sync``, ``ne_two_hop`` — shared by the single, hybrid
-and SPMD rounds, so a profiler trace attributes every op to its phase
-(the scope is the op's HLO ``op_name``; results are unchanged).
+an (N, P) frontier structure.  The phases and their ``jax.named_scope``
+names are listed in ``repro.dist.partitioner_sm``.
 """
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
-from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -38,8 +33,7 @@ import numpy as np
 
 from repro.core.epilogue import (alpha_limit, cleanup_leftovers,  # noqa: F401 — re-exported epilogue surface
                                  leftover_plan, leftover_targets)
-from repro.core.graph import (Graph, as_graph, exclusive_rank,
-                              target_histogram)
+from repro.core.graph import target_histogram
 from repro.core.metrics import stats_from_counts
 from repro.kernels.ne_round import ops as ne_ops
 
@@ -61,10 +55,10 @@ class NEConfig:
     two_hop: bool = True        # Condition (5) allocation on/off (ablation)
     seed: int = 0
     # Bit-packed replica sets (the Pallas ne_round packing kernels) in the
-    # SPMD partitioner; the single-controller round ignores it.  None
-    # resolves from the REPRO_NE_KERNELS env var at construction, so a
-    # resolved config is self-contained and its snapshot fingerprint
-    # stable.  Both values produce bit-identical results (asserted in tests).
+    # round.  None resolves from the REPRO_NE_KERNELS env var at
+    # construction, so a resolved config is self-contained and its
+    # snapshot fingerprint stable.  Both values produce bit-identical
+    # results (asserted in tests).
     use_pallas: bool = None
 
     def __post_init__(self):
@@ -76,16 +70,6 @@ class NEConfig:
 
     def clamped(self, num_vertices: int) -> "NEConfig":
         return dataclasses.replace(self, k_sel=min(self.k_sel, num_vertices))
-
-
-class NEState(NamedTuple):
-    edge_part: Array        # (M,)   int32, -1 = unallocated
-    vparts: Array           # (N, P) bool replica sets  V(E_p)
-    degree_rest: Array      # (N,)   int32  D_rest
-    edges_per_part: Array   # (P,)   int32  |E_p|
-    key: Array              # PRNG key
-    rounds: Array           # ()     int32
-    new_last_round: Array   # ()     int32  edges allocated in last round
 
 
 class PartitionResult:
@@ -235,132 +219,6 @@ def one_hop(vclaim: Array, u: Array, v: Array, edge_part: Array,
     return part, target_histogram(part, num_partitions)
 
 
-def _round(g: Graph, cfg: NEConfig, limit: int, state: NEState) -> NEState:
-    n = g.num_vertices
-    m = g.num_edges
-    p_num = cfg.num_partitions
-    with jax.named_scope("ne_select"):
-        key, sub = jax.random.split(state.key)
-
-    vclaim_key = vertex_claims(cfg, limit, state.vparts, state.degree_rest,
-                               state.edges_per_part, sub)
-
-    # --- one-hop allocation ------------------------------------------------
-    u, v = g.edges[:, 0], g.edges[:, 1]
-    part1, counts1 = one_hop(vclaim_key, u, v, state.edge_part, p_num)
-    with jax.named_scope("ne_one_hop"):
-        new1 = part1 >= 0
-        edge_part = jnp.where(new1, part1, state.edge_part)
-
-    # --- replica-set update (the SPMD round's SyncVertexAllocations) -------
-    with jax.named_scope("ne_sync"):
-        add_row = jnp.where(new1, part1, 0)
-        vparts = state.vparts
-        drop_u = jnp.where(new1, u, n)
-        drop_v = jnp.where(new1, v, n)
-        vparts = vparts.at[drop_u, add_row].set(True, mode="drop")
-        vparts = vparts.at[drop_v, add_row].set(True, mode="drop")
-        dec = (jnp.zeros((n,), jnp.int32)
-               .at[drop_u].add(new1.astype(jnp.int32), mode="drop")
-               .at[drop_v].add(new1.astype(jnp.int32), mode="drop"))
-        degree_rest = state.degree_rest - dec
-        edges_per_part = state.edges_per_part + counts1
-
-    # --- 3. two-hop "free edge" allocation, Condition (5) ------------------
-    if cfg.two_hop:
-        with jax.named_scope("ne_two_hop"):
-            ce = min(cfg.edge_chunk, m)
-            n_ec = (m + ce - 1) // ce
-            m_pad = n_ec * ce
-            pad = m_pad - m
-            u_p = jnp.pad(u, (0, pad))
-            v_p = jnp.pad(v, (0, pad))
-            un_p = jnp.pad(edge_part < 0, (0, pad))  # pads → False
-            # tie-break by |E_p| (Alg. 3 line 16)
-            enc_vec = priority_enc(edges_per_part,
-                                   jnp.arange(p_num, dtype=jnp.int32), p_num)
-            # free edges only go to partitions still under the α-capacity,
-            # and a partition may absorb at most its remaining capacity this
-            # round — otherwise one round's free-edge batch around a hub
-            # blows up |E_p| (the paper's per-vertex expansion granularity
-            # implies the same cap).
-            enc_vec = jnp.where(edges_per_part <= limit, enc_vec, I32_INF)
-            quota0 = jnp.maximum(limit + 1 - edges_per_part, 0)
-
-            def two_hop(quota, args):
-                uu, vv, unal = args
-                inter = vparts[uu] & vparts[vv]                  # (ce, P)
-                k2 = jnp.where(inter & unal[:, None], enc_vec[None, :],
-                               I32_INF)
-                best = k2.min(axis=1)
-                cand = jnp.where(best < I32_INF, best % p_num, -1)
-                rank = exclusive_rank(cand, p_num)
-                keep = (cand >= 0) & (rank < quota[jnp.maximum(cand, 0)])
-                out = jnp.where(keep, cand, -1)
-                return quota - target_histogram(out, p_num), out
-
-            _, part2 = jax.lax.scan(
-                two_hop, quota0,
-                (u_p.reshape(n_ec, ce), v_p.reshape(n_ec, ce),
-                 un_p.reshape(n_ec, ce)),
-            )
-            part2 = part2.reshape(m_pad)[:m]
-            new2 = part2 >= 0
-            edge_part = jnp.where(new2, part2, edge_part)
-            edges_per_part = edges_per_part + target_histogram(part2, p_num)
-            dec2 = (jnp.zeros((n,), jnp.int32)
-                    .at[jnp.where(new2, u, n)].add(new2.astype(jnp.int32),
-                                                   mode="drop")
-                    .at[jnp.where(new2, v, n)].add(new2.astype(jnp.int32),
-                                                   mode="drop"))
-            degree_rest = degree_rest - dec2
-            new_total = new1.sum() + new2.sum()
-    else:
-        new_total = new1.sum()
-
-    return NEState(edge_part, vparts, degree_rest, edges_per_part, key,
-                   state.rounds + 1, new_total.astype(jnp.int32))
-
-
-def _init_state(g: Graph, cfg: NEConfig) -> NEState:
-    n, m, p = g.num_vertices, g.num_edges, cfg.num_partitions
-    return NEState(
-        edge_part=jnp.full((m,), -1, jnp.int32),
-        vparts=jnp.zeros((n, p), bool),
-        degree_rest=g.degree.astype(jnp.int32),
-        edges_per_part=jnp.zeros((p,), jnp.int32),
-        key=jax.random.PRNGKey(cfg.seed),
-        rounds=jnp.zeros((), jnp.int32),
-        new_last_round=jnp.ones((), jnp.int32),
-    )
-
-
-# Round-stepping surface for the checkpointable runtime
-# (``repro.runtime.driver``): one jit call == one paper round, on exactly
-# the traced round function the whole-run while_loop uses — which is what
-# makes pause/snapshot/resume bit-identical to an uninterrupted run.
-ne_init_state = jax.jit(_init_state, static_argnames=("cfg",))
-ne_round_step = jax.jit(_round, static_argnames=("cfg", "limit"))
-
-
-def ne_done(state: NEState, cfg: NEConfig) -> bool:
-    """Host-side mirror of the whole-run while_loop condition."""
-    return bool((np.asarray(state.edge_part) >= 0).all()
-                or int(state.rounds) >= cfg.max_rounds)
-
-
-@partial(jax.jit, static_argnames=("cfg",))
-def _partition_jit(g: Graph, cfg: NEConfig) -> NEState:
-    limit = alpha_limit(cfg.alpha, g.num_edges, cfg.num_partitions)
-    init = _init_state(g, cfg)
-
-    def cond(s: NEState):
-        return ((s.edge_part < 0).any()
-                & (s.rounds < cfg.max_rounds))
-
-    return jax.lax.while_loop(cond, partial(_round, g, cfg, limit), init)
-
-
 def finalize_result(edge_part, vparts, counts, edges: np.ndarray,
                     cfg: NEConfig, rounds: int) -> PartitionResult:
     """Host-side epilogue shared by every single-controller entry point:
@@ -381,18 +239,3 @@ def finalize_result(edge_part, vparts, counts, edges: np.ndarray,
     stats = stats_from_counts(vparts.sum(axis=0), counts, vparts.shape[0])
     return PartitionResult(edge_part, vparts, counts, int(rounds), leftover,
                            stats)
-
-
-def partition(g: Graph, cfg: NEConfig) -> PartitionResult:
-    """Run Distributed NE.  Returns host-side result with cleanup applied.
-
-    ``g`` may be a Graph or any store handle ``core.graph.as_graph``
-    accepts (EdgeFile, PackedCSR) — this path needs the full CSR, so store
-    inputs are materialized via the streaming builder first.
-    """
-    g = as_graph(g)
-    cfg = cfg.clamped(g.num_vertices)
-    state = jax.block_until_ready(_partition_jit(g, cfg))
-    return finalize_result(state.edge_part, state.vparts,
-                           state.edges_per_part, np.asarray(g.edges), cfg,
-                           int(state.rounds))

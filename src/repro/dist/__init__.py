@@ -13,9 +13,10 @@ reproduction into the distributed system the paper describes (§4):
   ``sharding``        — logical-axis → ``PartitionSpec`` rule tables
                         (``Rules`` / ``lm_rules``) consumed by the LM
                         transformer and the launch step builders.
-  ``partitioner_sm``  — ``partition_spmd``: Distributed NE as a
-                        ``shard_map`` program over 2D-hash edge shards
-                        with per-round ``SyncVertexAllocations``.
+  ``partitioner_sm``  — the one NE round: ``partition_spmd`` runs it as
+                        a ``shard_map`` program over 2D-hash edge shards
+                        with per-round ``SyncVertexAllocations``;
+                        ``partition`` is that program on one device.
   ``redistribute``    — all-to-all edge shuffle so partition *p*'s edges
                         land on device *p* (feeds the GAS engine).
 

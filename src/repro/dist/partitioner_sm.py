@@ -11,8 +11,8 @@ One while_loop step == one paper round, per device:
      because selection reads only V(E_p), D_rest and |E_p|, all of which
      are re-synchronized at the end of every round;
   2. **one-hop allocation** over local edges: edge (u, v) joins the best
-     claiming partition ``min(claim[u], claim[v])`` — the single-controller
-     ``core.partitioner.one_hop``, restricted to the local shard;
+     claiming partition ``min(claim[u], claim[v])``
+     (``core.partitioner.one_hop`` over the local shard);
   3. **SyncVertexAllocations** — the paper's §4 merge, realized as an OR
      all-reduce of the replica-set deltas plus ``psum`` of the |E_p| and
      D_rest deltas, each device's deltas scattered from its batch's new
@@ -28,11 +28,15 @@ under a ``jax.named_scope`` — ``ne_select``, ``ne_one_hop``, ``ne_sync``
 (both ``_apply_alloc`` calls) and ``ne_two_hop`` — which names every op's
 phase in a profiler trace; each collective call alone sits in a nested
 ``ne_exchange`` scope, so the cross-device exchange reads apart from the
-local work of its phase.  Because steps 1–3
-are bit-identical to the single-controller fixed point and only the quota
-*ordering* in step 4 differs, the resulting quality (replication factor)
-matches ``core.partitioner.partition`` closely — asserted by
-tests/test_spmd.py.
+local work of its phase.
+
+This is the one round body: :func:`partition` is :func:`partition_spmd` on
+a one-device mesh, the hybrid's expansion phase runs it over the low
+subgraph (``repro.core.hybrid``), and ``repro.runtime.PartitionDriver``
+steps it one round at a time (:func:`spmd_round_step`).  On more devices
+only the two-hop quota *ordering* in step 4 depends on the device count,
+so the quality (replication factor) stays close to the one-device result
+— asserted by tests/test_spmd.py.
 """
 from __future__ import annotations
 
@@ -47,8 +51,8 @@ from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.core.epilogue import stitch_slices
-from repro.core.graph import (Graph, exclusive_rank, shard_edges,
-                              target_histogram)
+from repro.core.graph import (Graph, as_graph, exclusive_rank,
+                              shard_edges, target_histogram)
 from repro.core.partitioner import (I32_INF, NEConfig, PartitionResult,
                                     alpha_limit, finalize_result,
                                     one_hop, priority_enc, vertex_claims)
@@ -197,7 +201,7 @@ def _spmd_round(cfg: NEConfig, limit: int, n: int, num_dev: int,
             quota = jnp.maximum(limit + 1 - edges_per_part, 0)
             unal = mask_loc & (edge_part < 0)
             # candidates + local ranks, scanned in edge_chunk-sized chunks so
-            # peak memory is edge_chunk × P, like the single-controller path
+            # peak memory is edge_chunk × P
             c_len = u_loc.shape[0]
             ce = min(cfg.edge_chunk, c_len)
             n_ec = (c_len + ce - 1) // ce
@@ -256,14 +260,31 @@ def _spmd_round(cfg: NEConfig, limit: int, n: int, num_dev: int,
 # round-stepping surface (repro.runtime.driver)
 # ---------------------------------------------------------------------------
 
-def empty_vparts(n: int, cfg: NEConfig, xp=jnp) -> Array:
-    """All-empty replica sets in the representation the round uses:
-    bit-packed uint32 words under cfg.use_pallas, (N, P) bool otherwise.
-    ``xp=np`` builds them on the host."""
+def empty_vparts(n: int, cfg: NEConfig) -> np.ndarray:
+    """All-empty replica sets, on the host, in the representation the
+    round uses: bit-packed uint32 words under cfg.use_pallas, (N, P) bool
+    otherwise."""
     if cfg.use_pallas:
         w = ne_ops.replica_words(cfg.num_partitions)
-        return xp.zeros((n, w), xp.uint32)
-    return xp.zeros((n, cfg.num_partitions), bool)
+        return np.zeros((n, w), np.uint32)
+    return np.zeros((n, cfg.num_partitions), bool)
+
+
+def make_edge_mesh(num_devices: int | None = None) -> jax.sharding.Mesh:
+    """1-D edge-shard mesh for the round, single- or multi-process.
+
+    Uses the *global* device list, which ``jax.devices()`` orders by
+    process index then local device id — so under ``jax.distributed`` every
+    process builds the identical mesh and process ``h`` owns the contiguous
+    device range ``[h·L, (h+1)·L)``.  That contiguity is what lets the
+    runtime's host block ranges, per-host shard files and snapshot shard
+    indices all share one numbering.
+    """
+    devs = jax.devices()
+    d = num_devices or len(devs)
+    if d > len(devs):
+        raise ValueError(f"requested {d} devices, only {len(devs)} exist")
+    return compat.make_mesh((d,), (AXIS,), devices=devs[:d])
 
 
 def shard_over(mesh, x) -> Array:
@@ -281,10 +302,10 @@ def place_state(mesh, state: SpmdState) -> SpmdState:
 
 def spmd_init_state(shards: np.ndarray, masks: np.ndarray, n: int,
                     cfg: NEConfig, mesh) -> SpmdState:
-    """Host-built initial round state, bit-identical to the in-jit init of
-    :func:`_partition_spmd_jit` (global D_rest via one bincount pass instead
-    of the in-shard_map psum), placed on ``mesh``.  ``edge_part`` keeps its
-    (D, C) shard layout, sharded over the device axis.
+    """The initial round state, built on the host (global D_rest in one
+    bincount pass) and placed on ``mesh``: the one state every run starts
+    from.  ``edge_part`` keeps its (D, C) shard layout, sharded over the
+    device axis.
     """
     p_num = cfg.num_partitions
     flat = shards.reshape(-1, 2)[masks.reshape(-1)]
@@ -292,7 +313,7 @@ def spmd_init_state(shards: np.ndarray, masks: np.ndarray, n: int,
               + np.bincount(flat[:, 1], minlength=n))
     return place_state(mesh, SpmdState(
         edge_part=np.full(masks.shape, -1, np.int32),
-        vparts=empty_vparts(n, cfg, np),
+        vparts=empty_vparts(n, cfg),
         degree_rest=degree.astype(np.int32),
         edges_per_part=np.zeros((p_num,), np.int32),
         key=jax.random.PRNGKey(cfg.seed),
@@ -340,9 +361,9 @@ def _quality_reduce(vparts: Array, degree_rest: Array, p_num: int):
     """The (P,)-and-scalar reduction behind the live quality gauges.
 
     One fused pass over the replicated replica map: per-partition replica
-    counts |V(E_p)|, the boundary-set size (vertices already replicated
+    counts |V(E_p)| and the boundary-set size (vertices already replicated
     somewhere but still carrying unallocated degree — the frontier the
-    next round's two-hop allocation expands from), and ΣD_rest.  Packed
+    next round's two-hop allocation expands from).  Packed
     (uint32-word) replica sets unpack inside the jit, exactly as the
     round itself does for selection, so the gauge is cheap relative to a
     round on either representation.  No collectives: under multihost the
@@ -354,29 +375,25 @@ def _quality_reduce(vparts: Array, degree_rest: Array, p_num: int):
     vrep = jnp.sum(vparts, axis=0, dtype=jnp.int32)              # (P,)
     boundary = jnp.sum(vparts.any(axis=1) & (degree_rest > 0),
                        dtype=jnp.int32)
-    degree_sum = jnp.sum(degree_rest, dtype=jnp.int32)
-    return vrep, boundary, degree_sum
+    return vrep, boundary
 
 
-def round_quality(cfg: NEConfig, state, n: int) -> dict:
-    """Live quality gauges from a round state (SpmdState or NEState).
+def round_quality(cfg: NEConfig, state: SpmdState, n: int) -> dict:
+    """Live quality gauges from a round state.
 
     Same math as :func:`repro.core.metrics.stats_from_counts` over the
     current replica/edge counts — so at the fixed point (no leftover
     edges) the live values equal the finalized artifact's metrics, which
-    the multihost integration checks assert to 1e-6.  ``degree_sum``
-    rides along because ΣD_rest/2 is the single-controller
-    edges-remaining gauge (NEState has no ``remaining`` field).
+    the multihost integration checks assert to 1e-6.
     """
-    vrep_d, boundary, degree_sum = _quality_reduce(
+    vrep_d, boundary = _quality_reduce(
         state.vparts, state.degree_rest, cfg.num_partitions)
     vrep = np.asarray(vrep_d, np.int64)
     counts = np.asarray(state.edges_per_part, np.int64)
     rf = float(vrep.sum()) / float(max(n, 1))
     eb = float(counts.max()) / max(float(counts.mean()), 1e-9)
     vb = float(vrep.max()) / max(float(vrep.mean()), 1e-9)
-    return {"rf": rf, "eb": eb, "vb": vb, "boundary": int(boundary),
-            "degree_sum": int(degree_sum)}
+    return {"rf": rf, "eb": eb, "vb": vb, "boundary": int(boundary)}
 
 
 def round_sync_payload_bytes(cfg: NEConfig, n: int, num_dev: int) -> int:
@@ -429,43 +446,39 @@ def stitch_edge_part(ep_sh: np.ndarray, dev: np.ndarray, m: int,
 @partial(jax.jit, static_argnames=("cfg", "limit", "n", "mesh"))
 def _partition_spmd_jit(cfg: NEConfig, limit: int, n: int, mesh,
                         u_sh: Array, v_sh: Array, mask_sh: Array,
-                        m_total: Array):
-    p_num = cfg.num_partitions
+                        init: SpmdState) -> SpmdState:
+    """Every round to the fixed point in one program: :func:`_spmd_round`
+    under a while_loop, from ``init`` (:func:`spmd_init_state`), in and out
+    in the layout of :data:`STATE_SPECS`."""
     num_dev = mesh.shape[AXIS]
 
-    def body(u_l, v_l, mask_l, m_tot):
-        u_l, v_l, mask_l = u_l[0], v_l[0], mask_l[0]
-        init = SpmdState(
-            edge_part=jnp.full(u_l.shape, -1, jnp.int32),
-            vparts=empty_vparts(n, cfg),
-            degree_rest=(jnp.zeros((n,), jnp.int32)
-                         .at[u_l].add(mask_l.astype(jnp.int32))
-                         .at[v_l].add(mask_l.astype(jnp.int32))),
-            edges_per_part=jnp.zeros((p_num,), jnp.int32),
-            key=jax.random.PRNGKey(cfg.seed),
-            rounds=jnp.zeros((), jnp.int32),
-            remaining=m_tot,
-        )
-        # D_rest must be global degree, not shard-local degree
-        init = init._replace(
-            degree_rest=jax.lax.psum(init.degree_rest, AXIS))
-
+    def body(u_l, v_l, mask_l, ep_l, *rest):
         def cond(s: SpmdState):
             return (s.remaining > 0) & (s.rounds < cfg.max_rounds)
 
         out = jax.lax.while_loop(
             cond,
-            partial(_spmd_round, cfg, limit, n, num_dev, u_l, v_l, mask_l),
-            init)
-        return (out.edge_part[None], out.vparts, out.edges_per_part,
-                out.rounds)
+            partial(_spmd_round, cfg, limit, n, num_dev, u_l[0], v_l[0],
+                    mask_l[0]),
+            SpmdState(ep_l[0], *rest))
+        return out._replace(edge_part=out.edge_part[None])
 
     return jax.shard_map(
         body, mesh=mesh,
-        in_specs=(P(AXIS, None), P(AXIS, None), P(AXIS, None), P()),
-        out_specs=(P(AXIS, None), P(), P(), P()),
+        in_specs=(P(AXIS, None),) * 3 + tuple(STATE_SPECS),
+        out_specs=STATE_SPECS,
         check_vma=False,
-    )(u_sh, v_sh, mask_sh, m_total)
+    )(u_sh, v_sh, mask_sh, *init)
+
+
+def run_rounds(cfg: NEConfig, limit: int, n: int, mesh, shards: np.ndarray,
+               masks: np.ndarray, init: SpmdState) -> SpmdState:
+    """Every round to the fixed point over the (D, C) edge ``shards`` and
+    ``masks``, placed on ``mesh``, from ``init``; returns once the device
+    is done."""
+    return jax.block_until_ready(_partition_spmd_jit(
+        cfg, limit, n, mesh, shard_over(mesh, shards[:, :, 0]),
+        shard_over(mesh, shards[:, :, 1]), shard_over(mesh, masks), init))
 
 
 def _shard_input(source, num_devices: int):
@@ -497,8 +510,7 @@ def partition_spmd(g: Graph, cfg: NEConfig,
 
     ``g`` may be an in-memory Graph or a canonical ``repro.io.EdgeFile``
     (partitioned straight from the store, no CSR materialization).
-    Returns a host-side :class:`PartitionResult` matching the
-    single-controller :func:`repro.core.partitioner.partition` API.
+    Returns a host-side :class:`PartitionResult`.
     """
     if compat.process_env()[1] > 1:
         raise RuntimeError(
@@ -517,16 +529,28 @@ def partition_spmd(g: Graph, cfg: NEConfig,
                                np.zeros((n, p_num), bool),
                                np.zeros((p_num,), np.int32), 0, 0)
 
-    mesh = compat.make_mesh((d,), (AXIS,))
+    mesh = make_edge_mesh(d)
     limit = alpha_limit(cfg.alpha, m, p_num)
-    ep_sh, vparts, counts, rounds = jax.block_until_ready(
-        _partition_spmd_jit(cfg, limit, n, mesh,
-                            shard_over(mesh, shards[:, :, 0]),
-                            shard_over(mesh, shards[:, :, 1]),
-                            shard_over(mesh, masks), jnp.int32(m)))
+    st = run_rounds(cfg, limit, n, mesh, shards, masks,
+                    spmd_init_state(shards, masks, n, cfg, mesh))
 
-    edge_part = stitch_edge_part(ep_sh, dev, m)
+    edge_part = stitch_edge_part(st.edge_part, dev, m)
+    vparts = np.asarray(st.vparts)
     if cfg.use_pallas:  # result surface is always (N, P) bool
-        vparts = ne_ops.unpack_bits_np(np.asarray(vparts), p_num)
-    return finalize_result(edge_part, vparts, counts, edges, cfg,
-                           int(rounds))
+        vparts = ne_ops.unpack_bits_np(vparts, p_num)
+    return finalize_result(edge_part, vparts, st.edges_per_part, edges, cfg,
+                           int(st.rounds))
+
+
+def partition(g, cfg: NEConfig) -> PartitionResult:
+    """Run Distributed NE on one device: :func:`partition_spmd` over a
+    one-device mesh.  Returns the host-side result with cleanup applied.
+
+    ``g`` is a Graph or a canonical ``repro.io.EdgeFile`` (partitioned
+    straight from the store); any other handle ``core.graph.as_graph``
+    accepts (a PackedCSR, a non-canonical EdgeFile) is materialized first.
+    """
+    if not (isinstance(g, Graph)
+            or isinstance(g, EdgeFile) and g.canonical):
+        g = as_graph(g)
+    return partition_spmd(g, cfg, num_devices=1)

@@ -23,20 +23,3 @@ def make_host_mesh(model: int = 1) -> jax.sharding.Mesh:
     assert n % model == 0
     return compat.make_mesh((n // model, model), ("data", "model"))
 
-
-def make_edge_mesh(num_devices: int | None = None,
-                   axis: str = "shard") -> jax.sharding.Mesh:
-    """1-D edge-shard mesh for the SPMD partitioner, single- or multi-process.
-
-    Uses the *global* device list, which ``jax.devices()`` orders by
-    process index then local device id — so under ``jax.distributed`` every
-    process builds the identical mesh and process ``h`` owns the contiguous
-    device range ``[h·L, (h+1)·L)``.  That contiguity is what lets the
-    runtime's host block ranges, per-host shard files and snapshot shard
-    indices all share one numbering.
-    """
-    devs = jax.devices()
-    d = num_devices or len(devs)
-    if d > len(devs):
-        raise ValueError(f"requested {d} devices, only {len(devs)} exist")
-    return compat.make_mesh((d,), (axis,), devices=devs[:d])

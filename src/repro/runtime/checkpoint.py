@@ -1,6 +1,6 @@
 """Fault-tolerant checkpointing: atomic, resumable, mesh-elastic.
 
-Checkpoints store *logical* (unsharded) arrays + a msgpack manifest, never
+Checkpoints store *logical* (unsharded) arrays + a JSON manifest, never
 device buffers — restore re-shards onto whatever mesh is current, so a job
 can come back on a different device count (elastic rescale) or after node
 failure.  Writes are tmp-file + atomic rename; a corrupt/partial final
@@ -16,7 +16,6 @@ from pathlib import Path
 
 import jax
 import jax.numpy as jnp
-import msgpack
 import numpy as np
 
 # canonical home is repro.io.atomicdir (jax-free); re-exported here because

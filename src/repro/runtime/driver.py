@@ -3,9 +3,10 @@
 ``partition`` / ``partition_spmd`` are fire-and-forget: one jit call runs
 every round inside a ``while_loop`` and nothing survives a crash.  The
 :class:`PartitionDriver` re-expresses the same computation as a host-driven
-state machine — one jit call per paper round, on *exactly the traced round
-function the whole-run jits use* (``core.partitioner._round`` /
-``dist.partitioner_sm._spmd_round``).  All round state is integer or
+state machine — one jit call per paper round (``spmd_round_step``), on
+*exactly the traced round function the whole-run jit uses*
+(``dist.partitioner_sm._spmd_round``, the one NE round; one device is a
+one-device mesh).  All round state is integer or
 counter-mode PRNG, so stepping is bit-identical to the uninterrupted
 while_loop, and therefore so is kill-at-round-k + resume-from-snapshot
 (asserted by tests/test_runtime.py and the 8-device SPMD checks).
@@ -45,24 +46,21 @@ from __future__ import annotations
 import os
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core.graph import Graph, as_graph, shard_edges
 from repro.core.metrics import stats_from_counts
-from repro.core.partitioner import (NEConfig, NEState, PartitionResult,
-                                    alpha_limit, finalize_result, ne_done,
-                                    ne_init_state, ne_round_step)
+from repro.core.partitioner import (NEConfig, PartitionResult, alpha_limit,
+                                    finalize_result)
 from repro.dist import compat
-from repro.dist.partitioner_sm import (AXIS, SpmdState, place_state,
-                                       round_quality,
+from repro.dist.partitioner_sm import (SpmdState, make_edge_mesh,
+                                       place_state, round_quality,
                                        round_sync_payload_bytes, shard_over,
                                        spmd_init_state, spmd_round_step,
                                        stitch_edge_part, sync_chunk)
 from repro.io.edgefile import EdgeFile
 from repro.kernels.ne_round import ops as ne_ops
 from repro.io.stream import require_canonical
-from repro.launch.mesh import make_edge_mesh
 from repro.obs import live
 from repro.obs import trace as obs
 from repro.runtime import cluster
@@ -75,13 +73,13 @@ class PartitionDriver:
     """Interruptible, resumable Distributed NE run.
 
     ``mode="spmd"`` (default) drives the shard_map partitioner over
-    ``num_devices``; ``mode="single"`` drives the single-controller
-    fixed point; ``mode="hybrid"`` drives the HEP-style hybrid
-    (``cfg`` must then be a :class:`repro.core.hybrid.HybridConfig`) —
-    the tail is grid-hashed at ingest, rounds step the *same*
-    ``ne_round_step`` over the low subgraph from the seeded state, and
-    finalize stitches through ``hybrid_finalize``; snapshots/resume
-    inherit round-for-round (the seeded state is just an NEState).  One
+    ``num_devices`` (one device is a one-device mesh); ``mode="hybrid"``
+    drives the HEP-style hybrid (``cfg`` must then be a
+    :class:`repro.core.hybrid.HybridConfig`) — the tail is grid-hashed at
+    ingest, rounds step the *same* ``spmd_round_step`` over the low
+    subgraph's one-device shard from the seeded state, and finalize
+    stitches through ``hybrid_finalize``; snapshots/resume inherit
+    round-for-round (the seeded state is just an SpmdState).  One
     :meth:`step` == one paper round; :meth:`run` loops to completion
     with periodic snapshots; :meth:`resume` rebuilds a driver from the
     latest (or a chosen) snapshot.
@@ -92,14 +90,14 @@ class PartitionDriver:
                  snapshot_every: int = 0, keep: int = 3,
                  num_hosts: int | None = None, ingest_processes: bool = False,
                  exchange_dir: str | os.PathLike | None = None):
-        if mode not in ("spmd", "single", "hybrid"):
+        if mode not in ("spmd", "hybrid"):
             raise ValueError(f"unknown mode {mode!r}")
         self.mode = mode
         self.source = source
         self.snapshot_every = int(snapshot_every)
         self._result: PartitionResult | None = None
         self._done: bool | None = None
-        # SPMD: ``remaining`` as last read from the device (None until the
+        # ``remaining`` as last read from the device (None until the
         # current state's read), the read before it, and their difference
         self._rem: int | None = None
         self._rem_last: int | None = None
@@ -112,16 +110,16 @@ class PartitionDriver:
         # round-k integration checks); never set in production runs
         self.snapshot_fault_hook = None
 
-        if mode in ("single", "hybrid") and self._nprocs > 1:
-            raise ValueError(f"mode={mode!r} is single-controller by "
-                             "definition — multi-process runs drive the "
-                             "SPMD partitioner (mode='spmd')")
+        if mode == "hybrid" and self._nprocs > 1:
+            raise ValueError("mode='hybrid' is single-controller — "
+                             "multi-process runs drive the SPMD "
+                             "partitioner (mode='spmd')")
         obs.watch_compiles()
         with obs.span("ingest", cat="runtime", mode=mode):
             if mode == "hybrid":
                 from repro.core.hybrid import (HybridConfig,
                                                hybrid_init_state,
-                                               hybrid_split)
+                                               hybrid_split, low_shard)
 
                 if not isinstance(cfg, HybridConfig):
                     raise TypeError("mode='hybrid' takes a HybridConfig, "
@@ -131,24 +129,16 @@ class PartitionDriver:
                 self.cfg = cfg.clamped(split.num_vertices)
                 self._necfg = self.cfg.ne_config()
                 self._split = split
-                self._graph = split.low
                 self.n, self.m = split.num_vertices, split.num_edges
                 self._edges = None      # materialized lazily by save_artifact
                 self.limit = alpha_limit(self.cfg.alpha, self.m,
                                          self.cfg.num_partitions)
-                self.state = hybrid_init_state(split, self._necfg)
-            elif mode == "single":
-                g = source if isinstance(source, EdgeFile) \
-                    else as_graph(source)
-                self._graph_fp = graph_fingerprint(g)
-                g = as_graph(g)
-                self.cfg = cfg.clamped(g.num_vertices)
-                self._graph = g
-                self.n, self.m = g.num_vertices, g.num_edges
-                self._edges = np.asarray(g.edges)
-                self.limit = alpha_limit(self.cfg.alpha, self.m,
-                                         self.cfg.num_partitions)
-                self.state: NEState | SpmdState = ne_init_state(g, self.cfg)
+                self.num_devices = 1
+                shards, masks = low_shard(split)
+                with obs.span("ingest_place", cat="runtime"):
+                    self._place(shards, masks)
+                    self.state = hybrid_init_state(split, self._necfg,
+                                                   self.mesh)
             elif self.multihost:
                 self._init_multihost(source, cfg, num_devices, snapshot_dir,
                                      exchange_dir)
@@ -160,26 +150,21 @@ class PartitionDriver:
                     self.n, self.m, self._edges, shards, masks, self._dev = \
                         self._ingest(source, self.num_devices, num_hosts,
                                      ingest_processes)
-                self.cfg = cfg.clamped(self.n)
+                self.cfg = self._necfg = cfg.clamped(self.n)
                 self.limit = alpha_limit(self.cfg.alpha, self.m,
                                          self.cfg.num_partitions)
                 with obs.span("ingest_place", cat="runtime"):
-                    self.mesh = make_edge_mesh(self.num_devices, axis=AXIS)
-                    self._u_sh = shard_over(self.mesh, shards[:, :, 0])
-                    self._v_sh = shard_over(self.mesh, shards[:, :, 1])
-                    self._mask_sh = shard_over(self.mesh, masks)
+                    self._place(shards, masks)
                     self.state = spmd_init_state(shards, masks, self.n,
                                                  self.cfg, self.mesh)
 
         # per-round SyncVertexAllocations traffic (per device) — a pure
         # function of the config, recorded as a cumulative trace counter
-        self._sync_bytes = (0 if mode in ("single", "hybrid") else
-                            round_sync_payload_bytes(self.cfg, self.n,
-                                                     self.num_devices))
+        self._sync_bytes = round_sync_payload_bytes(self._necfg, self.n,
+                                                    self.num_devices)
         self._sync_total = 0
         # and the slots one trip of its fold scatters (sync_chunk)
-        self._sync_chunk = (sync_chunk(int(self._mask_sh.shape[1]))
-                            if self._sync_bytes else 0)
+        self._sync_chunk = sync_chunk(int(self._mask_sh.shape[1]))
         if live.live_enabled():
             live.publish(phase="ingest", round=0, edges_remaining=self.m)
         self.snapshot = (RunSnapshot(snapshot_dir, self.cfg, self._graph_fp,
@@ -220,10 +205,10 @@ class PartitionDriver:
                              "it from)")
         self._exchange_dir = os.fspath(exchange_dir)
         self.n, self.m = int(source.num_vertices), int(source.num_edges)
-        self.cfg = cfg.clamped(self.n)
+        self.cfg = self._necfg = cfg.clamped(self.n)
         self.limit = alpha_limit(self.cfg.alpha, self.m,
                                  self.cfg.num_partitions)
-        self.mesh = make_edge_mesh(self.num_devices, axis=AXIS)
+        self.mesh = make_edge_mesh(self.num_devices)
         self._owned = mh.owned_indices(self.mesh)
         cluster.exchange_write_range(self._exchange_dir, source.path,
                                      self._host, self._nprocs,
@@ -245,6 +230,14 @@ class PartitionDriver:
         # never holds O(M) host state in a multi-controller run
         self._edges = None
         self._dev = None
+
+    def _place(self, shards: np.ndarray, masks: np.ndarray):
+        """The (D, C) edge shards on a ``num_devices`` mesh, row ``d`` on
+        device ``d``."""
+        self.mesh = make_edge_mesh(self.num_devices)
+        self._u_sh = shard_over(self.mesh, shards[:, :, 0])
+        self._v_sh = shard_over(self.mesh, shards[:, :, 1])
+        self._mask_sh = shard_over(self.mesh, masks)
 
     @staticmethod
     def _ingest(source, num_devices: int, num_hosts: int | None,
@@ -274,15 +267,10 @@ class PartitionDriver:
 
     @property
     def done(self) -> bool:
-        # cached per state: run() + step() both consult it every round, and
-        # the single-controller check is a full edge_part host transfer
+        # cached per state: run() + step() both consult it every round
         if self._done is None:
             if self.m == 0:
                 self._done = True
-            elif self.mode in ("single", "hybrid"):
-                # HybridConfig carries max_rounds, so ne_done reads either
-                with obs.span("done_read", cat="runtime"):
-                    self._done = ne_done(self.state, self.cfg)
             else:
                 # the host mirror of the whole-run while_loop condition
                 with obs.span("done_read", cat="runtime"):
@@ -291,7 +279,7 @@ class PartitionDriver:
         return self._done
 
     def _remaining(self) -> int:
-        """The SPMD state's unallocated edges, read from the device once
+        """The state's unallocated edges, read from the device once
         per state.  The drop since the previous read is what the rounds
         between them folded, both syncs of each (``sync_new_edges``)."""
         if self._rem is None:
@@ -321,30 +309,22 @@ class PartitionDriver:
             return self.rounds
         # the round span covers the snapshot save too (nested "snapshot"
         # span): per-round cost as a long run pays it, matching the old
-        # hand-timed round_secs the multihost_snap bench row diffs.  An
-        # SPMD round's span carries what its sync sends, so a profiler
-        # trace holds the count beside the exchange's device time, and
-        # the sync's chunk with the new edges the round before folded
-        args = {}
-        if self._sync_bytes:
-            args = {"sync_payload_bytes": self._sync_bytes,
-                    "sync_chunk": self._sync_chunk}
-            if self._new_edges is not None:
-                args["sync_new_edges"] = self._new_edges
+        # hand-timed round_secs the multihost_snap bench row diffs.  The
+        # round's span carries what its sync sends, so a profiler trace
+        # holds the count beside the exchange's device time, and the
+        # sync's chunk with the new edges the round before folded
+        args = {"sync_payload_bytes": self._sync_bytes,
+                "sync_chunk": self._sync_chunk}
+        if self._new_edges is not None:
+            args["sync_new_edges"] = self._new_edges
         with obs.span("round", cat="runtime", **args) as sp:
             # the call returns once the round is enqueued; the wait is the
             # device's time, the read the scalar the step returns
-            with obs.span("round_dispatch", cat="runtime"):
-                if self.mode in ("single", "hybrid"):
-                    cfg = self.cfg if self.mode == "single" else self._necfg
-                    state = ne_round_step(self._graph, cfg, self.limit,
-                                          self.state)
-                else:
-                    with jax.set_mesh(self.mesh):
-                        state = spmd_round_step(
-                            self.cfg, self.limit, self.n, self.mesh,
-                            self._u_sh, self._v_sh, self._mask_sh,
-                            self.state)
+            with obs.span("round_dispatch", cat="runtime"), \
+                    jax.set_mesh(self.mesh):
+                state = spmd_round_step(
+                    self._necfg, self.limit, self.n, self.mesh, self._u_sh,
+                    self._v_sh, self._mask_sh, self.state)
             with obs.span("round_wait", cat="runtime"):
                 self.state = jax.block_until_ready(state)
             with obs.span("round_read", cat="runtime"):
@@ -353,19 +333,16 @@ class PartitionDriver:
             tr = obs.get_tracer()
             if tr is not None:
                 sp.set(round=rounds)
-                if self.mode == "spmd":
-                    tr.counter("edges_remaining", self._remaining())
-                    tr.counter("sync_new_edges", self._new_edges)
-                    tr.add("sync_payload_bytes", self._sync_bytes)
+                tr.counter("edges_remaining", self._remaining())
+                tr.counter("sync_new_edges", self._new_edges)
+                tr.add("sync_payload_bytes", self._sync_bytes)
             self._sync_total += self._sync_bytes
             if live.live_enabled():
                 # pure read of the replicated state (no RNG, no mutation),
                 # so monitored runs stay bit-identical to unmonitored
-                q = round_quality(self.cfg, self.state, self.n)
-                rem = (self._remaining() if self.mode == "spmd"
-                       else q["degree_sum"] // 2)
+                q = round_quality(self._necfg, self.state, self.n)
                 live.publish(phase="round", round=rounds,
-                             edges_remaining=rem,
+                             edges_remaining=self._remaining(),
                              sync_payload_bytes=self._sync_total,
                              rf=q["rf"], eb=q["eb"], vb=q["vb"],
                              boundary=q["boundary"])
@@ -413,13 +390,12 @@ class PartitionDriver:
             with obs.span("device_get", cat="runtime"):
                 edge_part, vparts, counts, rounds = jax.device_get(
                     (st.edge_part, st.vparts, st.edges_per_part, st.rounds))
-            if self.mode == "spmd":
-                with obs.span("stitch_edge_part", cat="runtime"):
-                    edge_part = stitch_edge_part(edge_part, self._dev, self.m)
-                if self.cfg.use_pallas:
-                    # SPMD round state keeps replica sets bit-packed; the
-                    # result surface is always (N, P) bool
-                    vparts = ne_ops.unpack_bits_np(vparts, p_num)
+            with obs.span("stitch_edge_part", cat="runtime"):
+                edge_part = stitch_edge_part(edge_part, self._dev, self.m)
+            if self.cfg.use_pallas:
+                # the round state keeps replica sets bit-packed; the
+                # result surface is always (N, P) bool
+                vparts = ne_ops.unpack_bits_np(vparts, p_num)
             with obs.span("finalize_result", cat="runtime"):
                 self._result = finalize_result(edge_part, vparts, counts,
                                                self._edges, self.cfg,
@@ -565,24 +541,21 @@ class PartitionDriver:
         if mode != self.mode:
             raise SnapshotMismatch(f"snapshot was taken in mode {mode!r}, "
                                    f"driver is {self.mode!r}")
-        cls = SpmdState if self.mode == "spmd" else NEState
-        want = cls._fields
-        missing = set(want) - set(fields)
+        missing = set(SpmdState._fields) - set(fields)
         if missing:
             raise SnapshotMismatch(f"snapshot is missing fields {missing}")
-        if self.mode == "spmd":
-            have = tuple(fields["edge_part"].shape)
-            expect = tuple(self._mask_sh.shape)
-            if have != expect:
-                # elastic resume: the snapshot was taken on a different
-                # device count — reshard the slices onto the current
-                # layout instead of refusing (single-controller, so the
-                # in-memory stitch + re-split is the honest path)
-                fields["edge_part"] = self._reshard_in_memory(
-                    np.asarray(fields["edge_part"]))
-        state = cls(**{k: np.asarray(fields[k]) for k in want})
-        self.state = (place_state(self.mesh, state) if self.mode == "spmd"
-                      else jax.tree.map(jnp.asarray, state))
+        have = tuple(fields["edge_part"].shape)
+        expect = tuple(self._mask_sh.shape)
+        if have != expect:
+            # elastic resume: the snapshot was taken on a different device
+            # count — reshard the slices onto the current layout instead of
+            # refusing (single-controller, so the in-memory stitch +
+            # re-split is the honest path).  A hybrid run is always one
+            # device's shard of its low edges
+            fields["edge_part"] = self._reshard_in_memory(
+                np.asarray(fields["edge_part"]))
+        self.state = place_state(self.mesh, SpmdState(
+            **{k: np.asarray(fields[k]) for k in SpmdState._fields}))
         self._state_changed(restored=True)
         return rnd
 

@@ -12,7 +12,7 @@ allocation process per machine, rounds separated by real collectives):
   collectives), :func:`worker_main` drives one process's share of a run:
   ingest only this host's block range through the
   :mod:`repro.runtime.cluster` exchange, build the *global* mesh via
-  :func:`repro.launch.mesh.make_edge_mesh`, step
+  :func:`repro.dist.partitioner_sm.make_edge_mesh`, step
   ``spmd_round_step`` through :class:`repro.runtime.driver.PartitionDriver`
   with per-host snapshot writes, and publish the finalized result from
   process 0;
@@ -175,7 +175,7 @@ def spmd_init_state_global(
     )
     return SpmdState(
         edge_part=edge_part,
-        vparts=replicate(mesh, empty_vparts(n, cfg, np)),
+        vparts=replicate(mesh, empty_vparts(n, cfg)),
         degree_rest=replicate(mesh, degree.astype(np.int32)),
         edges_per_part=replicate(mesh, np.zeros((p_num,), np.int32)),
         key=replicate(mesh, np.asarray(jax.random.PRNGKey(cfg.seed))),

@@ -2,7 +2,7 @@
 
 Two layers:
 
-* :class:`ShardedCheckpointManager` — a ``train.checkpoint.CheckpointManager``
+* :class:`ShardedCheckpointManager` — a ``runtime.checkpoint.CheckpointManager``
   extension where designated arrays are written one file per leading-axis
   shard (``<name>.shard<i>.bin``) instead of into the monolithic
   ``data.bin``.  In a multi-host deployment host ``h`` writes and reads only
@@ -12,7 +12,7 @@ Two layers:
   rename — a kill at any point leaves the previous step intact.
 
 * :class:`RunSnapshot` — the partitioner-specific façade: saves a
-  ``SpmdState`` / ``NEState`` keyed by round number, stamps the manifest
+  ``SpmdState`` keyed by round number, stamps the manifest
   with config + graph fingerprints, and *refuses to restore* against a
   different ``NEConfig`` or a different edge source — a resume that
   silently mixed graphs would produce garbage partitions that still look
@@ -36,7 +36,7 @@ import numpy as np
 from repro.core.partitioner import NEConfig
 from repro.io.edgefile import EdgeFile
 from repro.obs import trace as obs
-from repro.train.checkpoint import CheckpointManager, fsync_path
+from repro.runtime.checkpoint import CheckpointManager, _flatten, fsync_path
 
 
 # ---------------------------------------------------------------------------
@@ -92,8 +92,6 @@ class ShardedCheckpointManager(CheckpointManager):
     def save(self, step: int, tree, sharded: dict | None = None,
              extra_meta: dict | None = None) -> Path:
         import jax
-
-        from repro.train.checkpoint import _flatten
 
         tmp, manifest = self._begin(step, extra_meta)
         self._write_data(tmp, _flatten(jax.device_get(tree)), manifest)
@@ -178,8 +176,6 @@ class ShardedCheckpointManager(CheckpointManager):
         and the partial manifest in the shared tmp dir."""
         import jax
 
-        from repro.train.checkpoint import _flatten
-
         tmp, manifest = self._begin(step, extra_meta)
         self._write_data(tmp, _flatten(jax.device_get(tree)), manifest)
         with open(tmp / ".manifest.partial.json", "w") as f:
@@ -256,9 +252,9 @@ class SnapshotMismatch(RuntimeError):
 class RunSnapshot:
     """Round-keyed snapshots of a partitioning run.
 
-    ``save_state`` takes the raw field dict of an ``SpmdState`` /
-    ``NEState`` (numpy or jax arrays), stores ``edge_part`` sharded when it
-    carries a leading device axis, and stamps fingerprints; ``restore_state``
+    ``save_state`` takes the raw field dict of an ``SpmdState`` (numpy or
+    jax arrays), stores ``edge_part`` one file per device shard, and stamps
+    fingerprints; ``restore_state``
     validates them and hands back plain numpy arrays keyed by field name.
     """
 
@@ -270,9 +266,7 @@ class RunSnapshot:
 
     def save_state(self, round_k: int, fields: dict, mode: str) -> Path:
         fields = {k: np.asarray(v) for k, v in fields.items()}
-        sharded = None
-        if mode == "spmd":
-            sharded = {"edge_part": fields.pop("edge_part")}
+        sharded = {"edge_part": fields.pop("edge_part")}
         meta = {"mode": mode, "round": int(round_k),
                 "config_fingerprint": self.cfg_fp,
                 "graph_fingerprint": self.graph_fp}

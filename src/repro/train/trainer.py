@@ -25,7 +25,7 @@ from typing import Any, Callable
 import jax
 import numpy as np
 
-from repro.train.checkpoint import CheckpointManager
+from repro.runtime.checkpoint import CheckpointManager
 
 
 @dataclasses.dataclass

@@ -153,6 +153,19 @@ def test_edgefile_result_matches_graph(g, ef):
     np.testing.assert_array_equal(a.edge_part, b.edge_part)
 
 
+def test_packed_replica_sets_bit_identical(g):
+    """The expansion keeps ``use_pallas``'s packed replica sets, seeded
+    from the tail, and the result matches the bool representation's."""
+    import dataclasses
+
+    a = partition_hybrid(g, CFG)
+    b = partition_hybrid(g, dataclasses.replace(CFG, use_pallas=True))
+    np.testing.assert_array_equal(a.edge_part, b.edge_part)
+    np.testing.assert_array_equal(a.vparts, b.vparts)
+    np.testing.assert_array_equal(a.edges_per_part, b.edges_per_part)
+    assert a.rounds == b.rounds
+
+
 # -- driver: run / kill / resume -------------------------------------------
 
 def test_driver_matches_fire_and_forget(g, tmp_path):

@@ -16,8 +16,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import partition
 from repro.core.graph import as_graph
-from repro.core.partitioner import (I32_INF, NEConfig, one_hop, partition,
+from repro.core.partitioner import (I32_INF, NEConfig, one_hop,
                                     priority_enc, select_chunk,
                                     vertex_claims)
 from repro.dist.partitioner_sm import partition_spmd

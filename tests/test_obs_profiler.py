@@ -19,13 +19,12 @@ import pytest
 from jax.profiler import ProfileData
 
 from repro.core.graph import shard_edges
-from repro.core.partitioner import (NEConfig, alpha_limit, ne_init_state,
-                                    ne_round_step)
-from repro.dist.partitioner_sm import (AXIS, round_sync_payload_bytes,
-                                       shard_over, spmd_init_state,
-                                       spmd_round_step, sync_chunk)
+from repro.core.partitioner import NEConfig, alpha_limit
+from repro.dist.partitioner_sm import (make_edge_mesh,
+                                       round_sync_payload_bytes, shard_over,
+                                       spmd_init_state, spmd_round_step,
+                                       sync_chunk)
 from repro.graphs.rmat import rmat
-from repro.launch.mesh import make_edge_mesh
 from repro.obs import trace as obs
 from repro.runtime import PartitionDriver
 
@@ -193,11 +192,8 @@ def _round_hlo(path: str) -> str:
     cfg = NEConfig(num_partitions=16, k_sel=64, edge_chunk=1 << 12,
                    use_pallas=path == "spmd-packed").clamped(g.num_vertices)
     limit = alpha_limit(cfg.alpha, g.num_edges, cfg.num_partitions)
-    if path == "single":
-        return ne_round_step.lower(g, cfg, limit,
-                                   ne_init_state(g, cfg)).compile().as_text()
     shards, masks, _, _ = shard_edges(np.asarray(g.edges), 1)
-    mesh = make_edge_mesh(1, axis=AXIS)
+    mesh = make_edge_mesh(1)
     state = spmd_init_state(shards, masks, g.num_vertices, cfg, mesh)
     with jax.set_mesh(mesh):
         return spmd_round_step.lower(
@@ -207,7 +203,7 @@ def _round_hlo(path: str) -> str:
             state).compile().as_text()
 
 
-@pytest.mark.parametrize("path", ["spmd-bool", "spmd-packed", "single"])
+@pytest.mark.parametrize("path", ["spmd-bool", "spmd-packed"])
 def test_round_work_sits_under_a_phase_scope(path):
     """Graph500 scale 10 on the CPU: every instruction of the compiled
     round that does work and comes from a JAX op names one of the four

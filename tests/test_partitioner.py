@@ -1,4 +1,6 @@
 """Unit + property tests for the Distributed NE core (paper §3–§6)."""
+import hashlib
+
 import numpy as np
 import pytest
 
@@ -8,10 +10,12 @@ from hypothesis import given, settings, strategies as st  # noqa: E402
 from repro.core import NEConfig, evaluate, from_edges, partition, \
     theorem1_upper_bound
 from repro.core.baselines import dbh, grid_2d, hdrf, oblivious, random_1d
+from repro.core.hybrid import HybridConfig, partition_hybrid
 from repro.core.metrics import vertex_replicas
 from repro.core.sequential_ne import sequential_ne
 from repro.graphs.rmat import rmat
-from repro.graphs.generators import erdos_renyi, ring_plus_complete
+from repro.graphs.generators import erdos_renyi, grid2d, ring_plus_complete
+from repro.runtime import PartitionDriver
 
 
 @pytest.fixture(scope="module")
@@ -162,3 +166,54 @@ def test_sequential_ne_oracle(small_rmat):
     rf_rand = evaluate(e, random_1d(g, 8), g.num_vertices, 8)\
         .replication_factor
     assert rf_seq < rf_rand
+
+
+# Golden edge assignments: sha256 (first 16 hex digits) of the int32
+# ``edge_part``, with ``rounds`` and ``leftover``.  Recorded before the
+# one-device run and the hybrid's expansion moved onto the SPMD round, so
+# they pin that move as behaviour-preserving.
+_GOLDEN_GRAPHS = {
+    "rmat10": lambda: rmat(10, 8, seed=3),
+    "rmat12": lambda: rmat(12, 16, seed=1),
+    "er2000": lambda: erdos_renyi(2000, 8.0, seed=2),
+    "grid40": lambda: grid2d(40, 40),
+}
+_GOLDEN = [
+    ("partition", "rmat10", dict(num_partitions=8, seed=0),
+     "c33d64818fbf052c", 39, 0),
+    ("partition", "rmat12", dict(num_partitions=16, seed=5),
+     "4035b9bd21e1effa", 47, 0),
+    ("partition", "er2000", dict(num_partitions=8, seed=0, two_hop=False),
+     "67a001ae707a25b7", 53, 0),
+    ("partition", "grid40", dict(num_partitions=8, seed=1, max_rounds=5),
+     "f5d6fe5c2e03a489", 5, 2987),
+    ("partition", "rmat12", dict(num_partitions=8, seed=1, edge_chunk=1024),
+     "0fa9d44745379625", 54, 0),
+    ("hybrid", "rmat12", dict(num_partitions=8, budget_frac=0.5, seed=0),
+     "2e3c2e7e4800b96a", 50, 0),
+    ("hybrid", "er2000", dict(num_partitions=8, budget_frac=0.3, seed=4),
+     "e6981ec71d8a0fbb", 36, 0),
+    ("hybrid", "rmat10", dict(num_partitions=16, budget_frac=0.7, seed=1,
+                              two_hop=False),
+     "e1f7deb3631a333c", 30, 0),
+]
+
+
+@pytest.mark.parametrize("entry,graph,kw,digest,rounds,leftover", _GOLDEN,
+                         ids=[f"{e}-{g}-{i}" for i, (e, g, *_) in
+                              enumerate(_GOLDEN)])
+def test_golden_assignments(entry, graph, kw, digest, rounds, leftover):
+    """The public call and the driver give the recorded assignment."""
+    g = _GOLDEN_GRAPHS[graph]()
+    if entry == "partition":
+        cfg = NEConfig(**kw)
+        results = (partition(g, cfg),
+                   PartitionDriver(g, cfg, num_devices=1).run())
+    else:
+        cfg = HybridConfig(**kw)
+        results = (partition_hybrid(g, cfg),
+                   PartitionDriver(g, cfg, mode="hybrid").run())
+    for res in results:
+        got = hashlib.sha256(np.asarray(res.edge_part, np.int32)
+                             .tobytes()).hexdigest()[:16]
+        assert (got, res.rounds, res.leftover) == (digest, rounds, leftover)

@@ -158,7 +158,8 @@ def test_sync_new_edges_sum_to_m_without_a_new_read(monkeypatch):
 
 
 def test_driver_single_mode_matches_partition(graph12):
-    drv = PartitionDriver(graph12, CFG, mode="single")
+    """``partition`` is the one-device driver's program run whole."""
+    drv = PartitionDriver(graph12, CFG, num_devices=1)
     res = drv.run()
     ref = partition(graph12, CFG)
     np.testing.assert_array_equal(res.edge_part, ref.edge_part)
@@ -198,11 +199,12 @@ def test_resume_latest_snapshot(graph12, snapped_run):
 
 
 def test_resume_single_mode(tmp_path):
+    """A one-device run over a Graph resumes bit-identically."""
     g = rmat(9, 8, seed=5)
     cfg = NEConfig(num_partitions=4, seed=1, k_sel=32, edge_chunk=1 << 10)
-    full = PartitionDriver(g, cfg, mode="single", snapshot_dir=tmp_path,
+    full = PartitionDriver(g, cfg, num_devices=1, snapshot_dir=tmp_path,
                            snapshot_every=2, keep=100_000).run()
-    drv = PartitionDriver.resume(g, cfg, tmp_path, mode="single")
+    drv = PartitionDriver.resume(g, cfg, tmp_path, num_devices=1)
     assert drv.rounds > 0
     got = drv.run()
     np.testing.assert_array_equal(got.edge_part, full.edge_part)
@@ -226,9 +228,14 @@ def test_resume_wrong_graph_fails(snapped_run):
 
 
 def test_resume_wrong_mode_fails(graph12, snapped_run):
+    """An spmd snapshot resumed as a hybrid run is refused."""
+    from repro.core.hybrid import HybridConfig
+
     _, _, snap_dir = snapped_run
+    hy = HybridConfig(num_partitions=8, budget_frac=1.0, seed=0, k_sel=64,
+                      edge_chunk=1 << 12)
     with pytest.raises(SnapshotMismatch):
-        PartitionDriver.resume(graph12, CFG, snap_dir, mode="single")
+        PartitionDriver.resume(graph12, hy, snap_dir, mode="hybrid")
 
 
 def test_fingerprints_discriminate(graph12):

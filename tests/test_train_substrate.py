@@ -9,7 +9,7 @@ import pytest
 
 from repro.dist import compat
 from repro.train import optimizer as opt
-from repro.train.checkpoint import CheckpointManager
+from repro.runtime.checkpoint import CheckpointManager
 from repro.train.compression import init_residuals, psum_compressed
 from repro.train.trainer import TrainLoopConfig, run_training
 
@@ -83,7 +83,7 @@ def test_checkpoint_kill_mid_save(tmp_path):
     code = textwrap.dedent(f"""
         import os, signal
         import numpy as np
-        from repro.train import checkpoint as cp
+        from repro.runtime import checkpoint as cp
 
         mgr = cp.CheckpointManager({str(tmp_path)!r}, keep=5)
         mgr.save(1, {{"w": np.arange(64.0)}})
